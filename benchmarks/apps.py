@@ -131,18 +131,22 @@ class StringMatch(MapReduceApp):
 
 
 class WordCount(MapReduceApp):
-    """WC: the running example (Figs 1-3)."""
+    """WC: the running example (Figs 1-3).
 
-    def __init__(self, vocab: int):
-        WordCount.key_space = vocab
-        WordCount.value_aval = jax.ShapeDtypeStruct((), I32)
-        WordCount.emit_capacity = 16
+    ``dtype`` is the count type: int32 as in the paper, or float32 so the
+    holder tables are the f32 ones the Pallas fold kernels carry (counts
+    stay exact below 2^24 per word)."""
+
+    def __init__(self, vocab: int, dtype=I32):
+        self.key_space = vocab
+        self.value_aval = jax.ShapeDtypeStruct((), dtype)
+        self.emit_capacity = 16
         # zipf(1.2) head: the top word is ~19% of all tokens — the reduce
         # flow must buffer it in full (the paper's materialized value lists)
-        WordCount.max_values_per_key = 16384
+        self.max_values_per_key = 16384
 
     def map(self, window, emit):  # [16] token ids
-        emit(window, jnp.ones_like(window))
+        emit(window, jnp.ones_like(window, self.value_aval.dtype))
 
     def reduce(self, key, values, count):
         return jnp.sum(values)

@@ -6,7 +6,9 @@ the quantity that governs scaling at pod scale: **collective wire bytes per
 shard** as the shard count grows.  The combine flow all-reduces O(K) holder
 tables (shard-count-independent per-shard volume) while the reduce flow
 all-to-alls O(N) raw pairs.  Derived from compiled HLO on fake meshes in a
-subprocess per shard count."""
+subprocess per shard count.  The children compile for the CPU backend
+(``JAX_PLATFORMS=cpu``), never for a chip the parent may hold: the rows
+are byte counts from CPU-compiled HLO, not device measurements."""
 
 from __future__ import annotations
 
@@ -19,8 +21,6 @@ import textwrap
 from benchmarks.common import bench_scale, row
 
 _CODE = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={S}"
 import sys, json
 sys.path.insert(0, {src!r})
 import numpy as np, jax, jax.numpy as jnp
@@ -60,13 +60,12 @@ SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 def main():
     print("# paper Fig 5 analogue: per-shard collective bytes vs shard "
           "count (stream/combine flow = O(K) tables, reduce flow = "
-          "O(N) pairs)")
+          "O(N) pairs), counted on CPU fake meshes")
     shard_counts = (2, 4) if bench_scale() < 1 else (2, 4, 8)
     failed = []
     for S in shard_counts:
-        env = dict(os.environ)
-        env.pop("XLA_FLAGS", None)
-        env["PYTHONPATH"] = SRC
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=SRC,
+                   XLA_FLAGS=f"--xla_force_host_platform_device_count={S}")
         r = subprocess.run([sys.executable, "-c", _CODE.format(S=S, src=SRC)],
                            capture_output=True, text=True, timeout=420,
                            env=env)
@@ -78,8 +77,10 @@ def main():
             continue
         data = json.loads(line[0][len("RESULT "):])
         flow = data.get("optimized_flow") or "combine"
-        print(row(f"scalability_S{S}_{flow}_wire_bytes", data["optimized"]))
+        print(row(f"scalability_S{S}_{flow}_wire_bytes", data["optimized"],
+                  "count from CPU-compiled HLO"))
         print(row(f"scalability_S{S}_reduce_wire_bytes", data["reduce"],
+                  f"count from CPU-compiled HLO; "
                   f"ratio={data['reduce']/max(data['optimized'],1):.1f}x"))
     if failed:  # surface subprocess failures to run.py's health gate
         raise RuntimeError(f"scalability subprocesses failed: S={failed}")

@@ -73,6 +73,10 @@ def main(argv=None) -> int:
 
     import importlib
 
+    from repro.compile_cache import enable_compile_cache
+
+    print(f"# compile cache: {enable_compile_cache()}", file=sys.stderr)
+
     names = args.only if args.only else MODULE_NAMES
     rows: list[dict] = []
     failures: list[dict] = []

@@ -1,13 +1,14 @@
-"""Distributed word count on a fake 4-device mesh: the combine flow merges
+"""Distributed word count on a 4-device mesh: the combine flow merges
 holder tables with an all-reduce (O(K)); the baseline shuffles raw pairs
 with all-to-all (O(N)).  Prints both results + the collectives each flow
-lowered to.
+lowered to.  On a host with four chips it runs as is; on a CPU, ask for
+four fake devices:
 
-  PYTHONPATH=src python examples/wordcount_cluster.py
+  XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \
+      PYTHONPATH=src python examples/wordcount_cluster.py
 """
 
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -38,6 +39,9 @@ class WordCount(MapReduceApp):
         return jnp.sum(values)
 
 
+if len(jax.devices()) < 4:
+    sys.exit(f"needs 4 devices, found {len(jax.devices())}: set XLA_FLAGS "
+             f"as the docstring shows")
 mesh = jax.make_mesh((4,), ("data",))
 rng = np.random.default_rng(0)
 toks = jax.device_put(

@@ -195,18 +195,13 @@ def choose_chunk_pairs(key_space: int, *, holder_bytes: int, pair_bytes: int,
 
 
 def choose_key_block(key_space: int, chunk_pairs: int, *, d: int,
-                     use_kernels: bool,
-                     tile_n: int = 512, tile_d: int = 128) -> int:
+                     use_kernels: bool) -> int:
     """Key-block size per lowering memory model (see module docstring)."""
     if use_kernels:
-        try:
-            from repro.kernels import ops
+        from repro.kernels import ops
 
-            return ops.auto_key_block(key_space, d=d,
-                                      tile_n=min(tile_n, chunk_pairs),
-                                      tile_d=tile_d)
-        except Exception:  # pragma: no cover
-            pass
+        tile_n = min(ops.FOLD_TILE_N, -(-chunk_pairs // 128) * 128)
+        return ops.auto_key_block(key_space, d=d, tile_n=tile_n)
     # pure-JAX folds: one [chunk, Kb] expansion live per block — inside a
     # multi-chunk scan XLA materializes anything bigger (measured: an
     # unblocked K=32k fold in the scan body costs 268 MB peak / O(N·K)
@@ -397,29 +392,25 @@ def autotune_sort(
             chunk = min(chunk, _pow2_round(n_pairs_hint))
         chunk = max(min(chunk, MAX_CHUNK_PAIRS), app.emit_capacity, 1)
 
-    fanouts: tuple[int, ...] = ()
-    kernels_feasible = False
-    try:
-        from repro.kernels import ops
+    from repro.kernels import ops
 
-        plan = ops.plan_radix_levels(K, d=d + 1)
-        if plan.feasible:
-            kernels_feasible = True
-            bucket = plan.bucket_size
-            fanouts = plan.fanouts
-            if plan.levels > 1:
-                notes.append(
-                    f"hierarchical radix partition: {plan.describe()} — "
-                    f"key space past one bucket sweep, each level's "
-                    f"fan-out bounded at {ops.MAX_RADIX_FANOUT}")
-        else:
-            bucket = K
+    fanouts: tuple[int, ...] = ()
+    plan = ops.plan_radix_levels(K, d=d + 1)
+    kernels_feasible = plan.feasible
+    if plan.feasible:
+        bucket = plan.bucket_size
+        fanouts = plan.fanouts
+        if plan.levels > 1:
             notes.append(
-                f"LEVEL BUDGET: {plan.reason}; the kernel pipeline "
-                f"degrades to the pure-JAX multi-pass sorted fold "
-                f"(LoweringFallbackWarning at run time)")
-    except Exception:  # pragma: no cover
+                f"hierarchical radix partition: {plan.describe()} — "
+                f"key space past one bucket sweep, each level's "
+                f"fan-out bounded at {ops.MAX_RADIX_FANOUT}")
+    else:
         bucket = K
+        notes.append(
+            f"LEVEL BUDGET: {plan.reason}; the kernel pipeline "
+            f"degrades to the pure-JAX multi-pass sorted fold "
+            f"(LoweringFallbackWarning at run time)")
     sort_passes = col.sort_radix_passes(min(chunk, MAX_CHUNK_PAIRS), K)
     if not use_kernels:
         if sort_passes > 1:

@@ -303,8 +303,8 @@ def combine_flow(
     if impl == "auto":
         n = stream.keys.shape[0]
         # the fused-regime widening applies to the pure-JAX einsum only:
-        # the onehot_combine kernel has no key-block axis, so past the
-        # legacy cutoff its [K, Td] table would outgrow VMEM.
+        # the legacy combine flow keeps the onehot_combine kernel under the
+        # legacy cutoff (the chunked stream flow is the large-K kernel path)
         onehot_ok = (stream.key_space <= onehot_max_keys
                      or (onehot_fn is None
                          and n <= ADDITIVE_FOLD_PAIRS_FUSED))
@@ -496,6 +496,16 @@ class StreamCombiner:
         self.mode = (mode if mode is not None else
                      stream_mode(spec, dense_ok=self._dense_ok,
                                  additive_ok=additive_ok))
+        kernels_used = ((kernel_additive and self.mode == "additive")
+                        or (kernel_monoid and self.mode == "dense"))
+        if (fold_fn is not None or monoid_fold_fn is not None) \
+                and not kernels_used:
+            _emit_fallback(
+                f"stream flow: use_kernels=True, but the Pallas fold kernels "
+                f"carry only f32 holder tables under add/max/min monoids "
+                f"({spec.describe or spec.strategy} over {value_aval}, "
+                f"fold mode {self.mode}); the chunks fold in pure JAX.",
+                on_fallback)
         if mode is None and spec.mxu_lowerable and self.mode == "scatter":
             _emit_fallback(
                 f"stream flow: dense fold budgets exceeded at key_space="

@@ -23,9 +23,11 @@ Two backend profiles translate the terms into seconds:
   container (single core; the serialized scatter and the strength-reduced
   one-hot both get their measured constants, which is what makes the
   stream/sort crossover land where ``bench_flow_sweep`` measures it);
-* ``tpu`` — roofline: ``max(bytes / HBM_BW, flops / PEAK_FLOPS)`` with the
-  one-hot fold priced at MXU rates (the crossover moves far right: the MXU
-  makes O(N·K) cheap until K is huge — the co-design point of the paper).
+* ``tpu`` — roofline: ``max(bytes / hbm_bw, flops / peak_flops)`` with
+  the chip's published peaks (``roofline.peaks``, keyed by
+  ``device_kind``) and the one-hot fold priced at MXU rates (the
+  crossover moves far right: the MXU makes O(N·K) cheap until K is huge
+  — the co-design point of the paper).
 
 ``choose_flow`` ranks the candidate flows for a workload; the planner
 records the full report on the plan so ``MapReduce.explain()`` can show
@@ -50,6 +52,8 @@ from repro.roofline import analysis as roofline
 #:   scatter   — one serialized scatter row update (XLA:CPU scatter loop)
 #:   table     — one holder-table row touch (init/merge/finalize)
 #:   window    — one padded reduce-flow window element (gather + reduce)
+#:   wire      — one byte through the fake-mesh all-to-all (priced at the
+#:               v5e ICI link rate, 50 GB/s)
 CPU_COEFF = {
     "dispatch": 60e-6,
     "pair": 3.0e-8,
@@ -59,6 +63,7 @@ CPU_COEFF = {
     "scatter": 1.0e-7,
     "table": 2.5e-9,
     "window": 4.0e-9,
+    "wire": 1.0 / 50e9,
 }
 
 #: TPU compute rates: the one-hot fold runs on the MXU (priced against the
@@ -148,11 +153,11 @@ def _cpu_terms(flow: str, *, n, k, d, lmax, chunk_pairs, fused_combine,
 
 
 def _tpu_terms(flow: str, *, n, k, d, lmax, model_bytes, fused_combine,
-               sort_levels=1):
-    mem_s = model_bytes / roofline.HBM_BW
+               chip, sort_levels=1):
+    mem_s = model_bytes / chip.hbm_bw
     if flow in ("stream", "combine"):
         flops = 2.0 * n * k * d  # one-hot contraction on the MXU
-        comp_s = flops / (roofline.PEAK_FLOPS * TPU_MXU_UTIL)
+        comp_s = flops / (chip.flops * TPU_MXU_UTIL)
     elif flow == "sort":
         # hist + bucket-scatter per hierarchy level: the per-pair dynamic
         # VMEM stores run on the scalar unit once per level
@@ -180,8 +185,13 @@ def estimate_flow_cost(
     wire: str = "raw",
     shuffle_capacity: int | None = None,
     value_dtype: str = "int32",
+    device_kind: str | None = None,
 ) -> FlowCost:
     """Model one flow's cost for a workload (see module docstring).
+
+    The ``tpu`` profile prices ``device_kind``'s published peaks (default:
+    the running device's); a kind without a row in ``roofline.PEAKS``
+    raises.
 
     ``skew_factor`` is the sampled key-distribution imbalance (max range
     load over the uniform share, >= 1.0, from ``core/skew.py``): the
@@ -207,13 +217,10 @@ def estimate_flow_cost(
     sort_levels = 1
     if flow == "sort":
         if backend == "tpu":
-            try:
-                from repro.kernels import ops
+            from repro.kernels import ops
 
-                rplan = ops.plan_radix_levels(k, d=d + 1)
-                sort_levels = max(rplan.levels, 1) if rplan.feasible else 1
-            except Exception:  # pragma: no cover
-                sort_levels = 1
+            rplan = ops.plan_radix_levels(k, d=d + 1)
+            sort_levels = max(rplan.levels, 1) if rplan.feasible else 1
         else:
             sort_levels = col.sort_radix_passes(max(min(n, chunk), 1), k)
     model_bytes = roofline.mapreduce_flow_bytes(
@@ -233,6 +240,7 @@ def estimate_flow_cost(
     elif backend == "tpu":
         terms = _tpu_terms(flow, n=n, k=k, d=d, lmax=lmax,
                            model_bytes=model_bytes,
+                           chip=roofline.peaks(device_kind),
                            fused_combine=fused_combine,
                            sort_levels=sort_levels)
         est = max(v for _, v in terms)  # overlappable roofline terms
@@ -246,7 +254,9 @@ def estimate_flow_cost(
         wire_s = roofline.shuffle_wire_bytes(
             wire, n_pairs=n, key_space=k, num_shards=S,
             value_bytes=value_bytes, value_dtype=value_dtype,
-            capacity=shuffle_capacity) / roofline.LINK_BW
+            capacity=shuffle_capacity) * (
+                1.0 / roofline.peaks(device_kind).link_bw
+                if backend == "tpu" else CPU_COEFF["wire"])
         terms = list(terms) + [("wire", wire_s)]
         est += wire_s
     sf = max(float(skew_factor), 1.0)
@@ -283,6 +293,7 @@ def choose_flow(
     wire: str = "raw",
     shuffle_capacity: int | None = None,
     value_dtype: str = "int32",
+    device_kind: str | None = None,
 ) -> CostReport:
     """Rank ``candidates`` by modeled cost and pick the cheapest.
 
@@ -300,7 +311,8 @@ def choose_flow(
                             backend=backend, skew_factor=skew_factor,
                             num_shards=num_shards, wire=wire,
                             shuffle_capacity=shuffle_capacity,
-                            value_dtype=value_dtype)
+                            value_dtype=value_dtype,
+                            device_kind=device_kind)
          for f in candidates),
         key=lambda fc: fc.est_s)
     return CostReport(chosen=costs[0].flow, n_pairs=n_pairs,
@@ -323,6 +335,6 @@ def pipeline_overhead_s(n_stages: int, *, handoff_bytes: float = 0.0,
     dispatches = 1 if fused else max(1, int(n_stages))
     secs = dispatches * CPU_COEFF["dispatch"]
     if not fused and handoff_bytes:
-        bw = roofline.HBM_BW if backend == "tpu" else 2.0e10
+        bw = roofline.peaks().hbm_bw if backend == "tpu" else 2.0e10
         secs += float(handoff_bytes) / bw
     return secs

@@ -201,10 +201,18 @@ def _check_sort_kernel_plan(spec, key_space: int, value_aval,
     past the padded-layout envelope.  ``skew_factor`` (the sampled
     fixed-width imbalance) shrinks the leaf bucket so a hot leaf's padded
     region still fits the partition's VMEM envelope."""
-    if not use_kernels or bucket_size is not None:
+    if not use_kernels:
         return use_kernels, bucket_size, level_fanouts
     if not spec.kernel_monoid_ok(value_aval):
-        return use_kernels, bucket_size, level_fanouts  # kernel unused
+        col._emit_fallback(
+            f"sort flow: use_kernels=True, but the radix-partition kernel "
+            f"pipeline carries only f32 holder tables under add/max/min "
+            f"monoids ({spec.describe or spec.strategy} over "
+            f"{value_aval}); this key range folds with the pure-JAX sorted "
+            f"fold.", on_fallback)
+        return False, None, None
+    if bucket_size is not None:
+        return use_kernels, bucket_size, level_fanouts
     from repro.kernels import ops
 
     d, _ = spec.holder_width(value_aval)
@@ -1147,8 +1155,6 @@ def build_distributed_fn(
     channel, returning ``(keys, values, counts)``.  ``chunk_pairs`` /
     ``key_block`` must already be resolved to the PER-SHARD tiling (see
     :func:`_distributed_tiling`)."""
-    from jax.experimental.shard_map import shard_map
-
     S = mesh.shape[data_axis]
     if plan.flow in ("combine", "stream"):
         if plan.flow == "stream":
@@ -1189,8 +1195,8 @@ def build_distributed_fn(
             f"shuffle_plan was derived for {shuffle_plan.num_shards} "
             f"shards but the mesh data axis has {S}")
 
-    sm = shard_map(fn, mesh=mesh, in_specs=(P(data_axis),),
-                   out_specs=out_spec, check_rep=False)
+    sm = jax.shard_map(fn, mesh=mesh, in_specs=(P(data_axis),),
+                       out_specs=out_spec, check_vma=False)
     jitted = jax.jit(sm)
 
     def postprocess(out, *, strict_shuffle: bool = False):
@@ -1406,8 +1412,13 @@ def run_resilient(
             got = _jits[(name,) + _jkey] = jax.jit(fn)
         return got
 
+    # the driver replays shards one at a time on the host's device, so it
+    # slices a host copy: items sharded over an Explicit-axis mesh cannot be
+    # sliced along their sharded axis without an output sharding
+    items_host = jax.tree.map(np.asarray, items)
+
     def shard_slice(s: int):
-        return jax.tree.map(lambda a: a[s * per:(s + 1) * per], items)
+        return jax.tree.map(lambda a: a[s * per:(s + 1) * per], items_host)
 
     # -- the per-shard partial: a pure deterministic function of the shard --
     if flow == "stream":

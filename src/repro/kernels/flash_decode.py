@@ -76,7 +76,7 @@ def flash_decode(
     kv_len: jax.Array,  # [B] int32 valid lengths
     *,
     tile_s: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Single-token GQA decode attention -> [B, H, D] f32."""
     B, H, D = q.shape

@@ -1,151 +1,144 @@
 """jit'd public wrappers for the Pallas kernels.
 
 Each wrapper validates preconditions, picks tile sizes against the VMEM
-budget, and exposes an ``interpret`` flag (True on CPU — this container —
-and False on real TPU, where the Mosaic pipeline compiles the same kernel).
+budget, and resolves ``interpret``: ``None`` compiles the kernel with
+Mosaic on a TPU backend and interprets it anywhere else (the CPU test
+suite).  Nothing else switches a kernel to interpret mode on a TPU.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import combine_scatter as _cs
 from repro.kernels import flash_decode as _fd
-from repro.kernels import onehot_combine as _oc
+from repro.kernels import fold as _fold
 from repro.kernels import radix_partition as _rp
-from repro.kernels import segment_reduce as _sr
+from repro.roofline import analysis as _roofline
 
-#: v5e VMEM budget we tile against (bytes); leave headroom for double buffers.
-VMEM_BUDGET = 96 * 1024 * 1024  # of 128 MiB
+#: bytes of VMEM a kernel's blocks and temporaries are sized against: the
+#: scoped limit every kernel is compiled with (``fold.VMEM_LIMIT_BYTES``).
+VMEM_BUDGET = _fold.VMEM_LIMIT_BYTES
+
+#: default pair tile of the fold kernels (8 lane rows of 128 pairs).
+FOLD_TILE_N = 1024
+
+#: cap of the auto-sized fold key block.  The kernel body is unrolled over
+#: the tile's lane rows, so its code (and Mosaic's compile time: ~3 s at
+#: 4096 keys x 1024 pairs, ~15 s at 16384 on a described v5e) grows with
+#: key block x tile.
+MAX_KEY_BLOCK = 4096
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _interpret_default() -> bool:
-    """interpret-mode default: JAX_PALLAS_INTERPRET env override (the CI
-    kernel job sets it to 1), else interpret everywhere but real TPU."""
-    env = os.environ.get("JAX_PALLAS_INTERPRET", "").strip().lower()
-    if env:
-        return env not in ("0", "false", "no")
-    return not _on_tpu()
+def _interpret(interpret: bool | None) -> bool:
+    return jax.default_backend() != "tpu" if interpret is None else interpret
 
 
 def _pow2_floor(x: int) -> int:
     return 1 << (max(int(x), 1).bit_length() - 1)
 
 
-def auto_key_block(key_space: int, *, d: int = 1, tile_n: int = 512,
-                   tile_d: int = 128, budget: int = VMEM_BUDGET) -> int:
-    """Largest power-of-two key block whose fold working set fits ``budget``.
-
-    Per grid step the one-hot fold keeps a ``[Kb, Td]`` table block, a
-    ``[Tn, Kb]`` one-hot tile, and a ``[Tn, Td]`` value tile resident (f32);
-    half the budget is reserved for the pipeline's double buffers.  Returns
-    ``key_space`` when the whole table fits (no blocking needed)."""
-    td = min(tile_d, max(d, 1))
-    usable = budget // 2 // 4 - tile_n * td  # f32 elems for the Kb terms
-    blk = max(usable // (td + tile_n), 8)
-    blk = _pow2_floor(blk)
-    return key_space if blk >= key_space else blk
+def _fold_tile_n(tile_n: int, n: int) -> int:
+    if tile_n % _fold.LANES:
+        raise ValueError(f"tile_n={tile_n} must be a multiple of "
+                         f"{_fold.LANES} (pairs move in lane rows)")
+    return min(tile_n, _fold.round_up(max(n, 1), _fold.LANES))
 
 
-def onehot_combine(keys, values, key_space, *, tile_n=512, tile_d=128,
-                   interpret=None):
-    """Additive combine via MXU one-hot matmul. [N],[N,D] -> [K,D] f32."""
+def auto_key_block(key_space: int, *, d: int = 1,
+                   tile_n: int = FOLD_TILE_N,
+                   budget: int = VMEM_BUDGET) -> int:
+    """Largest power-of-two key block, at most :data:`MAX_KEY_BLOCK`,
+    whose fold working set (``roofline.stream_working_set_bytes``) fits
+    half of ``budget``; the other half is headroom for Mosaic's own
+    temporaries.  Returns ``key_space`` when the whole table fits in one
+    block (no blocking needed)."""
+    def fits(kb: int) -> bool:
+        return kb <= MAX_KEY_BLOCK and _roofline.stream_working_set_bytes(
+            chunk_pairs=tile_n, key_block=kb, d=d,
+            tile_n=tile_n) <= budget // 2
+
+    if fits(_fold.round_up(key_space, _fold.LANES)):
+        return key_space
+    blk = _fold.LANES
+    while fits(blk * 2):
+        blk *= 2
+    return blk
+
+
+def _fold_block(key_space: int, block_k: int | None, *, d: int,
+                tile_n: int) -> int:
+    """Resolve a fold kernel's key block to a lane multiple and check its
+    working set against the budget."""
+    if block_k is None:
+        block_k = auto_key_block(key_space, d=d, tile_n=tile_n)
+    block_k = _fold.round_up(min(block_k, key_space), _fold.LANES)
+    step = _roofline.stream_working_set_bytes(
+        chunk_pairs=tile_n, key_block=block_k, d=d, tile_n=tile_n)
+    if step > VMEM_BUDGET:
+        raise ValueError(
+            f"key block {block_k} too large for a VMEM-resident fold "
+            f"(needs {step:.0f} bytes/step); shrink block_k")
+    return block_k
+
+
+def chunk_monoid_fold(keys, values, acc, op="add", *, tile_n=FOLD_TILE_N,
+                      block_k=None, interpret=None):
+    """Fold an UNSORTED pair chunk into the carried ``[K, D]`` table.
+
+    [N] keys, [N, D] values, [K, D] acc -> [K, D] f32; rows of keys absent
+    from the chunk keep their ``acc`` values.  Signature matches the
+    streaming collector's ``monoid_fold_fn(keys, mat, acc, op)``.
+    ``block_k`` is the key-block grid axis: one ``[D, block_k]`` table
+    block is VMEM-resident per step (``None`` sizes it against
+    :data:`VMEM_BUDGET`)."""
     if values.ndim != 2:
         raise ValueError("values must be [N, D]")
-    d = values.shape[1]
-    table_bytes = key_space * min(tile_d, d) * 4
-    if table_bytes > VMEM_BUDGET:
-        raise ValueError(
-            f"key_space {key_space} too large for VMEM-resident table; use "
-            "combine_scatter with key blocking or the jnp scatter path")
-    interpret = _interpret_default() if interpret is None else interpret
-    return _oc.onehot_combine(keys, values, key_space, tile_n=tile_n,
-                              tile_d=tile_d, interpret=interpret)
+    if acc.shape[1] != values.shape[1]:
+        raise ValueError(f"acc shape {acc.shape} != (K, {values.shape[1]})")
+    n, d = values.shape
+    if n == 0:  # empty chunk: nothing to fold
+        return acc.astype(jnp.float32)
+    tn = _fold_tile_n(tile_n, n)
+    block_k = _fold_block(acc.shape[0], block_k, d=d, tile_n=tn)
+    return _fold.block_fold(keys, values, acc, op, tile_n=tn,
+                            block_k=block_k, interpret=_interpret(interpret))
 
 
-def onehot_fold(keys, values, acc, key_space=None, *, tile_n=512, tile_d=128,
+def onehot_fold(keys, values, acc, key_space=None, *, tile_n=FOLD_TILE_N,
                 block_k=None, interpret=None):
     """Streaming-chunk additive fold: ``acc + one_hot(keys)ᵀ @ values``.
 
-    [N] keys, [N, D] values, [K, D] f32 acc -> [K, D] f32.  The carried
-    holder table round-trips HBM once per chunk; the one-hot tile lives in
-    VMEM only (grid accumulation).  Signature matches the streaming
-    collector's ``fold_fn(keys, mat, acc)`` when ``key_space`` is omitted.
+    The MXU form of :func:`chunk_monoid_fold` with ``op="add"``; the
+    signature matches the streaming collector's ``fold_fn(keys, mat,
+    acc)``."""
+    if key_space is not None and acc.shape[0] != key_space:
+        raise ValueError(f"acc shape {acc.shape} != ({key_space}, D)")
+    return chunk_monoid_fold(keys, values, acc, "add", tile_n=tile_n,
+                             block_k=block_k, interpret=interpret)
 
-    ``block_k`` adds a key-block grid axis so only one ``[block_k, Td]``
-    table block is VMEM-resident per step; ``None`` auto-sizes it against
-    :data:`VMEM_BUDGET` (``key_space`` itself when the whole table fits).
-    """
+
+def onehot_combine(keys, values, key_space, *, tile_n=FOLD_TILE_N,
+                   interpret=None):
+    """Additive combine via the MXU one-hot fold. [N],[N,D] -> [K,D] f32."""
     if values.ndim != 2:
         raise ValueError("values must be [N, D]")
-    if key_space is None:
-        key_space = acc.shape[0]
-    if acc.shape[0] != key_space or acc.shape[1] != values.shape[1]:
-        raise ValueError(f"acc shape {acc.shape} != ({key_space}, "
-                         f"{values.shape[1]})")
-    n, d = values.shape
-    if n == 0:  # empty chunk: nothing to fold
-        return acc.astype(jnp.float32)
-    tn, td = min(tile_n, max(n, 8)), min(tile_d, d)
-    if block_k is None:
-        block_k = auto_key_block(key_space, d=d, tile_n=tn, tile_d=td)
-    block_k = min(block_k, key_space)
-    # VMEM residents per grid step: the [Kb, Td] table block, the [Tn, Kb]
-    # one-hot temp, and the [Tn, Td] value tile
-    step_bytes = (block_k * td + tn * block_k + tn * td) * 4
-    if step_bytes > VMEM_BUDGET:
-        raise ValueError(
-            f"key block {block_k} too large for VMEM-resident fold "
-            f"(needs {step_bytes} bytes/step); shrink block_k or the chunk")
-    interpret = _interpret_default() if interpret is None else interpret
-    return _oc.onehot_fold(keys, values, acc, key_space, tile_n=tile_n,
-                           tile_d=tile_d, block_k=block_k,
-                           interpret=interpret)
+    acc = jnp.zeros((key_space, values.shape[1]), jnp.float32)
+    return onehot_fold(keys, values, acc, tile_n=tile_n, interpret=interpret)
 
 
-def chunk_monoid_fold(keys, values, acc, op="add", *, tile_n=256,
-                      block_k=None, interpret=None):
-    """Streaming-chunk monoid fold of an UNSORTED pair tile into [K, D] acc.
-
-    Signature matches the streaming collector's
-    ``monoid_fold_fn(keys, mat, acc, op)``; key space is taken from acc.
-    ``block_k`` adds the same key-block grid axis as :func:`onehot_fold`
-    (``None`` auto-sizes against the VMEM budget).
-    """
+def combine_scatter(keys, values, key_space, op="add", *,
+                    tile_n=FOLD_TILE_N, interpret=None):
+    """General monoid combine (masked fold from identity). -> [K, D] f32."""
     if values.ndim != 2:
         raise ValueError("values must be [N, D]")
-    key_space = acc.shape[0]
-    n, d = values.shape
-    if n == 0:  # empty chunk: nothing to fold
-        return acc.astype(jnp.float32)
-    tn = min(tile_n, max(n, 8))
-    if block_k is None:
-        # residents per step: [Kb, D] block + [Tn, Kb] mask + (max/min) the
-        # [Tn, Kb, D] masked expansion
-        per_key = d + tn + (tn * d if op != "add" else 0)
-        usable = VMEM_BUDGET // 2 // 4
-        block_k = _pow2_floor(max(usable // per_key, 8))
-    block_k = min(block_k, key_space)
-    step_elems = block_k * d + tn * block_k
-    if op != "add":
-        step_elems += tn * block_k * d
-    if step_elems * 4 > VMEM_BUDGET:
-        raise ValueError(
-            f"holder block/mask too large for VMEM residency "
-            f"({step_elems * 4} bytes/step); shrink block_k or the chunk")
-    interpret = _interpret_default() if interpret is None else interpret
-    return _sr.chunk_monoid_fold(keys, values, acc, key_space, op,
-                                 tile_n=tile_n, block_k=block_k,
-                                 interpret=interpret)
+    acc = jnp.full((key_space, values.shape[1]), _fold.IDENTITY[op],
+                   jnp.float32)
+    return chunk_monoid_fold(keys, values, acc, op, tile_n=tile_n,
+                             interpret=interpret)
 
 
 def auto_bucket_size(key_space: int, *, d: int = 1, pad_align: int = 256,
@@ -163,21 +156,19 @@ def auto_bucket_size(key_space: int, *, d: int = 1, pad_align: int = 256,
     return key_space if blk >= key_space else blk
 
 
-#: per-level fan-out cap of the hierarchical radix partition: bounds each
-#: level's [Tn, B] one-hot histogram sweep and the per-level region padding
-#: (B·pad_align slots).  One level covers key_space <= fan-out·leaf.
+#: per-level fan-out cap of the hierarchical radix partition.  One level
+#: covers key_space <= fan-out·leaf.
 MAX_RADIX_FANOUT = 32
 
-#: level budget of the hierarchical partition — the knob ISSUE 4's fallback
-#: warning reports against.  3 levels × fan-out 32 × a 16k leaf covers
-#: K = 512M; anything past it degrades to the pure-JAX sorted fold with a
-#: LoweringFallbackWarning instead of silently clamping the bucket count.
+#: level budget of the hierarchical partition.  3 levels × fan-out 32 × a
+#: 16k leaf covers K = 512M; anything past it degrades to the pure-JAX
+#: sorted fold with a LoweringFallbackWarning instead of silently clamping
+#: the bucket count.
 MAX_RADIX_LEVELS = 3
 
-#: leaf bucket cap: the segment_reduce output block is [leaf, D] f32 and on
-#: TPU D tiles at 128 lanes, so a 16k leaf keeps the block at 8 MB even for
-#: wide holders — past this the hierarchy adds a level instead of growing
-#: the leaf out of VMEM.
+#: leaf bucket cap: the segment_reduce output block is [D, leaf] f32, so a
+#: 16k leaf keeps the block small even for wide holders — past this the
+#: hierarchy adds a level instead of growing the leaf out of VMEM.
 LEAF_BUCKET_CAP = 16384
 
 
@@ -225,14 +216,14 @@ def plan_radix_levels(key_space: int, *, d: int = 1, pad_align: int = 256,
                       skew_factor: float | None = None) -> RadixPlan:
     """Pick the leaf bucket and per-level fan-outs for a key space.
 
-    The leaf is the ``segment_reduce`` block (VMEM-resident ``[leaf, D]``,
-    capped at ``leaf_cap``); the leaf count is then decomposed into the
-    fewest levels whose fan-outs stay within ``max_fanout`` (near-uniform
-    powers of two).  A key space needing more than ``max_levels`` levels is
+    The leaf is the ``segment_reduce`` block (VMEM-resident, capped at
+    ``leaf_cap``); the leaf count is then decomposed into the fewest
+    levels whose fan-outs stay within ``max_fanout`` (near-uniform powers
+    of two).  A key space needing more than ``max_levels`` levels is
     reported infeasible — the caller warns and falls back instead of
-    clamping the bucket count past the padded-layout envelope (the old
-    silent degrade).  The budget knobs default to the module constants at
-    call time (patchable in tests).
+    clamping the bucket count past the padded-layout envelope.  The budget
+    knobs default to the module constants at call time (patchable in
+    tests).
 
     ``skew_factor`` (the sampled fixed-width load imbalance from
     ``core/skew.py``, >= 1.0) halves the leaf cap per power of two of
@@ -255,7 +246,6 @@ def plan_radix_levels(key_space: int, *, d: int = 1, pad_align: int = 256,
     num_leaves = -(-key_space // leaf)
     # fan-outs are powers of two, so the cap that actually binds is the
     # pow2 floor of max_fanout — level count and bit split both use it
-    # (a non-pow2 cap must never round a level's fan-out above itself)
     fan_bits = max(max_fanout.bit_length() - 1, 1)
     bits = max(num_leaves - 1, 1).bit_length()
     levels = -(-bits // fan_bits)
@@ -265,7 +255,6 @@ def plan_radix_levels(key_space: int, *, d: int = 1, pad_align: int = 256,
             reason=f"key_space={key_space} needs {levels} radix levels at "
                    f"fan-out {1 << fan_bits} (leaf bucket {leaf}), over "
                    f"the max_levels={max_levels} budget")
-    # near-uniform power-of-two fan-outs covering num_leaves
     base, extra = divmod(bits, levels)
     fanouts = tuple(1 << (base + (1 if i < extra else 0))
                     for i in range(levels))
@@ -282,35 +271,26 @@ def radix_partition(keys, values, key_space, *, bucket_size=None,
     consumes with ``block_k=bucket_size, tile_n=pad_align``.
 
     ``fanouts`` selects the hierarchical multi-pass decomposition (see
-    :func:`plan_radix_levels`); ``None`` keeps the classic single-level
-    two-pass partition."""
+    :func:`plan_radix_levels`); ``None`` is the single-level partition."""
     if values.ndim != 2:
         raise ValueError("values must be [N, D]")
     n, d = values.shape
     if bucket_size is None:
         bucket_size = auto_bucket_size(key_space, d=d, pad_align=pad_align)
     num_buckets = -(-key_space // bucket_size)
-    out_slots = n + num_buckets * pad_align + pad_align
-    cursor_rows = num_buckets
-    if fanouts:
-        # the widest per-level cursor: the leaf level's parent·fanout rows
-        cursor_rows = max(num_buckets, -(-key_space // (
-            bucket_size * fanouts[-1])) * fanouts[-1])
-    if (out_slots * (4 + 4 * d) + cursor_rows * 8) > VMEM_BUDGET:
+    out_slots = _fold.round_up(n + num_buckets * pad_align + pad_align,
+                               pad_align)
+    # the padded output rows stay VMEM-resident (double-buffered), lanes
+    # padded to 128: key column + the value columns
+    rows_bytes = 2 * out_slots * _fold.round_up(1 + d, _fold.LANES) * 4
+    if rows_bytes > VMEM_BUDGET * 3 // 4:
         raise ValueError(
             f"radix partition of {n} pairs x {num_buckets} buckets does not "
             f"fit the VMEM budget; shrink the chunk or grow bucket_size")
-    interpret = _interpret_default() if interpret is None else interpret
-    if fanouts and len(fanouts) > 1:
-        # forwarded as-is: the multi-pass driver enforces its documented
-        # tile_n == pad_align contract (raises on mismatch)
-        return _rp.radix_partition_multi(
-            keys, values, key_space, bucket_size=bucket_size,
-            fanouts=tuple(fanouts), pad_align=pad_align, tile_n=tile_n,
-            interpret=interpret)
-    return _rp.radix_partition(keys, values, key_space,
-                               bucket_size=bucket_size, pad_align=pad_align,
-                               tile_n=tile_n, interpret=interpret)
+    return _rp.radix_partition_multi(
+        keys, values, key_space, bucket_size=bucket_size,
+        fanouts=tuple(fanouts or ()), pad_align=pad_align, tile_n=tile_n,
+        interpret=_interpret(interpret))
 
 
 def sort_segment_fold(keys, values, acc, op="add", *, bucket_size=None,
@@ -324,9 +304,9 @@ def sort_segment_fold(keys, values, acc, op="add", *, bucket_size=None,
     ``block_k=bucket_size`` — presorted segments, no per-pair scatter.
 
     ``bucket_size=None`` derives the level decomposition from
-    :func:`plan_radix_levels` (multi-pass past one bucket sweep); an
-    infeasible plan raises — the engine checks feasibility first and falls
-    back to the pure-JAX sorted fold with a warning."""
+    :func:`plan_radix_levels`; an infeasible plan raises — the engine
+    checks feasibility first and falls back to the pure-JAX sorted fold
+    with a warning."""
     if values.ndim != 2:
         raise ValueError("values must be [N, D]")
     key_space = acc.shape[0]
@@ -344,21 +324,24 @@ def sort_segment_fold(keys, values, acc, op="add", *, bucket_size=None,
     pkeys, pvals, _ = radix_partition(
         keys, values, key_space, bucket_size=bucket_size, fanouts=fanouts,
         pad_align=pad_align, interpret=interpret)
-    chunk = segment_reduce(pkeys, pvals, key_space, op,
-                           tile_n=pad_align, block_k=bucket_size,
-                           interpret=interpret)
-    f = {"add": jnp.add, "max": jnp.maximum, "min": jnp.minimum}[op]
-    return f(acc.astype(jnp.float32), chunk)
+    return _segment_fold(pkeys, pvals, acc, op, tile_n=pad_align,
+                         block_k=bucket_size, interpret=interpret)
 
 
-def combine_scatter(keys, values, key_space, op="add", *, tile_n=256,
-                    interpret=None):
-    """General monoid combine (masked broadcast update). -> [K, D] f32."""
-    if values.ndim != 2:
-        raise ValueError("values must be [N, D]")
-    interpret = _interpret_default() if interpret is None else interpret
-    return _cs.combine_scatter(keys, values, key_space, op, tile_n=tile_n,
-                               interpret=interpret)
+def _segment_fold(sorted_keys, sorted_values, acc, op, *, tile_n, block_k,
+                  interpret):
+    key_space = acc.shape[0]
+    if block_k >= key_space:
+        block_k = _fold.round_up(key_space, _fold.LANES)
+    elif block_k % _fold.LANES and _fold.LANES % block_k:
+        raise ValueError(f"block_k={block_k} neither divides nor is a "
+                         f"multiple of {_fold.LANES}")
+    # aligned blocks nest, so a tile inside one block_k block is inside
+    # one block of the lane-rounded size too
+    block_k = _fold.round_up(block_k, _fold.LANES)
+    return _fold.segment_reduce(sorted_keys, sorted_values, acc, op,
+                                tile_n=tile_n, block_k=block_k,
+                                interpret=_interpret(interpret))
 
 
 def segment_reduce(sorted_keys, sorted_values, key_space, op="add", *,
@@ -367,9 +350,9 @@ def segment_reduce(sorted_keys, sorted_values, key_space, op="add", *,
 
     block_k=None lets the wrapper choose: the smallest power-of-two block
     >= the max in-tile key spread (dynamic data -> computed on host if the
-    keys are concrete, else full key space).
+    keys are concrete, else full key space).  Absent keys read as the
+    monoid identity.
     """
-    interpret = _interpret_default() if interpret is None else interpret
     if block_k is None:
         try:  # concrete keys: exploit sorted locality
             ks = np.asarray(sorted_keys)
@@ -398,9 +381,10 @@ def segment_reduce(sorted_keys, sorted_values, key_space, op="add", *,
             block_k = min(blk, key_space)
         except jax.errors.TracerArrayConversionError:
             block_k = key_space
-    return _sr.segment_reduce(sorted_keys, sorted_values, key_space, op,
-                              tile_n=tile_n, block_k=block_k,
-                              interpret=interpret)
+    acc = jnp.full((key_space, sorted_values.shape[1]), _fold.IDENTITY[op],
+                   jnp.float32)
+    return _segment_fold(sorted_keys, sorted_values, acc, op, tile_n=tile_n,
+                         block_k=block_k, interpret=interpret)
 
 
 def flash_decode(q, k, v, kv_len, *, tile_s=512, interpret=None):
@@ -409,9 +393,8 @@ def flash_decode(q, k, v, kv_len, *, tile_s=512, interpret=None):
     _, S, Hkv, _ = k.shape
     if H % Hkv:
         raise ValueError("H must be a multiple of Hkv (GQA)")
-    interpret = _interpret_default() if interpret is None else interpret
     # keep K/V tile + holder within VMEM
     while tile_s * D * 4 * 2 + (H // Hkv) * (D + 2) * 4 > VMEM_BUDGET:
         tile_s //= 2
     return _fd.flash_decode(q, k, v, kv_len, tile_s=tile_s,
-                            interpret=interpret)
+                            interpret=_interpret(interpret))

@@ -1,44 +1,38 @@
-"""Multi-pass hierarchical radix partition for the sort flow.
+"""Radix partition for the sort flow: pairs grouped into padded buckets.
 
-The sort flow's shuffle on TPU: a chunk of emitted pairs is partitioned by
-key into contiguous bucket regions (bucket ``b`` holds keys in
-``[b·bucket_size, (b+1)·bucket_size)``), each region padded to a multiple
-of ``pad_align`` pairs — exactly the alignment the ``segment_reduce`` kernel
-needs so that every pair tile falls inside ONE aligned K-block of size
-``bucket_size``.  The partition is the chunk-local form of the paper's
-shuffle: pairs move once, bucket-by-bucket, and the reduce consumes
-presorted segments instead of scattering per pair.
+A chunk of emitted pairs is partitioned by key into contiguous bucket
+regions (bucket ``b`` holds keys in ``[b·bucket_size, (b+1)·bucket_size)``),
+each region padded to a multiple of ``pad_align`` slots — the alignment
+``segment_reduce`` needs so that every tile of ``pad_align`` pairs falls
+inside ONE aligned key block.  The partition is the chunk-local form of
+the paper's shuffle: pairs move once, and the reduce consumes presorted
+segments instead of scattering per pair.
 
-One level (``radix_partition``, the K ≤ fan-out·bucket regime):
+Each pass is two kernels:
 
-Pass 1 (``_hist_kernel``): per-bucket pair counts via one-hot column sums —
-a [Tn, B] compare + reduce per tile, MXU/VPU-friendly, no scatter.
+1. ``_hist_kernel`` counts the pairs of each bucket;
+2. ``_scatter_kernel`` copies each pair's row to its bucket's cursor.
 
-Pass 2 (``_scatter_kernel``): sequential grid over pair tiles with a
-VMEM-resident per-bucket cursor carried across tiles.  Each tile computes
-its pairs' destination slots (bucket cursor + stable within-tile rank) and
-stores them with per-pair dynamic writes — VMEM dynamic-update-slices, the
-TPU scatter idiom; the partitioned copy never round-trips HBM between the
-two passes and the reduce.  Within a bucket the original emission order is
-preserved (stable), which the first-element idiom relies on.
+Both walk the pairs one at a time on the scalar unit: the keys of a tile
+sit in SMEM (a ``[1, tile_n]`` block of ``[n_tiles, 1, tile_n]``), the
+bucket cursors live in an SMEM scratch, and the scatter moves one
+``[1, C]`` row per pair with a dynamic sublane offset in VMEM.
+Walking the pairs in order makes every pass stable, which the
+first-element idiom and the bitwise tests rely on.  (A vector form would
+need a gather of the cursors, which Mosaic does not lower.)
 
-Hierarchy (``radix_partition_multi``, K past one bucket sweep): the key
-space is decomposed digit-by-digit over ``fanouts = (B1, …, BL)`` levels
-with per-level ranges ``R_L = bucket_size`` and ``R_{l-1} = R_l · B_l``.
-The top level is the standard two-pass kernel at fan-out ``B1``; every
-inner level re-runs histogram + bucket-scatter *per parent bucket region*:
-the parent layout is ``pad_align``-aligned and ``tile_n == pad_align``, so
-each tile lies inside exactly ONE parent region, the one-hot sweep is
-digit-local (``[Tn, B_l]``, never ``[Tn, num_leaves]``), and the tile's
-counts/cursor updates land in the parent's row block of the composite
-per-level cursor (the cursor carry that makes the batched sweep identical
-to a per-region recursion).  Stability per level makes the final layout
-bitwise equal to a single-level partition at ``bucket_size`` — which is the
-test oracle.
+A pair is one int32 row: the key in column 0, then the values' f32 bits.
+The whole padded output stays VMEM-resident across the grid.
 
-Preconditions (ops.py enforces): the padded output fits the VMEM budget;
-keys are int32 in ``[0, key_space]`` with invalid/pad slots carrying values
-``>= num_buckets·range`` that drop into the trash slot.
+Hierarchy (``radix_partition_multi``): the key space is decomposed over
+``fanouts = (B1, …, BL)`` levels with ranges ``R_L = bucket_size`` and
+``R_{l-1} = R_l · B_l``; level ``l`` partitions by ``key // R_l``.  Every
+pass is stable, so the leaf layout is bitwise the single-level partition
+at ``bucket_size`` — which is the test oracle.
+
+Keys are int32 in ``[0, key_space]``; tile padding carries the cover
+``bucket_size · ΠB``, which maps past every level's last bucket and so
+drops into the trash slot.
 """
 
 from __future__ import annotations
@@ -51,215 +45,95 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.fold import compiler_params
 
-def _hist_kernel(keys_ref, out_ref, *, bucket_size: int, num_buckets: int):
-    i = pl.program_id(0)
 
-    @pl.when(i == 0)
+def _bucket(key, rng: int, num_buckets: int):
+    """Bucket id of ``key`` at range ``rng``; ``num_buckets`` means drop."""
+    b = key >> (rng.bit_length() - 1) if rng & (rng - 1) == 0 else key // rng
+    return jnp.minimum(b, num_buckets)
+
+
+def _hist_kernel(keys_ref, hist_ref, *, rng: int, num_buckets: int):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        def zero(b, c):
+            hist_ref[b] = 0
+            return c
 
-    keys = keys_ref[...]  # [Tn] int32; sentinel -> bucket >= num_buckets
-    b = keys // bucket_size
-    iota = lax.broadcasted_iota(jnp.int32, (keys.shape[0], num_buckets), 1)
-    hit = (b[:, None] == iota)  # sentinel rows are all-zero
-    out_ref[...] += jnp.sum(hit.astype(jnp.int32), axis=0)
+        lax.fori_loop(0, num_buckets + 1, zero, 0)
+
+    def count(j, c):
+        b = _bucket(keys_ref[0, j], rng, num_buckets)
+        hist_ref[b] += 1
+        return c
+
+    lax.fori_loop(0, keys_ref.shape[1], count, 0)
 
 
-def _scatter_kernel(starts_ref, keys_ref, vals_ref, out_keys_ref,
-                    out_vals_ref, cursor_ref, *, bucket_size: int,
-                    num_buckets: int, out_slots: int, sentinel: int):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+def _scatter_kernel(starts_ref, keys_ref, rows_ref, out_ref, cursor_ref, *,
+                    rng: int, num_buckets: int, pad_key: int):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        cursor_ref[...] = starts_ref[...]
-        # pad/trash slots must read as dropped pairs downstream
-        out_keys_ref[...] = jnp.full_like(out_keys_ref, sentinel)
-        out_vals_ref[...] = jnp.zeros_like(out_vals_ref)
+        def seed(b, c):
+            cursor_ref[b] = starts_ref[b]
+            return c
 
-    keys = keys_ref[...]  # [Tn]
-    vals = vals_ref[...]  # [Tn, D]
-    tn = keys.shape[0]
-    b = keys // bucket_size
-    valid = b < num_buckets
-    bc = jnp.minimum(b, num_buckets - 1)
+        lax.fori_loop(0, num_buckets, seed, 0)
+        cursor_ref[num_buckets] = out_ref.shape[0] - 1  # the trash slot
+        lane = lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+        out_ref[...] = jnp.where(lane == 0, pad_key, 0)
 
-    # stable within-tile rank: pairs of the same bucket keep arrival order
-    iota_n = lax.broadcasted_iota(jnp.int32, (tn, tn), 0)
-    same = (bc[None, :] == bc[:, None]) & (iota_n.T <= iota_n)
-    rank = jnp.sum(same & valid[None, :], axis=1) - 1
+    def move(j, c):
+        b = _bucket(keys_ref[0, j], rng, num_buckets)
+        dst = cursor_ref[b]
+        cursor_ref[b] = dst + jnp.where(b < num_buckets, 1, 0)
+        out_ref[pl.ds(dst, 1), :] = rows_ref[pl.ds(j, 1), :]
+        return c
 
-    cursor = cursor_ref[...]
-    dst = jnp.where(valid, cursor[bc] + rank, out_slots - 1)  # trash slot
-
-    def write(j, _):
-        d = dst[j]
-        out_keys_ref[pl.ds(d, 1)] = keys[j][None]
-        out_vals_ref[pl.ds(d, 1), :] = vals[j][None, :]
-        return 0
-
-    lax.fori_loop(0, tn, write, 0)
-
-    iota_b = lax.broadcasted_iota(jnp.int32, (tn, num_buckets), 1)
-    tile_counts = jnp.sum(((b[:, None] == iota_b) &
-                           valid[:, None]).astype(jnp.int32), axis=0)
-    cursor_ref[...] = cursor + tile_counts
+    lax.fori_loop(0, keys_ref.shape[1], move, 0)
 
 
-def _hist_level_kernel(keys_ref, out_ref, *, range_child: int, fanout: int,
-                       num_buckets: int):
-    """Inner-level histogram: region-local one-hot, composite accumulate.
+def _partition_pass(keys, rows, *, rng: int, num_buckets: int, n_real: int,
+                    pad_align: int, tile_n: int, pad_key: int,
+                    interpret: bool):
+    """One stable pass: ``[Np]`` keys + ``[Np, C]`` rows -> padded layout.
 
-    Tiles of the parent-partitioned input lie entirely inside ONE parent
-    bucket region (regions are ``pad_align``-aligned and tile_n ==
-    pad_align), so the one-hot sweep is only ``[Tn, fanout]`` wide and the
-    tile's digit counts accumulate into the parent's row block of the
-    composite ``[num_parents·fanout]`` histogram."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    keys = keys_ref[...]  # [Tn]
-    b = keys // range_child  # composite bucket id at this level
-    valid = b < num_buckets  # pads/trash carry >= num_buckets·range_child
-    digit = b % fanout
-    # every valid key in the tile shares one parent region
-    p = jnp.max(jnp.where(valid, b // fanout, 0))
-    iota = lax.broadcasted_iota(jnp.int32, (keys.shape[0], fanout), 1)
-    hit = (digit[:, None] == iota) & valid[:, None]
-    out_ref[pl.ds(p * fanout, fanout)] += jnp.sum(hit.astype(jnp.int32),
-                                                  axis=0)
-
-
-def _scatter_level_kernel(starts_ref, keys_ref, vals_ref, out_keys_ref,
-                          out_vals_ref, cursor_ref, *, range_child: int,
-                          fanout: int, num_buckets: int, out_slots: int,
-                          sentinel: int):
-    """Inner-level bucket scatter: composite cursor, digit-local update.
-
-    Same per-pair dynamic VMEM stores as ``_scatter_kernel``; the cursor is
-    the full composite ``[num_parents·fanout]`` array (per-level cursor
-    carry), but each tile only advances its parent's ``fanout`` rows — the
-    batched equivalent of re-running the scatter per parent region."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        cursor_ref[...] = starts_ref[...]
-        out_keys_ref[...] = jnp.full_like(out_keys_ref, sentinel)
-        out_vals_ref[...] = jnp.zeros_like(out_vals_ref)
-
-    keys = keys_ref[...]  # [Tn]
-    vals = vals_ref[...]  # [Tn, D]
-    tn = keys.shape[0]
-    b = keys // range_child
-    valid = b < num_buckets
-    bc = jnp.minimum(b, num_buckets - 1)
-
-    # stable within-tile rank over composite ids (same as the top level)
-    iota_n = lax.broadcasted_iota(jnp.int32, (tn, tn), 0)
-    same = (bc[None, :] == bc[:, None]) & (iota_n.T <= iota_n)
-    rank = jnp.sum(same & valid[None, :], axis=1) - 1
-
-    cursor = cursor_ref[...]
-    dst = jnp.where(valid, cursor[bc] + rank, out_slots - 1)  # trash slot
-
-    def write(j, _):
-        d = dst[j]
-        out_keys_ref[pl.ds(d, 1)] = keys[j][None]
-        out_vals_ref[pl.ds(d, 1), :] = vals[j][None, :]
-        return 0
-
-    lax.fori_loop(0, tn, write, 0)
-
-    p = jnp.max(jnp.where(valid, b // fanout, 0))
-    digit = b % fanout
-    iota_f = lax.broadcasted_iota(jnp.int32, (tn, fanout), 1)
-    counts = jnp.sum(((digit[:, None] == iota_f) &
-                      valid[:, None]).astype(jnp.int32), axis=0)
-    cur = cursor_ref[pl.ds(p * fanout, fanout)]
-    cursor_ref[pl.ds(p * fanout, fanout)] = cur + counts
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "key_space", "bucket_size", "pad_align", "tile_n", "interpret"))
-def radix_partition(
-    keys: jax.Array,
-    values: jax.Array,
-    key_space: int,
-    *,
-    bucket_size: int,
-    pad_align: int = 256,
-    tile_n: int = 256,
-    interpret: bool = True,
-):
-    """Partition [N] keys + [N, D] values into padded bucket regions.
-
-    Returns ``(pkeys [Np], pvals [Np, D], starts [B])`` with
-    ``Np = N + B·pad_align + pad_align`` (static): bucket ``b`` occupies
-    ``pkeys[starts[b] : starts[b] + padded_count[b]]``, every region is a
-    ``pad_align`` multiple, pad slots carry the sentinel ``key_space`` and
-    the final ``pad_align`` slots are the invalid-pair trash region.
-    """
-    n = keys.shape[0]
-    d = values.shape[1]
-    num_buckets = -(-key_space // bucket_size)
-    tile_n = min(tile_n, max(n, 8))
-
-    pad_n = (-n) % tile_n
-    # tile padding must be INVALID (trash-bound), not the sentinel: when
-    # key_space is not a bucket_size multiple the sentinel still maps into
-    # the last bucket (harmless for real sentinel pairs — their rows are
-    # cropped downstream — but padding must not consume bucket slots).
-    invalid = num_buckets * bucket_size
-    keys_p = jnp.pad(keys, (0, pad_n), constant_values=invalid)
-    vals_p = jnp.pad(values.astype(jnp.float32), ((0, pad_n), (0, 0)))
-    n_tiles = keys_p.shape[0] // tile_n
-
+    Returns ``(rows [out_slots, C], starts [num_buckets])``."""
+    n_tiles = keys.shape[0] // tile_n
+    keys = keys.reshape(n_tiles, 1, tile_n)  # SMEM tiles of any length
+    smem_tile = pl.BlockSpec((None, 1, tile_n), lambda i: (i, 0, 0),
+                             memory_space=pltpu.SMEM)
     hist = pl.pallas_call(
-        functools.partial(_hist_kernel, bucket_size=bucket_size,
-                          num_buckets=num_buckets),
+        functools.partial(_hist_kernel, rng=rng, num_buckets=num_buckets),
         grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((tile_n,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((num_buckets,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((num_buckets,), jnp.int32),
+        in_specs=[smem_tile],
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+        out_shape=jax.ShapeDtypeStruct((num_buckets + 1,), jnp.int32),
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(keys_p)
-
-    padded = -(-hist // pad_align) * pad_align  # per-bucket padded counts
-    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                              jnp.cumsum(padded)[:-1].astype(jnp.int32)])
-    out_slots = n + num_buckets * pad_align + pad_align  # + trash region
+    )(keys)[:num_buckets]
+    padded = -(-hist // pad_align) * pad_align
+    starts = (jnp.cumsum(padded) - padded).astype(jnp.int32)
+    out_slots = n_real + num_buckets * pad_align + pad_align  # + trash
     out_slots += (-out_slots) % pad_align
-
-    pkeys, pvals = pl.pallas_call(
-        functools.partial(_scatter_kernel, bucket_size=bucket_size,
-                          num_buckets=num_buckets, out_slots=out_slots,
-                          sentinel=key_space),
+    c = rows.shape[1]
+    out = pl.pallas_call(
+        functools.partial(_scatter_kernel, rng=rng, num_buckets=num_buckets,
+                          pad_key=pad_key),
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((num_buckets,), lambda i: (0,)),
-            pl.BlockSpec((tile_n,), lambda i: (i,)),
-            pl.BlockSpec((tile_n, d), lambda i: (i, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            smem_tile,
+            pl.BlockSpec((tile_n, c), lambda i: (i, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((out_slots,), lambda i: (0,)),
-            pl.BlockSpec((out_slots, d), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((out_slots,), jnp.int32),
-            jax.ShapeDtypeStruct((out_slots, d), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((num_buckets,), jnp.int32)],
+        out_specs=pl.BlockSpec((out_slots, c), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((out_slots, c), jnp.int32),
+        scratch_shapes=[pltpu.SMEM((num_buckets + 1,), jnp.int32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(starts, keys_p, vals_p)
-    # trash/pad slots may carry the invalid pad constant — normalize every
-    # dropped slot to the one sentinel the consumers check for
-    pkeys = jnp.minimum(pkeys, key_space)
-    return pkeys, pvals, starts
+    )(starts, keys, rows)
+    return out, starts
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -272,98 +146,41 @@ def radix_partition_multi(
     *,
     bucket_size: int,
     fanouts: tuple[int, ...],
-    pad_align: int = 256,
-    tile_n: int = 256,
-    interpret: bool = True,
+    pad_align: int,
+    tile_n: int,
+    interpret: bool,
 ):
-    """Hierarchical partition into padded LEAF bucket regions.
+    """Partition ``[N]`` keys + ``[N, D]`` values into padded leaf buckets.
 
-    ``fanouts = (B1, …, BL)`` decomposes the key space digit-by-digit:
-    level ``l`` partitions by ``key // R_l`` with ``R_L = bucket_size`` and
-    ``R_{l-1} = R_l · B_l`` (so ``bucket_size · ΠB >= key_space``).  Level 1
-    is the standard two-pass kernel; inner levels run the region-local
-    kernels with the composite per-level cursor carry.  The final layout is
-    bitwise identical to ``radix_partition(bucket_size=bucket_size)`` —
-    leaf ``b`` at ``starts[b]``, regions ``pad_align`` multiples, sentinel
-    pads, trailing trash region — without any level's one-hot sweep or
-    per-level padding exceeding its fan-out.
-    """
-    if len(fanouts) <= 1:
-        return radix_partition(keys, values, key_space,
-                               bucket_size=bucket_size, pad_align=pad_align,
-                               tile_n=tile_n, interpret=interpret)
-    if tile_n != pad_align:
-        raise ValueError(
-            "radix_partition_multi needs tile_n == pad_align so inner-level "
-            "tiles never straddle a parent bucket region")
-    n = keys.shape[0]
-    d = values.shape[1]
-    # per-level ranges R_1 > … > R_L = bucket_size; the invalid/pad value is
-    # the cover bucket_size·ΠB at EVERY level (w_l · R_l is level-invariant)
+    Returns ``(pkeys [Np], pvals [Np, D], starts [B])``: leaf bucket ``b``
+    occupies ``pkeys[starts[b] : starts[b] + padded_count[b]]``, every
+    region is a ``pad_align`` multiple, pad slots carry the sentinel
+    ``key_space`` and the final ``pad_align`` slots are the trash region
+    for invalid pairs.  ``fanouts=()`` or one entry is the single-level
+    partition; more entries run one stable pass per level."""
+    n, d = values.shape
+    num_leaves = -(-key_space // bucket_size)
     ranges = [bucket_size]
-    for B in reversed(fanouts[1:]):
-        ranges.insert(0, ranges[0] * B)
-    cover = ranges[0] * fanouts[0]
+    for fan in reversed(fanouts[1:]):
+        ranges.insert(0, ranges[0] * fan)
+    cover = ranges[0] * (fanouts[0] if fanouts else num_leaves)
     if cover < key_space:
         raise ValueError(f"fanouts {fanouts} x bucket_size {bucket_size} "
                          f"cover {cover} < key_space {key_space}")
-
-    pad_n = (-n) % tile_n
-    pkeys = jnp.pad(keys, (0, pad_n), constant_values=cover)
-    pvals = jnp.pad(values.astype(jnp.float32), ((0, pad_n), (0, 0)))
-
-    nb_parent = 1  # real bucket count of the previous level
+    tile_n = min(tile_n, max(n, 8))
+    pad = (-n) % tile_n
+    keys_p = jnp.pad(keys.astype(jnp.int32), (0, pad), constant_values=cover)
+    bits = lax.bitcast_convert_type(values.astype(jnp.float32), jnp.int32)
+    rows = jnp.concatenate([keys_p[:, None],
+                            jnp.pad(bits, ((0, pad), (0, 0)))], axis=1)
     starts = None
-    for lvl, B in enumerate(fanouts):
-        rng = ranges[lvl]
-        nb = -(-key_space // rng)  # real buckets at this level
-        # cursor/histogram rows: parent-row blocks of B rows each.  Level 1
-        # has ONE parent (the whole chunk), so the level kernels reduce
-        # exactly to the classic top-level sweep (digit == bucket id,
-        # parent == 0) — no tile-alignment precondition needed there.
-        width = nb if lvl == 0 else nb_parent * B
-        fanout = nb if lvl == 0 else B
-        n_tiles = pkeys.shape[0] // tile_n
-        hist = pl.pallas_call(
-            functools.partial(_hist_level_kernel, range_child=rng,
-                              fanout=fanout, num_buckets=nb),
-            grid=(n_tiles,),
-            in_specs=[pl.BlockSpec((tile_n,), lambda i: (i,))],
-            out_specs=pl.BlockSpec((width,), lambda i: (0,)),
-            out_shape=jax.ShapeDtypeStruct((width,), jnp.int32),
-            interpret=interpret,
-        )(pkeys)
-
-        padded = -(-hist // pad_align) * pad_align
-        starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                  jnp.cumsum(padded)[:-1].astype(jnp.int32)])
-        out_slots = n + nb * pad_align + pad_align  # + trash region
-        out_slots += (-out_slots) % pad_align
-
-        scatter_fn = functools.partial(
-            _scatter_level_kernel, range_child=rng, fanout=fanout,
-            num_buckets=nb, out_slots=out_slots, sentinel=cover)
-        pkeys, pvals = pl.pallas_call(
-            scatter_fn,
-            grid=(n_tiles,),
-            in_specs=[
-                pl.BlockSpec((width,), lambda i: (0,)),
-                pl.BlockSpec((tile_n,), lambda i: (i,)),
-                pl.BlockSpec((tile_n, d), lambda i: (i, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((out_slots,), lambda i: (0,)),
-                pl.BlockSpec((out_slots, d), lambda i: (0, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((out_slots,), jnp.int32),
-                jax.ShapeDtypeStruct((out_slots, d), jnp.float32),
-            ],
-            scratch_shapes=[pltpu.VMEM((width,), jnp.int32)],
-            interpret=interpret,
-        )(starts, pkeys, pvals)
-        nb_parent = nb
-
-    # normalize once, at the leaf layout (same contract as single level)
-    pkeys = jnp.minimum(pkeys, key_space)
-    return pkeys, pvals, starts[: -(-key_space // bucket_size)]
+    for rng in ranges:
+        rows, starts = _partition_pass(
+            rows[:, 0], rows, rng=rng, num_buckets=-(-key_space // rng),
+            n_real=n, pad_align=pad_align, tile_n=tile_n, pad_key=cover,
+            interpret=interpret)
+        tile_n = pad_align  # later passes walk the padded layout
+    # every dropped slot (pads, trash) reads as THE sentinel downstream
+    pkeys = jnp.minimum(rows[:, 0], key_space)
+    pvals = lax.bitcast_convert_type(rows[:, 1:], jnp.float32)
+    return pkeys, pvals, starts[:num_leaves]

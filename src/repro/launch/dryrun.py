@@ -1,12 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape) on the production
 meshes and derive the roofline terms (EXPERIMENTS.md §Dry-run / §Roofline).
 
-The two lines above MUST precede every other import — jax locks the device
-count at first init.  Do not replicate them in conftest/pyproject: smoke
-tests and benchmarks must see one device.
+``main()`` asks for 512 fake CPU devices through ``XLA_FLAGS`` before JAX
+initializes its backend; importing this module sets nothing, so the
+``build_cell`` helpers can be used on any mesh.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch llama3-8b \
@@ -17,6 +14,7 @@ Usage:
 
 import argparse
 import json
+import os
 import time
 import traceback
 from functools import partial
@@ -235,6 +233,9 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *,
 
 
 def main():
+    # before the first device query: JAX fixes the device count when its
+    # backend initializes
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

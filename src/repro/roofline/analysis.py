@@ -11,7 +11,8 @@ standard algorithmic factors (ring all-reduce 2(n−1)/n, all-gather /
 reduce-scatter (n−1)/n, all-to-all (n−1)/n, permute 1) with the group size n
 parsed from ``replica_groups``.
 
-Hardware model (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware model: the published per-chip peaks in :data:`PEAKS`, keyed by
+``jax.Device.device_kind``.
 """
 
 from __future__ import annotations
@@ -20,9 +21,44 @@ import dataclasses
 import json
 import re
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published peaks of one chip."""
+
+    flops: float  # bf16 FLOP/s
+    hbm_bw: float  # HBM bytes/s
+    hbm_bytes: float  # HBM capacity
+    link_bw: float  # interconnect bytes/s per link
+
+
+#: per-chip peaks by ``device_kind``.  "TPU v5 lite" is the TPU v5e; source:
+#: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB of HBM at
+#: 819 GB/s, 1,600 Gbit/s of interconnect per chip over 4 links (50 GB/s
+#: each).
+PEAKS = {
+    "TPU v5 lite": DevicePeaks(flops=197e12, hbm_bw=819e9, hbm_bytes=16e9,
+                               link_bw=50e9),
+}
+
+#: the chip the dry-run rooflines and TPU-profile defaults describe.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str | None = None) -> DevicePeaks:
+    """Peaks of ``device_kind`` (default: the running device).  A kind not
+    in :data:`PEAKS` is an error, never a v5e default."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add its "
+            f"row, with its source, to roofline.analysis.PEAKS") from None
+
 
 _COLL_RE = re.compile(
     r"=\s*([a-z0-9_\[\]\(\),\s]*?)\s*"
@@ -88,18 +124,19 @@ class Roofline:
     collective_ops: dict
     model_flops: float  # 6·N·D (global), for the usefulness ratio
     peak_memory_bytes: float
+    device_kind: str = DRYRUN_DEVICE_KIND
 
     @property
     def compute_s(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / peaks(self.device_kind).flops
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_accessed / HBM_BW
+        return self.bytes_accessed / peaks(self.device_kind).hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / LINK_BW
+        return self.collective_bytes / peaks(self.device_kind).link_bw
 
     @property
     def dominant(self) -> str:
@@ -121,7 +158,7 @@ class Roofline:
     @property
     def mfu(self) -> float:
         """Model-flops utilization at the roofline step time."""
-        denom = self.step_s * self.chips * PEAK_FLOPS
+        denom = self.step_s * self.chips * peaks(self.device_kind).flops
         return self.model_flops / denom if denom else 0.0
 
     def to_dict(self) -> dict:
@@ -312,20 +349,24 @@ def stream_working_set_bytes(
     chunk_pairs: int,
     key_block: int,
     d: int = 1,
-    tile_n: int = 512,
-    tile_d: int = 128,
+    tile_n: int = 1024,
 ) -> float:
-    """Per-grid-step VMEM residency model of the key-blocked one-hot fold.
+    """Per-grid-step VMEM residency model of the key-blocked fold kernel
+    (``kernels/fold.py``), in bytes.
 
-    The Pallas fold kernel keeps three residents per step: the
-    ``[key_block, tile_d]`` holder-table block, the ``[tile_n, key_block]``
-    one-hot tile, and the ``[tile_n, tile_d]`` value tile (all f32).  The
-    autotuner sizes ``key_block`` so this fits the VMEM budget with
-    double-buffering headroom; ``d`` is the flattened holder width
-    (channels + the counts column)."""
-    tn = min(tile_n, max(chunk_pairs, 8))
-    td = min(tile_d, max(d, 1))
-    return 4.0 * (key_block * td + tn * key_block + tn * td)
+    Per step the kernel keeps, double-buffered, the ``[D, key_block]``
+    accumulator block and output block, the ``[D, tile_n]`` value tile
+    and the ``[R, 128]`` key tile (sublanes padded to 8), plus about
+    three ``[128, key_block]`` one-hot temporaries (iota, hit mask,
+    one-hot).  Lanes pad to 128 and sublanes to 8; ``d`` is the
+    flattened holder width (channels + the counts column)."""
+    lanes = 128
+    tn = min(tile_n, -(-max(chunk_pairs, 1) // lanes) * lanes)
+    kb = -(-max(key_block, 1) // lanes) * lanes
+    dp = -(-max(d, 1) // 8) * 8
+    key_rows = max(tn // lanes, 8)
+    return 4.0 * (4 * dp * kb + 2 * dp * tn + 2 * key_rows * lanes
+                  + 3 * lanes * kb)
 
 
 def pipeline_handoff_bytes(key_space: int, *, value_bytes: int = 4,

@@ -21,8 +21,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 #       reduce-only workloads still pass.  Tests that assert the auto
 #       resolution itself skip under the override (they declare it).
 #   REPRO_TEST_KERNELS=1
-#       flips the use_kernels default to True (combine with
-#       JAX_PALLAS_INTERPRET=1 to exercise the Pallas kernel lowerings).
+#       flips the use_kernels default to True (on the CPU backend the
+#       Pallas kernels run in interpret mode).
 #   REPRO_TEST_SKEW=zipf
 #       flips the ShuffleOptions.skew default to "auto", so every
 #       distributed/resilient run in the suite goes through the sampled-

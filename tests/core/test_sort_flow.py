@@ -501,11 +501,22 @@ def test_tpu_profile_moves_crossover_right():
     architecture) — the radix partition's per-pair scalar stores only pay
     off when K reaches the few-hundred-k range."""
     cpu = cm.choose_flow(n_pairs=1024, key_space=32768, backend="cpu")
-    tpu = cm.choose_flow(n_pairs=1024, key_space=32768, backend="tpu")
+    v5e = "TPU v5 lite"  # pricing a TPU from this host names the chip
+    tpu = cm.choose_flow(n_pairs=1024, key_space=32768, backend="tpu",
+                         device_kind=v5e)
     assert cpu.chosen == "sort"
     assert tpu.chosen == "stream"
-    tpu_big = cm.choose_flow(n_pairs=1024, key_space=1 << 21, backend="tpu")
+    tpu_big = cm.choose_flow(n_pairs=1024, key_space=1 << 21, backend="tpu",
+                             device_kind=v5e)
     assert tpu_big.chosen == "sort"
+
+
+def test_tpu_profile_refuses_unknown_device_kind():
+    """A chip without a row in the peaks table is an error, never priced
+    as a v5e."""
+    with pytest.raises(ValueError, match="no published peaks"):
+        cm.choose_flow(n_pairs=1024, key_space=32768, backend="tpu",
+                       device_kind="TPU v4")
 
 
 # ---------------------------------------------------------------------------

@@ -266,6 +266,19 @@ def test_scatter_fallback_beyond_fused_regime_warns():
     assert sci.mode == "scatter"
 
 
+@pytest.mark.parametrize("flow", ["stream", "sort"])
+def test_kernels_that_cannot_carry_the_holder_warn(tokens, flow):
+    """use_kernels=True with int32 holder tables: the Pallas kernels carry
+    f32 tables only, so the fold runs in pure JAX — and says so."""
+    from repro.core import LoweringFallbackWarning
+
+    with pytest.warns(LoweringFallbackWarning, match="only f32 holder"):
+        res = MapReduce(WordCount(), flow=flow,
+                        use_kernels=True).run(jnp.asarray(tokens))
+    want = np.bincount(tokens.reshape(-1), minlength=VOCAB)
+    np.testing.assert_array_equal(np.asarray(res.values), want)
+
+
 def test_int_tables_accumulate_exactly_per_chunk():
     """Integer holder tables accumulate in their own dtype across chunks
     (per-chunk f32 deltas are exact; the running sum is int32)."""

@@ -121,7 +121,6 @@ def test_error_feedback_single_step_residual_is_quant_error():
 def test_compressed_psum_tracks_exact_psum():
     """shard_map all-gather path: the int8-on-the-wire sum equals the
     exact psum within the sum of per-shard quantization bounds."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     n_dev = jax.local_device_count()
@@ -132,14 +131,14 @@ def test_compressed_psum_tracks_exact_psum():
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((n_dev, 32)), jnp.float32)
 
-    exact = shard_map(
+    exact = jax.shard_map(
         lambda v: jax.lax.psum(v, "d"), mesh=mesh,
         in_specs=P("d"), out_specs=P())(x)
-    # check_rep can't see through the all_gather+sum, but the result IS
-    # replicated (every shard gathers the same int8+scale rows)
-    approx = shard_map(
+    # the replication check can't see through the all_gather+sum, but the
+    # result IS replicated (every shard gathers the same int8+scale rows)
+    approx = jax.shard_map(
         lambda v: comp.compressed_psum(v[0], "d"), mesh=mesh,
-        in_specs=P("d"), out_specs=P(), check_rep=False)(x)
+        in_specs=P("d"), out_specs=P(), check_vma=False)(x)
     bound = sum(_bound(x[i]) for i in range(n_dev))
     assert np.abs(np.asarray(approx) - np.asarray(exact)).max() \
         <= bound + 1e-6

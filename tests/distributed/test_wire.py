@@ -321,7 +321,7 @@ def test_cost_model_wire_term_matches_wire_layer():
             key_space=K, num_shards=S, n_pairs=per,
             value_avals=jax.ShapeDtypeStruct((per, 1), jnp.int32),
             codec=codec)
-        want = wire.wire_bytes_per_shard(fmt) / roofline.LINK_BW
+        want = wire.wire_bytes_per_shard(fmt) * cm.CPU_COEFF["wire"]
         assert dict(fc.terms)["wire"] == pytest.approx(want)
         assert roofline.shuffle_wire_bytes(
             codec, n_pairs=n, key_space=K,

@@ -314,15 +314,15 @@ def test_compressed_psum_wire_dtype():
     out = run_with_devices("""
         import numpy as np, jax, jax.numpy as jnp
         from functools import partial
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import compressed_psum
 
         mesh = jax.make_mesh((4,), ("d",))
         x = jnp.asarray(np.random.default_rng(0).standard_normal((4, 64)),
                         jnp.float32)
-        f = shard_map(lambda a: compressed_psum(a[0], "d"), mesh=mesh,
-                      in_specs=(P("d"),), out_specs=P(), check_rep=False)
+        f = jax.shard_map(lambda a: compressed_psum(a[0], "d"), mesh=mesh,
+                          in_specs=(P("d"),), out_specs=P(),
+                          check_vma=False)
         with mesh:
             got = jax.jit(f)(x)
             txt = jax.jit(f).lower(x).compile().as_text()

@@ -117,10 +117,28 @@ def test_flash_decode_matches_monoid():
                                atol=1e-4)
 
 
+@pytest.mark.parametrize("backend,want", [("tpu", False), ("cpu", True)])
+def test_interpret_follows_the_backend_only(monkeypatch, backend, want):
+    """Kernels interpret exactly off a TPU; no environment variable
+    switches a TPU run to interpret mode."""
+    monkeypatch.setenv("JAX_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ops._interpret(None) is want
+    assert ops._interpret(not want) is (not want)  # explicit choice wins
+
+
 def test_onehot_vmem_guard():
+    """A key space past one VMEM-resident block is split into key blocks;
+    an explicit block past the budget still trips the guard."""
+    keys = jnp.asarray([0, 5, 2 ** 21 - 1, 2 ** 21], jnp.int32)
+    got = np.asarray(ops.onehot_combine(keys, jnp.ones((4, 2)), 2 ** 21))
+    assert got.shape == (2 ** 21, 2)
+    assert got[[0, 5, 2 ** 21 - 1]].tolist() == [[1, 1]] * 3
+    assert got.sum() == 6
     with pytest.raises(ValueError, match="VMEM"):
-        ops.onehot_combine(jnp.zeros(8, jnp.int32), jnp.zeros((8, 256)),
-                           key_space=2 ** 21)
+        ops.chunk_monoid_fold(keys, jnp.ones((4, 256)),
+                              jnp.zeros((2 ** 21, 256)), "max",
+                              block_k=2 ** 21)
 
 
 @pytest.mark.parametrize("n,d,k", [(16, 4, 5), (100, 8, 64), (513, 2, 300)])
@@ -335,11 +353,24 @@ def test_plan_radix_levels_multi_level_and_budget():
 
 
 def test_radix_partition_multi_requires_aligned_tiles():
-    with pytest.raises(ValueError, match="cover|tile_n"):
-        from repro.kernels import radix_partition as rp
+    """Each pass walks its pairs in order, so a first-pass tile need not
+    match pad_align; fan-outs that do not cover the key space raise."""
+    from repro.kernels import radix_partition as rp
+
+    keys = RNG.integers(0, 257, size=64).astype(np.int32)
+    vals = _vals((64, 1), np.float32)
+    got_k, _, got_s = rp.radix_partition_multi(
+        jnp.asarray(keys), jnp.asarray(vals), 256, bucket_size=16,
+        fanouts=(4, 4), pad_align=16, tile_n=32, interpret=True)
+    want_k, _, want_s = ref.radix_partition(
+        jnp.asarray(keys), jnp.asarray(vals), 256, bucket_size=16,
+        pad_align=16)
+    np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
+    np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
+    with pytest.raises(ValueError, match="cover"):
         rp.radix_partition_multi(
-            jnp.zeros(64, jnp.int32), jnp.zeros((64, 1), jnp.float32),
-            256, bucket_size=16, fanouts=(4, 4), pad_align=16, tile_n=32)
+            jnp.asarray(keys), jnp.asarray(vals), 256, bucket_size=16,
+            fanouts=(4, 2), pad_align=16, tile_n=16, interpret=True)
 
 
 def test_fold_kernel_autoblocks_past_vmem_budget():

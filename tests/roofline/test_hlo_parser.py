@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.roofline import hlo_parser
+from repro.roofline import analysis, hlo_parser
 from repro.roofline.analysis import collective_stats
 
 
@@ -43,6 +43,15 @@ def test_bytes_dus_not_full_buffer():
     c = hlo_parser.analyze_text(
         jax.jit(f, donate_argnums=(0,)).lower(buf, upd).compile().as_text())
     assert c.bytes_accessed < 1 << 16  # slice-sized, not 8 MiB
+
+
+def test_peaks_keyed_by_device_kind():
+    v5e = analysis.peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9, 16e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        analysis.peaks("TPU v4")
+    with pytest.raises(ValueError, match="no published peaks"):
+        analysis.peaks()  # the test host's device: a CPU has no row
 
 
 def test_wire_factors():

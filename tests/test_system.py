@@ -26,6 +26,28 @@ class WordCount(MapReduceApp):
         return jnp.sum(values)
 
 
+def test_compile_cache_dir(monkeypatch):
+    """The persistent compile cache lands in one fixed, git-ignored path
+    inside the checkout, unless JAX_COMPILATION_CACHE_DIR names one."""
+    from pathlib import Path
+
+    from repro import compile_cache
+
+    set_dirs = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, val: set_dirs.append((name, val)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    assert set_dirs == []  # JAX reads the variable itself
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    root = Path(__file__).resolve().parents[1]
+    want = str(root / ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert set_dirs == [("jax_compilation_cache_dir", want)]
+    assert ".jax_cache/" in (root / ".gitignore").read_text().splitlines()
+
+
 def test_paper_story_end_to_end():
     rng = np.random.default_rng(0)
     toks = jnp.asarray(rng.integers(0, 512, (128, 8)).astype(np.int32))
