@@ -31,11 +31,11 @@ model's TPU profile prices (see ``flow_sweep_K32768_sort_bytes`` for the
 model-vs-measured split).
 
 PR 4 takes the sort flow past one bucket sweep: ``--big`` adds the
-K=1,048,576 crossover rows where the MULTI-PASS hierarchy is what keeps the
+K=1,048,576 crossover rows where the MULTI-PASS radix sort is what keeps the
 fast path — the pure-JAX lowering runs the two-pass packed radix sort
 (``stable_sort_by_key(impl="radix")``; the forced single-pass two-key
 comparator sort is timed A/B and loses), the kernel pipeline runs the
-two-level hierarchical partition (parity-asserted in interpret mode), the
+one-pass bucket partition (parity-asserted in interpret mode), the
 cost model (extended with per-pass terms) must still pick sort for
 ``flow="auto"``, and the model bytes chain ``sort ≤ combine < reduce``
 must hold.  The nightly CI job runs ``--crossover --big --json
@@ -290,18 +290,18 @@ HUGE_N = 4096
 
 
 def crossover_big():
-    """The PR 4 headline rows: K=1M, where the hierarchy carries the flow.
+    """The PR 4 headline rows: K=1M, where the multi-pass sort carries the
+    flow.
 
     Asserted: the multi-pass sort flow beats the one-hot stream fold
     wall-clock (measured ~670× on this container — the one-hot fold pays
     the O(N·K) sweep at K=1M); the model bytes chain ``sort ≤ combine <
     reduce`` holds; ``flow="auto"`` with the workload hint picks sort via
-    the extended cost model; the tiling records two hierarchy levels and
-    two packed-sort passes; and at the default 16k chunk the multi-pass
+    the extended cost model; the tiling records two packed-sort passes;
+    and at the default 16k chunk the multi-pass
     radix sort beats the forced single-pass two-key comparator sort both
     sort-only (~4.5×) and flow-level (~1.3× — the O(K) table merge is
-    shared).  The kernel hierarchical pipeline is parity-checked in
-    interpret mode (timing reported as info, not gated: interpret mode
+    shared).  The kernel pipeline is parity-checked in interpret mode (timing reported as info, not gated: interpret mode
     executes kernel bodies in Python).
     """
     rng = np.random.default_rng(2)
@@ -313,8 +313,8 @@ def crossover_big():
 
     mr_sort = MapReduce(app, flow="sort", n_pairs_hint=N)
     t = mr_sort.tiling
-    assert len(t.level_fanouts) == 2 and t.sort_passes == 2, (
-        f"K=1M must engage the hierarchy: {t.describe()}")
+    assert t.sort_passes == 2, (
+        f"K=1M must engage the multi-pass sort: {t.describe()}")
     np.testing.assert_allclose(np.asarray(mr_sort.run(items).values), want)
     t_sort = time_fn(lambda x: mr_sort.run(x).counts, items, iters=7)
 
@@ -358,7 +358,7 @@ def crossover_big():
         f"multi-pass radix sort must beat the two-key comparator sort: "
         f"radix={t_sr * 1e6:.0f}us two_key={t_st * 1e6:.0f}us")
     assert t_multi < t_single * 1.5, (
-        f"hierarchical sort flow left the single-level class: "
+        f"multi-pass sort flow left the single-pass class: "
         f"multi={t_multi * 1e6:.0f}us single={t_single * 1e6:.0f}us")
     print(row(f"flow_sweep_K{K}_single_level_AB", t_multi * 1e6,
               f"forced_two_key={t_single * 1e6:.0f}us "
@@ -367,10 +367,9 @@ def crossover_big():
               f"two_key={t_st * 1e6:.0f}us ({t_st / t_sr:.2f}x)"))
 
     # model bytes chain under the kernel-lowering assumption every flow
-    # model makes (sort_levels=1: the hierarchical partition's inner passes
-    # stay in fast memory, like the single-level partition and the fused
-    # one-hot); the pure-JAX multi-pass pays (levels-1)·2N int32 extra —
-    # reported next to the chain
+    # model makes (sort_levels=1: the one-pass partition stays in fast
+    # memory, like the fused one-hot); the pure-JAX multi-pass pays
+    # (levels-1)·2N int32 extra — reported next to the chain
     mb = {f: roofline.mapreduce_flow_bytes(
         f, n_pairs=N, key_space=K, value_bytes=4,
         chunk_pairs=mr_sort.stream_chunk_pairs, max_values_per_key=8)
@@ -386,7 +385,7 @@ def crossover_big():
               f"ordering=ok purejax_multipass={mb_jax:.0f} "
               f"measured_cpu={measured:.0f}"))
 
-    # kernel hierarchical pipeline: interpret-mode parity (info row)
+    # kernel pipeline: interpret-mode parity (info row)
     mr_k = MapReduce(app, flow="sort", use_kernels=True, n_pairs_hint=N)
     np.testing.assert_allclose(np.asarray(mr_k.run(items).values), want)
     print(row(f"flow_sweep_K{K}_kernel_hierarchy", 0.0,

@@ -21,7 +21,7 @@ Four chips (``--chips 4``): only the multi-chip path, on a
 ``jax.make_mesh((4,), ("data",))`` mesh with the same input sharded over
 it: ``run_distributed`` with ``flow="sort"`` (the all-to-all shuffle) and
 ``flow="stream"`` (the psum merge), and ``run_resilient`` with one killed
-host.
+host, each host computing its shards on its own chip.
 
 Every result must equal an ``np.bincount`` oracle on the host, values and
 counts.  Any failure — no TPU, a LoweringFallbackWarning, a kernel phase
@@ -245,7 +245,15 @@ def _four_chips(args, windows, vocab, check, peak_bytes, WordCount):
     if res.recovery.recomputed != [(1, 2)]:
         raise SmokeFailure(f"run_resilient: expected shard 1 recomputed on "
                            f"host 2, got {res.recovery.recomputed}")
-    _log(f"{line} | recomputed {res.recovery.recomputed}")
+    # each host computes on its own chip: shard s on device s, the killed
+    # host's shard on host 2's device
+    devs = mesh.devices.reshape(-1)
+    want = {s: devs[2 if s == 1 else s].id for s in range(4)}
+    if res.recovery.devices != want:
+        raise SmokeFailure(f"run_resilient: shards ran on devices "
+                           f"{res.recovery.devices}, expected {want}")
+    _log(f"{line} | recomputed {res.recovery.recomputed} | shard -> device "
+         f"{res.recovery.devices}")
 
 
 if __name__ == "__main__":

@@ -171,7 +171,6 @@ class ExecutionOptions:
     chunk_pairs: int | None = None
     key_block: int | None = None
     bucket_size: int | None = None
-    level_fanouts: tuple[int, ...] | None = None
     # serving
     items_bucket: str = "exact"
     cache: bool = True
@@ -288,11 +287,10 @@ class MapReduce:
                   chunks are radix-partitioned / stably sorted by key and
                   ONE aggregate per distinct key merges into the holder
                   tables — O(N·log N + K) compute vs the one-hot fold's
-                  O(N·K), the winner at large sparse key spaces.  Past one
-                  bucket sweep the partition runs the multi-pass hierarchy
-                  (``kernels/ops.plan_radix_levels``; the pure-JAX sort a
-                  multi-pass packed digit radix), so K in the millions
-                  keeps the fast path — ``explain()`` shows levels×buckets
+                  O(N·K), the winner at large sparse key spaces.  Past the
+                  31-bit packed regime the pure-JAX sort runs a multi-pass
+                  packed digit radix — ``explain()`` shows the buckets and
+                  sort passes
       * "combine" force the legacy combine flow (materialize pairs, fold
                   once); kept for A/B benchmarks
       * "reduce"  force the baseline flow (paper's un-optimized MR4J)
@@ -368,7 +366,6 @@ class MapReduce:
             self.stream_chunk_pairs = entry.stream_chunk_pairs
             self._key_block = entry.key_block
             self._bucket_size = entry.bucket_size
-            self._level_fanouts = entry.level_fanouts
             return
 
         cache_event = "miss" if cache else ""
@@ -392,7 +389,6 @@ class MapReduce:
         self.tiling = None
         key_block = None
         bucket_size = None
-        level_fanouts = None
         if self.plan.flow == "stream":
             self.tiling = at.autotune_stream(
                 app, self.plan.spec, use_kernels=use_kernels,
@@ -414,11 +410,6 @@ class MapReduce:
             stream_chunk_pairs = self.tiling.chunk_pairs
             bucket_size = (self.tiling.key_block if self.tiling.blocked
                            else None)
-            # the hierarchical level decomposition rides with the bucket;
-            # an infeasible plan leaves bucket_size=None so the engine
-            # re-checks and fires the LoweringFallbackWarning on the plan
-            level_fanouts = (self.tiling.level_fanouts
-                             if bucket_size is not None else None)
         elif not isinstance(stream_chunk_pairs, int):
             stream_chunk_pairs = eng.DEFAULT_CHUNK_PAIRS
         if (self.plan.flow == "combine" and self.plan.spec is not None
@@ -447,7 +438,6 @@ class MapReduce:
         self.stream_chunk_pairs = stream_chunk_pairs
         self._key_block = key_block
         self._bucket_size = bucket_size
-        self._level_fanouts = level_fanouts
         self.plan.stage = "planned"
         self.plan.cache_key = self._plan_key
         self.plan.cache_event = cache_event
@@ -458,8 +448,7 @@ class MapReduce:
                 plan=dataclasses.replace(self.plan),
                 tiling=self.tiling,
                 stream_chunk_pairs=stream_chunk_pairs,
-                key_block=key_block, bucket_size=bucket_size,
-                level_fanouts=level_fanouts))
+                key_block=key_block, bucket_size=bucket_size))
             pc.file_put(self._plan_key,
                         pc.file_entry_from(self.plan, self.tiling))
 
@@ -478,8 +467,6 @@ class MapReduce:
                        else opts.key_block),
             bucket_size=(self._bucket_size if opts.bucket_size is None
                          else opts.bucket_size),
-            level_fanouts=(self._level_fanouts if opts.level_fanouts is None
-                           else opts.level_fanouts),
         )
 
     # -- staged execution surface ------------------------------------------
@@ -756,7 +743,7 @@ class Optimized:
                    repr(opts.shuffle),
                    knobs["combine_impl"], knobs["use_kernels"],
                    knobs["chunk_pairs"], knobs["key_block"],
-                   knobs["bucket_size"], knobs["level_fanouts"]))
+                   knobs["bucket_size"]))
 
     def compile(self) -> "Compiled":
         """Stage 3: produce the executable.  Content-cached — a warm hit
@@ -833,7 +820,6 @@ class Optimized:
                 shuffle_capacity=opts.shuffle_capacity,
                 chunk_pairs=chunk_pairs, key_block=key_block,
                 bucket_size=knobs["bucket_size"],
-                level_fanouts=knobs["level_fanouts"],
                 shuffle_plan=sk.plan_from_options(
                     mr.app.key_space, S, opts.shuffle, flow=plan.flow,
                     spec=plan.spec, value_aval=mr.app.value_aval),
@@ -868,7 +854,6 @@ class Optimized:
                 shuffle_capacity=opts.shuffle_capacity,
                 chunk_pairs=opts.chunk_pairs, key_block=opts.key_block,
                 bucket_size=opts.bucket_size,
-                level_fanouts=opts.level_fanouts,
                 strict_shuffle=opts.strict_shuffle,
                 shuffle_plan=res_plan,
                 wire=(opts.shuffle.wire if opts.shuffle is not None

@@ -79,10 +79,7 @@ class StreamTiling:
     working_set_bytes: float  # per-grid-step VMEM model (kernel path)
     n_pairs_hint: int
     notes: tuple[str, ...] = ()
-    #: hierarchical radix fan-outs of the sort flow's kernel partition
-    #: (() == single level / no partition); the pure-JAX lowering's
-    #: multi-pass packed-sort count is recorded in sort_passes.
-    level_fanouts: tuple[int, ...] = ()
+    #: packed-sort passes per chunk of the sort flow's pure-JAX lowering
     sort_passes: int = 1
 
     @property
@@ -93,17 +90,10 @@ class StreamTiling:
     def blocked(self) -> bool:
         return self.key_block < self.key_space
 
-    @property
-    def levels(self) -> int:
-        return max(len(self.level_fanouts), 1)
-
     def describe(self) -> str:
         if self.mode == "sort":
             blk = (f"buckets={self.n_key_blocks}×{self.key_block}keys"
                    if self.blocked else "buckets=1 (single full sort)")
-            if len(self.level_fanouts) > 1:
-                fan = "·".join(str(b) for b in self.level_fanouts)
-                blk += f" levels={len(self.level_fanouts)}({fan})"
             if self.sort_passes > 1:
                 blk += f" sort_passes={self.sort_passes}"
         else:
@@ -356,20 +346,15 @@ def autotune_sort(
     chunk_pairs: int | str = "auto",
     n_pairs_hint: int | None = None,
 ) -> StreamTiling:
-    """Pick the sort-flow tiling: chunk size + radix level decomposition.
+    """Pick the sort-flow tiling: chunk size + radix bucket width.
 
     The sort flow touches the O(K) tables once per chunk and its per-pair
     cost grows only as log(chunk), so the chunk is sized as large as the
     clamp allows (bounded by the workload hint — no point chunking beyond
-    the stream).  ``key_block`` records the LEAF radix bucket width and
-    ``level_fanouts`` the hierarchical decomposition the Pallas pipeline
-    partitions with (``kernels/ops.plan_radix_levels``, sized against the
-    VMEM budget); the pure-JAX lowering sorts each chunk instead —
-    ``sort_passes`` packed digit sorts once the 31-bit packed regime runs
-    out (noted).  An infeasible level plan (key space past the level
-    budget) is noted here; the engine fires the
-    :class:`LoweringFallbackWarning` when the kernel path is actually
-    requested.
+    the stream).  ``key_block`` records the radix bucket width the Pallas
+    pipeline partitions with (``kernels/ops.auto_bucket_size``); the
+    pure-JAX lowering sorts each chunk instead — ``sort_passes`` packed
+    digit sorts once the 31-bit packed regime runs out (noted).
     """
     from repro.core import plan_cache as pc
 
@@ -394,23 +379,7 @@ def autotune_sort(
 
     from repro.kernels import ops
 
-    fanouts: tuple[int, ...] = ()
-    plan = ops.plan_radix_levels(K, d=d + 1)
-    kernels_feasible = plan.feasible
-    if plan.feasible:
-        bucket = plan.bucket_size
-        fanouts = plan.fanouts
-        if plan.levels > 1:
-            notes.append(
-                f"hierarchical radix partition: {plan.describe()} — "
-                f"key space past one bucket sweep, each level's "
-                f"fan-out bounded at {ops.MAX_RADIX_FANOUT}")
-    else:
-        bucket = K
-        notes.append(
-            f"LEVEL BUDGET: {plan.reason}; the kernel pipeline "
-            f"degrades to the pure-JAX multi-pass sorted fold "
-            f"(LoweringFallbackWarning at run time)")
+    bucket = ops.auto_bucket_size(K, d=d + 1)
     sort_passes = col.sort_radix_passes(min(chunk, MAX_CHUNK_PAIRS), K)
     if not use_kernels:
         if sort_passes > 1:
@@ -425,11 +394,9 @@ def autotune_sort(
                          "pipeline's partition granularity)")
 
     hint = n_pairs_hint if n_pairs_hint else max(chunk * 4, 1 << 16)
-    # bytes model per ACTUAL lowering: the kernel hierarchy only when its
-    # plan is feasible — the infeasible fallback runs the pure-JAX
-    # multi-pass sort and pays its per-pass traffic
-    levels = (max(len(fanouts), 1) if use_kernels and kernels_feasible
-              else sort_passes)
+    # bytes model per lowering: the kernel partition is one pass; the
+    # pure-JAX multi-pass sort pays its per-pass traffic
+    levels = 1 if use_kernels else sort_passes
     model_bytes = roofline.mapreduce_flow_bytes(
         "sort", n_pairs=hint, key_space=K, value_bytes=value_bytes,
         holder_bytes=holder_bytes, chunk_pairs=chunk, sort_levels=levels)
@@ -443,7 +410,7 @@ def autotune_sort(
         source="manual" if manual_chunk else "model",
         model_bytes=model_bytes, model_peak_bytes=model_peak,
         working_set_bytes=working_set, n_pairs_hint=hint,
-        notes=tuple(notes), level_fanouts=fanouts, sort_passes=sort_passes)
+        notes=tuple(notes), sort_passes=sort_passes)
 
 
 def _probe_chunk(app, spec, chunk: int, *, use_kernels: bool,

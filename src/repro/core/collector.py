@@ -872,14 +872,12 @@ class SortCombiner:
     (``core/cost_model.py`` quantifies the crossover).
 
     Under ``use_kernels`` the per-chunk fold runs as the Pallas radix
-    pipeline instead: the (possibly multi-pass hierarchical) histogram +
-    bucket-scatter partition (``kernels/radix_partition.py``) feeding the
-    existing ``segment_reduce`` kernel leaf-bucket-by-leaf-bucket —
-    ``sort_fold_fn(keys, mat, acc, op)`` with the same merge contract as
-    the pure-JAX path (the leaf-bucket aggregates land in the carried
-    holder tables through the monoid merge, exactly like the single-level
-    fold).  The pure-JAX lowering mirrors the hierarchy with the
-    multi-pass packed radix sort (``stable_sort_by_key(impl="radix")``,
+    pipeline instead: the histogram + bucket-scatter partition
+    (``kernels/radix_partition.py``) feeding the ``segment_reduce``
+    kernel bucket by bucket — ``sort_fold_fn(keys, mat, acc, op)`` with
+    the same merge contract as the pure-JAX path (the bucket aggregates
+    land in the carried holder tables through the monoid merge).  The
+    pure-JAX lowering switches to the multi-pass packed radix sort (``stable_sort_by_key(impl="radix")``,
     a ``lax.scan`` over digit levels) once the packed 31-bit single-sort
     regime runs out; ``sort_impl`` forces a lowering for A/B benchmarks.
     Same interface as :class:`StreamCombiner` (init_state / fold_chunk /

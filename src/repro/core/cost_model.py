@@ -153,15 +153,15 @@ def _cpu_terms(flow: str, *, n, k, d, lmax, chunk_pairs, fused_combine,
 
 
 def _tpu_terms(flow: str, *, n, k, d, lmax, model_bytes, fused_combine,
-               chip, sort_levels=1):
+               chip):
     mem_s = model_bytes / chip.hbm_bw
     if flow in ("stream", "combine"):
         flops = 2.0 * n * k * d  # one-hot contraction on the MXU
         comp_s = flops / (chip.flops * TPU_MXU_UTIL)
     elif flow == "sort":
-        # hist + bucket-scatter per hierarchy level: the per-pair dynamic
-        # VMEM stores run on the scalar unit once per level
-        comp_s = (n * RADIX_PASSES * max(sort_levels, 1) / TPU_SCALAR_PAIRS
+        # hist + bucket-scatter: the per-pair dynamic VMEM stores run on
+        # the scalar unit
+        comp_s = (n * RADIX_PASSES / TPU_SCALAR_PAIRS
                   + (n * d + k * d) / TPU_VPU_ELEMS)
     else:  # reduce
         logn = max(math.log2(max(n, 2)), 1.0)
@@ -211,18 +211,11 @@ def estimate_flow_cost(
     chunk = chunk_pairs or n
     from repro.core import collector as col
 
-    # the sort flow's level count per lowering: pure-JAX digit-sort passes
-    # on the cpu profile, hierarchical partition levels (kernel path) on
-    # tpu — derived only when the sort flow is the one being priced
+    # the sort flow's passes per lowering: pure-JAX digit-sort passes on
+    # the cpu profile, the one-pass partition (kernel path) on tpu
     sort_levels = 1
-    if flow == "sort":
-        if backend == "tpu":
-            from repro.kernels import ops
-
-            rplan = ops.plan_radix_levels(k, d=d + 1)
-            sort_levels = max(rplan.levels, 1) if rplan.feasible else 1
-        else:
-            sort_levels = col.sort_radix_passes(max(min(n, chunk), 1), k)
+    if flow == "sort" and backend != "tpu":
+        sort_levels = col.sort_radix_passes(max(min(n, chunk), 1), k)
     model_bytes = roofline.mapreduce_flow_bytes(
         flow, n_pairs=n, key_space=k, value_bytes=value_bytes,
         holder_bytes=holder_bytes, chunk_pairs=chunk,
@@ -241,8 +234,7 @@ def estimate_flow_cost(
         terms = _tpu_terms(flow, n=n, k=k, d=d, lmax=lmax,
                            model_bytes=model_bytes,
                            chip=roofline.peaks(device_kind),
-                           fused_combine=fused_combine,
-                           sort_levels=sort_levels)
+                           fused_combine=fused_combine)
         est = max(v for _, v in terms)  # overlappable roofline terms
     else:
         raise ValueError(f"unknown backend profile {backend!r}")

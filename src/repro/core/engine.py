@@ -11,13 +11,11 @@ Four execution flows:
   before folding.
 * sort    — **radix-bucketed segment reduce** (``collector.SortCombiner``):
   each chunk's pairs are partitioned by key (stable packed sort — multi-pass
-  digit radix past the 31-bit packed regime — or the hierarchical Pallas
+  digit radix past the 31-bit packed regime — or the Pallas
   radix-partition kernel pipeline under ``use_kernels``) and ONE aggregate
   per distinct key merges into the carried tables — O(N·log N + K) compute
   where the one-hot stream fold pays O(N·K); the cost model
-  (``core/cost_model.py``) picks it for large sparse key spaces, and the
-  level decomposition (``kernels/ops.plan_radix_levels``) keeps the fast
-  path through K in the millions instead of silently degrading.
+  (``core/cost_model.py``) picks it for large sparse key spaces.
 * combine — the legacy combining collector (materialize pairs, fold once);
   kept for A/B benchmarks against the paper's optimized flow.
 * reduce  — the paper's baseline (materialize, sort, group, per-key reduce).
@@ -170,62 +168,33 @@ def _fold_kernels(use_kernels: bool, key_block: int | None = None
             partial(ops.chunk_monoid_fold, block_k=key_block))
 
 
-def _sort_fold_kernel(use_kernels: bool, bucket_size: int | None = None,
-                      level_fanouts: tuple[int, ...] | None = None
+def _sort_fold_kernel(use_kernels: bool, bucket_size: int | None = None
                       ) -> Callable | None:
-    """Radix-partition + segment-reduce pipeline for the sort collector.
-
-    ``level_fanouts`` binds the hierarchical multi-pass decomposition
-    (``ops.plan_radix_levels``); ``None`` lets the wrapper re-derive it."""
+    """Radix-partition + segment-reduce pipeline for the sort collector
+    (``bucket_size=None`` lets the wrapper size the buckets)."""
     if not use_kernels:
         return None
     from repro.kernels import ops
 
-    return partial(ops.sort_segment_fold, bucket_size=bucket_size,
-                   fanouts=level_fanouts)
+    return partial(ops.sort_segment_fold, bucket_size=bucket_size)
 
 
-def _check_sort_kernel_plan(spec, key_space: int, value_aval,
-                            use_kernels: bool,
-                            bucket_size: int | None,
-                            level_fanouts: tuple[int, ...] | None,
-                            on_fallback: Callable | None,
-                            skew_factor: float | None = None):
-    """Resolve the radix level plan for the kernel sort fold.
+def _sort_kernels_usable(spec, value_aval, use_kernels: bool,
+                            on_fallback: Callable | None) -> bool:
+    """Whether the kernel sort fold can carry ``spec``'s holders.
 
-    Returns ``(use_kernels, bucket_size, level_fanouts)``.  A key space
-    whose decomposition exceeds the level budget fires a
-    :class:`LoweringFallbackWarning` (once, through the plan sink) with the
-    plan diagnostics and drops to the pure-JAX multi-pass sorted fold —
-    instead of the old behaviour of silently clamping the bucket count
-    past the padded-layout envelope.  ``skew_factor`` (the sampled
-    fixed-width imbalance) shrinks the leaf bucket so a hot leaf's padded
-    region still fits the partition's VMEM envelope."""
-    if not use_kernels:
-        return use_kernels, bucket_size, level_fanouts
-    if not spec.kernel_monoid_ok(value_aval):
+    The radix-partition pipeline folds f32 holder tables under add/max/min
+    only; anything else fires a :class:`LoweringFallbackWarning` (once,
+    through the plan sink) and folds with the pure-JAX sorted fold."""
+    if use_kernels and not spec.kernel_monoid_ok(value_aval):
         col._emit_fallback(
             f"sort flow: use_kernels=True, but the radix-partition kernel "
             f"pipeline carries only f32 holder tables under add/max/min "
             f"monoids ({spec.describe or spec.strategy} over "
             f"{value_aval}); this key range folds with the pure-JAX sorted "
             f"fold.", on_fallback)
-        return False, None, None
-    if bucket_size is not None:
-        return use_kernels, bucket_size, level_fanouts
-    from repro.kernels import ops
-
-    d, _ = spec.holder_width(value_aval)
-    plan = ops.plan_radix_levels(key_space, d=d + 1,
-                                 skew_factor=skew_factor)
-    if not plan.feasible:
-        col._emit_fallback(
-            f"sort flow: {plan.reason}; degrading to the pure-JAX "
-            f"multi-pass sorted fold (the radix-partition kernel pipeline "
-            f"is disabled for this key space). Raise MAX_RADIX_LEVELS or "
-            f"shard the key space.", on_fallback)
-        return False, None, None
-    return use_kernels, plan.bucket_size, plan.fanouts
+        return False
+    return use_kernels
 
 
 def _plan_fallback_cb(plan) -> Callable | None:
@@ -435,7 +404,6 @@ def sort_local_tables(app, spec, items, *,
                       chunk_pairs: int = DEFAULT_SORT_CHUNK_PAIRS,
                       use_kernels: bool = False,
                       bucket_size: int | None = None,
-                      level_fanouts: tuple[int, ...] | None = None,
                       sort_mode: str | None = None,
                       sort_impl: str = "auto",
                       on_fallback: Callable | None = None,
@@ -443,20 +411,17 @@ def sort_local_tables(app, spec, items, *,
     """Sort flow over ``items``: chunked scan, per-chunk radix/sort fold.
 
     Same chunk scaffolding as the stream flow; each chunk is partitioned by
-    key (hierarchically, past one bucket sweep) and ONE aggregate per
-    distinct key merges into the carried tables
+    key and ONE aggregate per distinct key merges into the carried tables
     (``collector.SortCombiner``).  Returns un-finalized ``(tables, counts)``.
     """
     n_items = jax.tree.leaves(items)[0].shape[0]
     cap = max(app.emit_capacity, 1)
     chunk_items = max(1, min(n_items, chunk_pairs // cap))
-    use_kernels, bucket_size, level_fanouts = _check_sort_kernel_plan(
-        spec, app.key_space, app.value_aval, use_kernels, bucket_size,
-        level_fanouts, on_fallback)
+    use_kernels = _sort_kernels_usable(spec, app.value_aval,
+                                          use_kernels, on_fallback)
     sc = col.SortCombiner(
         spec, app.key_space, app.value_aval,
-        sort_fold_fn=_sort_fold_kernel(use_kernels, bucket_size,
-                                       level_fanouts),
+        sort_fold_fn=_sort_fold_kernel(use_kernels, bucket_size),
         mode=sort_mode, sort_impl=sort_impl)
     state = _fold_items_chunked(app, sc, items, chunk_items, n_valid=n_valid)
     return sc.tables_counts(state)
@@ -466,16 +431,14 @@ def run_local_sort(app, spec, items, *,
                    chunk_pairs: int = DEFAULT_SORT_CHUNK_PAIRS,
                    use_kernels: bool = False,
                    bucket_size: int | None = None,
-                   level_fanouts: tuple[int, ...] | None = None,
                    sort_mode: str | None = None,
                    sort_impl: str = "auto",
                    on_fallback: Callable | None = None,
                    n_valid=None):
     tables, counts = sort_local_tables(
         app, spec, items, chunk_pairs=chunk_pairs, use_kernels=use_kernels,
-        bucket_size=bucket_size, level_fanouts=level_fanouts,
-        sort_mode=sort_mode, sort_impl=sort_impl, on_fallback=on_fallback,
-        n_valid=n_valid)
+        bucket_size=bucket_size, sort_mode=sort_mode, sort_impl=sort_impl,
+        on_fallback=on_fallback, n_valid=n_valid)
     grouped = col.finalize_tables(spec, tables, counts, app.key_space)
     return grouped.keys, grouped.values, grouped.counts
 
@@ -484,7 +447,6 @@ def run_local(app, plan, items, *, combine_impl="auto", use_kernels=False,
               chunk_pairs: int | None = None,
               key_block: int | None = None,
               bucket_size: int | None = None,
-              level_fanouts: tuple[int, ...] | None = None,
               n_valid=None):
     if plan.flow == "stream":
         return run_local_stream(app, plan.spec, items,
@@ -502,7 +464,6 @@ def run_local(app, plan, items, *, combine_impl="auto", use_kernels=False,
                                            else chunk_pairs),
                               use_kernels=use_kernels,
                               bucket_size=bucket_size,
-                              level_fanouts=level_fanouts,
                               on_fallback=_plan_fallback_cb(plan),
                               n_valid=n_valid)
     stream = map_phase(app, items)
@@ -837,7 +798,7 @@ def _patch_hot_rows(spec, tables, counts, hot_tables, hot_counts,
 
 def _sort_shard_fn(app, spec, *, axis_name, num_shards, shuffle_capacity,
                    use_kernels, chunk_pairs, bucket_size=None,
-                   level_fanouts=None, on_fallback=None, shuffle_plan=None,
+                   on_fallback=None, shuffle_plan=None,
                    wire="raw"):
     """Sort flow per shard: the reduce-flow key-partitioned all-to-all
     (bucket boundaries == shard key ranges, O(N) traffic), then the local
@@ -845,11 +806,10 @@ def _sort_shard_fn(app, spec, *, axis_name, num_shards, shuffle_capacity,
     ``chunk_pairs``-sized pieces and finalizes its key range.  Output
     key-sharded like the reduce flow.
 
-    The shard key ranges ARE the hierarchy's top-level digits: the
-    all-to-all is the distributed form of radix level 0 (wire format
-    unchanged from the reduce flow), and each shard re-derives the
-    remaining level decomposition for its own ``K/S`` range — one fewer
-    level than the local pipeline needs at the full key space.
+    The shard key ranges are the top-level radix digits: the all-to-all
+    is the distributed form of the partition (wire format unchanged from
+    the reduce flow), and each shard sizes the buckets of its own ``K/S``
+    range.
 
     With a skew ``shuffle_plan``, the ranges are the sampled balanced
     boundaries and each hot key's occurrences arrive split over several
@@ -879,10 +839,7 @@ def _sort_shard_fn(app, spec, *, axis_name, num_shards, shuffle_capacity,
                                use_kernels=use_kernels,
                                chunk_pairs=chunk_pairs,
                                bucket_size=bucket_size,
-                               level_fanouts=level_fanouts,
                                on_fallback=on_fallback,
-                               skew_factor=(shuffle_plan.imbalance
-                                            if shuffle_plan else None),
                                hot_patch=hot_patch)
         return out + (overflow[None],)
 
@@ -891,19 +848,17 @@ def _sort_shard_fn(app, spec, *, axis_name, num_shards, shuffle_capacity,
 
 def _sort_range_tables(app, spec, lstream: col.PairStream, *,
                        use_kernels, chunk_pairs, bucket_size=None,
-                       level_fanouts=None, on_fallback=None,
-                       skew_factor=None):
+                       on_fallback=None):
     """Fold one localized key range to UN-finalized ``(tables, counts)``
     with the sort collector in ``chunk_pairs``-sized pieces — the shared
     core of :func:`_sort_range_fold` and the resilient driver's hot-split
     two-pass phase B (which must patch merged hot rows in between)."""
     K_local = lstream.key_space
-    uk, bs, lf = _check_sort_kernel_plan(
-        spec, K_local, app.value_aval, use_kernels, bucket_size,
-        level_fanouts, on_fallback, skew_factor=skew_factor)
+    uk = _sort_kernels_usable(spec, app.value_aval, use_kernels,
+                                 on_fallback)
     sc = col.SortCombiner(
         spec, K_local, app.value_aval,
-        sort_fold_fn=_sort_fold_kernel(uk, bs, lf))
+        sort_fold_fn=_sort_fold_kernel(uk, bucket_size))
     state = sc.init_state()
     n = lstream.keys.shape[0]
     if n <= chunk_pairs:
@@ -931,8 +886,7 @@ def _sort_range_tables(app, spec, lstream: col.PairStream, *,
 
 def _sort_range_fold(app, spec, lstream: col.PairStream, lo, *,
                      use_kernels, chunk_pairs, bucket_size=None,
-                     level_fanouts=None, on_fallback=None,
-                     skew_factor=None, hot_patch=None):
+                     on_fallback=None, hot_patch=None):
     """Sort-flow tail for one key range: fold the localized presorted-by-
     range segment with the local sort collector in ``chunk_pairs``-sized
     pieces and finalize the range.  Shared between the all-to-all shard fn
@@ -944,8 +898,7 @@ def _sort_range_fold(app, spec, lstream: col.PairStream, lo, *,
     tables, counts = _sort_range_tables(
         app, spec, lstream, use_kernels=use_kernels,
         chunk_pairs=chunk_pairs, bucket_size=bucket_size,
-        level_fanouts=level_fanouts, on_fallback=on_fallback,
-        skew_factor=skew_factor)
+        on_fallback=on_fallback)
     if hot_patch is not None:
         tables, counts = hot_patch(tables, counts)
     keys = jnp.arange(K_local, dtype=jnp.int32) + lo
@@ -1087,7 +1040,6 @@ def run_distributed(
     chunk_pairs: int | None = None,
     key_block: int | None = None,
     bucket_size: int | None = None,
-    level_fanouts: tuple[int, ...] | None = None,
     strict_shuffle: bool = False,
     shuffle_plan=None,
     wire: str = "raw",
@@ -1123,8 +1075,7 @@ def run_distributed(
         combine_impl=combine_impl, use_kernels=use_kernels,
         scatter_output=scatter_output, shuffle_capacity=shuffle_capacity,
         chunk_pairs=chunk_pairs, key_block=key_block,
-        bucket_size=bucket_size, level_fanouts=level_fanouts,
-        shuffle_plan=shuffle_plan, wire=wire)
+        bucket_size=bucket_size, shuffle_plan=shuffle_plan, wire=wire)
     return post(jitted(items), strict_shuffle=strict_shuffle)
 
 
@@ -1141,7 +1092,6 @@ def build_distributed_fn(
     chunk_pairs: int | None = None,
     key_block: int | None = None,
     bucket_size: int | None = None,
-    level_fanouts: tuple[int, ...] | None = None,
     shuffle_plan=None,
     wire: str = "raw",
 ):
@@ -1175,7 +1125,6 @@ def build_distributed_fn(
                             num_shards=S, shuffle_capacity=shuffle_capacity,
                             use_kernels=use_kernels, chunk_pairs=chunk_pairs,
                             bucket_size=bucket_size,
-                            level_fanouts=level_fanouts,
                             on_fallback=_plan_fallback_cb(plan),
                             shuffle_plan=shuffle_plan, wire=wire)
         out_spec = (P(data_axis), P(data_axis), P(data_axis), P(data_axis))
@@ -1274,6 +1223,32 @@ def merge_partial_tables(app, spec, tables_seq, counts_seq):
     raise ValueError("combiner has no cross-shard merge strategy")
 
 
+def _rows_on_device(a, lo: int, hi: int, device):
+    """Rows ``[lo, hi)`` of ``a`` committed to ``device``.
+
+    A ``jax.Array`` split along its leading axis only (any sharding over a
+    mesh, Explicit axes included) is assembled from the device shards that
+    hold those rows, so the data moves chip to chip and never through the
+    host; any other layout moves whole onto ``device`` first."""
+    if not isinstance(a, jax.Array):
+        return jax.device_put(a[lo:hi], device)
+    full = tuple(slice(None) for _ in a.shape[1:])
+    pieces = {}
+    for sh in a.addressable_shards:
+        rows = sh.index[0] if sh.index else slice(None)
+        if tuple(sh.index[1:]) != full:
+            return jax.device_put(a, device)[lo:hi]
+        r0, r1, _ = rows.indices(a.shape[0])
+        if r1 <= lo or r0 >= hi:
+            continue
+        if (r0, r1) not in pieces or sh.device == device:
+            pieces[(r0, r1)] = sh.data
+    parts = [jax.device_put(pieces[r][max(lo, r[0]) - r[0]:
+                                      min(hi, r[1]) - r[0]], device)
+             for r in sorted(pieces)]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
 def run_resilient(
     app,
     plan,
@@ -1294,7 +1269,6 @@ def run_resilient(
     chunk_pairs: int | None = None,
     key_block: int | None = None,
     bucket_size: int | None = None,
-    level_fanouts: tuple[int, ...] | None = None,
     strict_shuffle: bool = False,
     shuffle_plan=None,
     wire: str = "raw",
@@ -1403,7 +1377,7 @@ def run_resilient(
     _jits = jit_cache if jit_cache is not None else {}
     _jkey = (flow, H, S, per, chunk_pairs, key_block, use_kernels,
              combine_impl, shuffle_capacity, strict_shuffle, bucket_size,
-             level_fanouts, wire,
+             wire,
              shuffle_plan.epoch if shuffle_plan is not None else None)
 
     def _cached_jit(name, fn):
@@ -1412,13 +1386,27 @@ def run_resilient(
             got = _jits[(name,) + _jkey] = jax.jit(fn)
         return got
 
-    # the driver replays shards one at a time on the host's device, so it
-    # slices a host copy: items sharded over an Explicit-axis mesh cannot be
-    # sliced along their sharded axis without an output sharding
-    items_host = jax.tree.map(np.asarray, items)
+    # host h computes on device h of the mesh: a shard's items are
+    # gathered there from the chips that hold them (an Explicit-axis mesh
+    # cannot slice its sharded axis in place), and its partial stays there
+    # until phase B gathers every partial on the coordinator's device
+    host_devs = (list(mesh.devices.reshape(-1)) if mesh is not None
+                 else None)
 
-    def shard_slice(s: int):
-        return jax.tree.map(lambda a: a[s * per:(s + 1) * per], items_host)
+    def host_device(h: int):
+        return host_devs[h % len(host_devs)]
+
+    def shard_slice(s: int, h: int):
+        lo, hi = s * per, (s + 1) * per
+        if host_devs is None:
+            return jax.tree.map(lambda a: a[lo:hi], items)
+        log.devices[s] = host_device(h).id
+        return jax.tree.map(
+            lambda a: _rows_on_device(a, lo, hi, host_device(h)), items)
+
+    shard_spec = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((per,) + tuple(a.shape[1:]),
+                                       a.dtype), items)
 
     # -- the per-shard partial: a pure deterministic function of the shard --
     if flow == "stream":
@@ -1454,7 +1442,7 @@ def run_resilient(
         if wire_fmt is None:
             ak, av = jax.eval_shape(
                 lambda it: (lambda st: (st.keys, st.values))(
-                    map_phase(app, it)), shard_slice(0))
+                    map_phase(app, it)), shard_spec)
             wire_fmt = _jits[("wire_fmt",) + _jkey] = wirelib.wire_format(
                 key_space=app.key_space, num_shards=S,
                 n_pairs=ak.shape[0], value_avals=av,
@@ -1472,7 +1460,7 @@ def run_resilient(
     partial_example = _jits.get(("partial_example",) + _jkey)
     if partial_example is None:
         partial_example = _jits[("partial_example",) + _jkey] = (
-            jax.eval_shape(_partial, shard_slice(0)))
+            jax.eval_shape(_partial, shard_spec))
 
     def save_partial(s: int, p) -> None:
         if ckpt_dir is None:
@@ -1603,11 +1591,11 @@ def run_resilient(
                 # the cluster: beats, checkpoints, and partials are all
                 # dropped at the transport — survivors must recover its
                 # shards as if it were dead
-                partial_fn(shard_slice(s))
+                partial_fn(shard_slice(s, h))
                 progress[h] = j + 1
                 mon.beat(h, step=progress[h])  # dropped by the monitor
                 continue
-            p = partial_fn(shard_slice(s))
+            p = partial_fn(shard_slice(s, h))
             if h not in dead_script or inject.checkpoint_survives:
                 save_partial(s, p)
             if h not in dead_script:
@@ -1689,7 +1677,7 @@ def run_resilient(
             computed_by[s] = backup  # the restoring rank holds it now
             log.restored.append(s)
             return
-        p = partial_fn(shard_slice(s))  # deterministic re-execution
+        p = partial_fn(shard_slice(s, backup))  # deterministic re-execution
         partials[s] = p
         computed_by[s] = backup
         save_partial(s, p)
@@ -1717,6 +1705,7 @@ def run_resilient(
             devs = (devs[:new_H] if new_H <= len(devs)
                     else list(jax.devices())[:new_H])
             final_mesh = elastic.best_mesh(devs, axis_names=(data_axis,))
+            host_devs = list(final_mesh.devices.reshape(-1))
         new_owner = {s: h for h in range(new_H)
                      for s in flt.shard_for(step, h, new_H, S)}
         log.moved = sorted(s for s in range(S)
@@ -1734,7 +1723,7 @@ def run_resilient(
                 computed_by[s] = new_owner[s]
                 log.restored.append(s)
             else:
-                partials[s] = partial_fn(shard_slice(s))
+                partials[s] = partial_fn(shard_slice(s, new_owner[s]))
                 computed_by[s] = new_owner[s]
                 save_partial(s, partials[s])
                 log.recomputed.append((s, new_owner[s]))
@@ -1746,12 +1735,17 @@ def run_resilient(
     # re-executed by its owner — no shard is ever silently absent ----------
     for s in range(S):
         if s not in partials:
-            partials[s] = partial_fn(shard_slice(s))
+            partials[s] = partial_fn(shard_slice(s, owner[s]))
             computed_by[s] = owner[s]
             save_partial(s, partials[s])
             log.recomputed.append((s, owner[s]))
 
     # -- phase B: monoid re-merge (tables) or key-range replay (shuffle) ----
+    if host_devs is not None:  # on the coordinator: lowest live rank
+        live = [a for a in alive if a < H]
+        phase_b_dev = host_device(coordlib.elect(live) if live else 0)
+        partials = {s: jax.device_put(p, phase_b_dev)
+                    for s, p in partials.items()}
     if flow in ("stream", "combine"):
         keys, values, counts = merge_partial_tables(
             app, spec,
@@ -1803,9 +1797,7 @@ def run_resilient(
                 return _sort_range_fold(
                     app, spec, lstream, lo, use_kernels=use_kernels,
                     chunk_pairs=chunk_pairs, bucket_size=bucket_size,
-                    level_fanouts=level_fanouts, on_fallback=cb,
-                    skew_factor=(shuffle_plan.imbalance
-                                 if shuffle_plan is not None else None))
+                    on_fallback=cb)
 
             # one dispatch for the whole phase B: it is embarrassingly
             # parallel over destinations, so vmap batches the S per-range
@@ -1832,8 +1824,7 @@ def run_resilient(
                 tables, counts = _sort_range_tables(
                     app, spec, lstream, use_kernels=use_kernels,
                     chunk_pairs=chunk_pairs, bucket_size=bucket_size,
-                    level_fanouts=level_fanouts, on_fallback=cb,
-                    skew_factor=shuffle_plan.imbalance)
+                    on_fallback=cb)
                 fk = recv_keys.reshape(-1)
                 fv = jax.tree.map(
                     lambda v: v.reshape((-1,) + v.shape[2:]), recv_vals)

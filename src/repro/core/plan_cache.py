@@ -287,7 +287,6 @@ class PlanEntry:
     stream_chunk_pairs: int
     key_block: int | None
     bucket_size: int | None
-    level_fanouts: tuple[int, ...] | None
 
 
 @dataclasses.dataclass
@@ -351,8 +350,7 @@ def sizes() -> tuple[int, int]:
 #: anything else — hand-edited files, entries from an older schema, plain
 #: corruption — reads as "no entry" (the tune-cache corrupt-safe contract).
 _FILE_SCHEMA = {"flow": str, "chunk_pairs": int}
-_FILE_OPTIONAL = {"key_block": int, "bucket_size": int,
-                  "level_fanouts": list}
+_FILE_OPTIONAL = {"key_block": int, "bucket_size": int}
 
 
 def plan_cache_path() -> str | None:
@@ -421,7 +419,6 @@ def file_entry_from(plan, tiling) -> dict:
     if tiling is not None:
         entry["chunk_pairs"] = int(tiling.chunk_pairs)
         entry["key_block"] = int(tiling.key_block)
-        entry["level_fanouts"] = [int(f) for f in tiling.level_fanouts]
     else:
         from repro.core.engine import DEFAULT_CHUNK_PAIRS
 

@@ -205,6 +205,9 @@ class RecoveryLog:
     recomputed: list = dataclasses.field(default_factory=list)
     #: (shard, backup_host) speculatively re-executed for stragglers.
     speculated: list = dataclasses.field(default_factory=list)
+    #: shard -> id of the mesh device its partial was last computed on
+    #: (empty when driven mesh-less).
+    devices: dict = dataclasses.field(default_factory=dict)
     dead_hosts: list = dataclasses.field(default_factory=list)
     straggler_hosts: list = dataclasses.field(default_factory=list)
     #: (old_hosts, new_hosts) when an elastic resize happened, else None.

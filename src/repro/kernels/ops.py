@@ -8,8 +8,6 @@ suite).  Nothing else switches a kernel to interpret mode on a TPU.
 
 from __future__ import annotations
 
-import dataclasses
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -141,137 +139,40 @@ def combine_scatter(keys, values, key_space, op="add", *,
                              interpret=interpret)
 
 
+#: cap of the radix bucket width.  The bucket is the ``segment_reduce``
+#: key block, and each of its tiles compares 128 pairs against the whole
+#: block per lane row, so the block bounds the reduce's work per pair.
+MAX_BUCKET_SIZE = 16384
+
+
 def auto_bucket_size(key_space: int, *, d: int = 1, pad_align: int = 256,
                      budget: int = VMEM_BUDGET) -> int:
     """Radix bucket width for the sort-flow pipeline.
 
-    The bucket is the ``segment_reduce`` output block, so it must keep a
-    ``[bucket, D]`` table block VMEM-resident; buckets much smaller than
-    ``pad_align`` would drown in per-bucket padding, so the floor is a few
-    K of keys and small key spaces keep a single bucket (plain segment
-    reduce, no partition needed)."""
-    blk = _pow2_floor(max(key_space // 64, 8 * pad_align))
+    About 32 buckets per key space, at most :data:`MAX_BUCKET_SIZE` keys
+    each, with a ``[bucket, D]`` table block well inside the VMEM budget.
+    Buckets much smaller than ``pad_align`` would drown in per-bucket
+    padding, so the floor is a few K of keys, and small key spaces keep a
+    single bucket (plain segment reduce, no partition needed)."""
+    blk = _pow2_floor(max(key_space // 32, 8 * pad_align))
+    blk = min(blk, _pow2_floor(MAX_BUCKET_SIZE))
     while blk > 8 and blk * max(d, 1) * 4 > budget // 8:
         blk //= 2
     return key_space if blk >= key_space else blk
 
 
-#: per-level fan-out cap of the hierarchical radix partition.  One level
-#: covers key_space <= fan-out·leaf.
-MAX_RADIX_FANOUT = 32
-
-#: level budget of the hierarchical partition.  3 levels × fan-out 32 × a
-#: 16k leaf covers K = 512M; anything past it degrades to the pure-JAX
-#: sorted fold with a LoweringFallbackWarning instead of silently clamping
-#: the bucket count.
-MAX_RADIX_LEVELS = 3
-
-#: leaf bucket cap: the segment_reduce output block is [D, leaf] f32, so a
-#: 16k leaf keeps the block small even for wide holders — past this the
-#: hierarchy adds a level instead of growing the leaf out of VMEM.
-LEAF_BUCKET_CAP = 16384
-
-
-@dataclasses.dataclass(frozen=True)
-class RadixPlan:
-    """Level decomposition of the sort-flow radix partition.
-
-    ``fanouts == ()`` means no partition at all (single bucket — the plain
-    segment reduce); ``len(fanouts) == 1`` is the classic single-level
-    two-pass partition; more entries run the hierarchical multi-pass.
-    ``feasible == False`` marks a key space whose decomposition would
-    exceed ``max_levels`` — callers must NOT silently clamp; they emit a
-    :class:`LoweringFallbackWarning` and take the pure-JAX sorted fold.
-    """
-
-    bucket_size: int
-    fanouts: tuple[int, ...]
-    key_space: int
-    feasible: bool = True
-    reason: str = ""
-
-    @property
-    def levels(self) -> int:
-        return len(self.fanouts)
-
-    @property
-    def num_leaves(self) -> int:
-        return -(-self.key_space // self.bucket_size)
-
-    def describe(self) -> str:
-        if not self.feasible:
-            return f"INFEASIBLE ({self.reason})"
-        if not self.fanouts:
-            return "buckets=1 (single full segment reduce)"
-        fan = "·".join(str(b) for b in self.fanouts)
-        return (f"buckets={self.num_leaves}×{self.bucket_size}keys "
-                f"levels={self.levels}({fan})")
-
-
-def plan_radix_levels(key_space: int, *, d: int = 1, pad_align: int = 256,
-                      max_fanout: int | None = None,
-                      max_levels: int | None = None,
-                      leaf_cap: int | None = None,
-                      budget: int = VMEM_BUDGET,
-                      skew_factor: float | None = None) -> RadixPlan:
-    """Pick the leaf bucket and per-level fan-outs for a key space.
-
-    The leaf is the ``segment_reduce`` block (VMEM-resident, capped at
-    ``leaf_cap``); the leaf count is then decomposed into the fewest
-    levels whose fan-outs stay within ``max_fanout`` (near-uniform powers
-    of two).  A key space needing more than ``max_levels`` levels is
-    reported infeasible — the caller warns and falls back instead of
-    clamping the bucket count past the padded-layout envelope.  The budget
-    knobs default to the module constants at call time (patchable in
-    tests).
-
-    ``skew_factor`` (the sampled fixed-width load imbalance from
-    ``core/skew.py``, >= 1.0) halves the leaf cap per power of two of
-    imbalance: under skew the hottest leaf's pair REGION (not the key
-    count) dominates the partition's padded layout, so smaller leaves
-    spread the hot range over more buckets and keep each region inside
-    the VMEM envelope."""
-    max_fanout = MAX_RADIX_FANOUT if max_fanout is None else max_fanout
-    max_levels = MAX_RADIX_LEVELS if max_levels is None else max_levels
-    leaf_cap = LEAF_BUCKET_CAP if leaf_cap is None else leaf_cap
-    if skew_factor is not None and skew_factor > 1.0:
-        shrink = 1 << int(np.ceil(np.log2(float(skew_factor))))
-        leaf_cap = max(leaf_cap // shrink, pad_align)
-    leaf = _pow2_floor(max(key_space // max_fanout, 8 * pad_align))
-    leaf = min(leaf, _pow2_floor(leaf_cap))
-    while leaf > 8 and leaf * max(d, 1) * 4 > budget // 8:
-        leaf //= 2
-    if leaf >= key_space:
-        return RadixPlan(key_space, (), key_space)
-    num_leaves = -(-key_space // leaf)
-    # fan-outs are powers of two, so the cap that actually binds is the
-    # pow2 floor of max_fanout — level count and bit split both use it
-    fan_bits = max(max_fanout.bit_length() - 1, 1)
-    bits = max(num_leaves - 1, 1).bit_length()
-    levels = -(-bits // fan_bits)
-    if levels > max_levels:
-        return RadixPlan(
-            leaf, (), key_space, feasible=False,
-            reason=f"key_space={key_space} needs {levels} radix levels at "
-                   f"fan-out {1 << fan_bits} (leaf bucket {leaf}), over "
-                   f"the max_levels={max_levels} budget")
-    base, extra = divmod(bits, levels)
-    fanouts = tuple(1 << (base + (1 if i < extra else 0))
-                    for i in range(levels))
-    return RadixPlan(leaf, fanouts, key_space)
-
-
 def radix_partition(keys, values, key_space, *, bucket_size=None,
-                    fanouts=None, pad_align=256, tile_n=256, interpret=None):
-    """Radix partition of a pair chunk into padded LEAF bucket regions.
+                    pad_align=256, tile_n=256, interpret=None):
+    """Radix partition of a pair chunk into padded bucket regions.
 
-    [N] keys + [N, D] values -> (pkeys, pvals, starts); leaf bucket ``b``
+    [N] keys + [N, D] values -> (pkeys, pvals, starts); bucket ``b``
     holds keys in ``[b·bucket_size, (b+1)·bucket_size)``, every region a
     ``pad_align`` multiple (sentinel-padded) — the layout ``segment_reduce``
     consumes with ``block_k=bucket_size, tile_n=pad_align``.
 
-    ``fanouts`` selects the hierarchical multi-pass decomposition (see
-    :func:`plan_radix_levels`); ``None`` is the single-level partition."""
+    The padded output stays VMEM-resident, so the chunk plus one
+    ``pad_align`` region per bucket must fit the VMEM budget; past it this
+    raises rather than partition anywhere else."""
     if values.ndim != 2:
         raise ValueError("values must be [N, D]")
     n, d = values.shape
@@ -287,14 +188,13 @@ def radix_partition(keys, values, key_space, *, bucket_size=None,
         raise ValueError(
             f"radix partition of {n} pairs x {num_buckets} buckets does not "
             f"fit the VMEM budget; shrink the chunk or grow bucket_size")
-    return _rp.radix_partition_multi(
+    return _rp.radix_partition(
         keys, values, key_space, bucket_size=bucket_size,
-        fanouts=tuple(fanouts or ()), pad_align=pad_align, tile_n=tile_n,
-        interpret=_interpret(interpret))
+        pad_align=pad_align, tile_n=tile_n, interpret=_interpret(interpret))
 
 
 def sort_segment_fold(keys, values, acc, op="add", *, bucket_size=None,
-                      fanouts=None, pad_align=256, interpret=None):
+                      pad_align=256, interpret=None):
     """Sort-flow chunk fold: radix partition + bucket-wise segment reduce,
     merged into the carried ``[K, D]`` f32 accumulator.
 
@@ -302,27 +202,17 @@ def sort_segment_fold(keys, values, acc, op="add", *, bucket_size=None,
     op)``.  The partition guarantees every reduce tile falls inside one
     aligned ``bucket_size`` K-block, so ``segment_reduce`` runs with
     ``block_k=bucket_size`` — presorted segments, no per-pair scatter.
-
-    ``bucket_size=None`` derives the level decomposition from
-    :func:`plan_radix_levels`; an infeasible plan raises — the engine
-    checks feasibility first and falls back to the pure-JAX sorted fold
-    with a warning."""
+    ``bucket_size=None`` takes :func:`auto_bucket_size`."""
     if values.ndim != 2:
         raise ValueError("values must be [N, D]")
     key_space = acc.shape[0]
     n, d = values.shape
     if n == 0:
         return acc.astype(jnp.float32)
-    if bucket_size is None and fanouts is None:
-        plan = plan_radix_levels(key_space, d=d, pad_align=pad_align)
-        if not plan.feasible:
-            raise ValueError(f"sort_segment_fold: {plan.reason}; use the "
-                             f"pure-JAX sorted fold for this key space")
-        bucket_size, fanouts = plan.bucket_size, plan.fanouts
-    elif bucket_size is None:
+    if bucket_size is None:
         bucket_size = auto_bucket_size(key_space, d=d, pad_align=pad_align)
     pkeys, pvals, _ = radix_partition(
-        keys, values, key_space, bucket_size=bucket_size, fanouts=fanouts,
+        keys, values, key_space, bucket_size=bucket_size,
         pad_align=pad_align, interpret=interpret)
     return _segment_fold(pkeys, pvals, acc, op, tile_n=pad_align,
                          block_k=bucket_size, interpret=interpret)

@@ -8,7 +8,7 @@ inside ONE aligned key block.  The partition is the chunk-local form of
 the paper's shuffle: pairs move once, and the reduce consumes presorted
 segments instead of scattering per pair.
 
-Each pass is two kernels:
+The partition is two kernels:
 
 1. ``_hist_kernel`` counts the pairs of each bucket;
 2. ``_scatter_kernel`` copies each pair's row to its bucket's cursor.
@@ -17,22 +17,16 @@ Both walk the pairs one at a time on the scalar unit: the keys of a tile
 sit in SMEM (a ``[1, tile_n]`` block of ``[n_tiles, 1, tile_n]``), the
 bucket cursors live in an SMEM scratch, and the scatter moves one
 ``[1, C]`` row per pair with a dynamic sublane offset in VMEM.
-Walking the pairs in order makes every pass stable, which the
+Walking the pairs in order makes the partition stable, which the
 first-element idiom and the bitwise tests rely on.  (A vector form would
 need a gather of the cursors, which Mosaic does not lower.)
 
 A pair is one int32 row: the key in column 0, then the values' f32 bits.
 The whole padded output stays VMEM-resident across the grid.
 
-Hierarchy (``radix_partition_multi``): the key space is decomposed over
-``fanouts = (B1, …, BL)`` levels with ranges ``R_L = bucket_size`` and
-``R_{l-1} = R_l · B_l``; level ``l`` partitions by ``key // R_l``.  Every
-pass is stable, so the leaf layout is bitwise the single-level partition
-at ``bucket_size`` — which is the test oracle.
-
-Keys are int32 in ``[0, key_space]``; tile padding carries the cover
-``bucket_size · ΠB``, which maps past every level's last bucket and so
-drops into the trash slot.
+Keys are int32 in ``[0, key_space]``; tile padding carries the sentinel
+``key_space``, which maps past the last bucket and so drops into the
+trash slot.
 """
 
 from __future__ import annotations
@@ -48,13 +42,16 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.fold import compiler_params
 
 
-def _bucket(key, rng: int, num_buckets: int):
-    """Bucket id of ``key`` at range ``rng``; ``num_buckets`` means drop."""
-    b = key >> (rng.bit_length() - 1) if rng & (rng - 1) == 0 else key // rng
+def _bucket(key, bucket_size: int, num_buckets: int):
+    """Bucket id of ``key``; ``num_buckets`` means drop."""
+    if bucket_size & (bucket_size - 1) == 0:
+        b = key >> (bucket_size.bit_length() - 1)
+    else:
+        b = key // bucket_size
     return jnp.minimum(b, num_buckets)
 
 
-def _hist_kernel(keys_ref, hist_ref, *, rng: int, num_buckets: int):
+def _hist_kernel(keys_ref, hist_ref, *, bucket_size: int, num_buckets: int):
     @pl.when(pl.program_id(0) == 0)
     def _init():
         def zero(b, c):
@@ -64,7 +61,7 @@ def _hist_kernel(keys_ref, hist_ref, *, rng: int, num_buckets: int):
         lax.fori_loop(0, num_buckets + 1, zero, 0)
 
     def count(j, c):
-        b = _bucket(keys_ref[0, j], rng, num_buckets)
+        b = _bucket(keys_ref[0, j], bucket_size, num_buckets)
         hist_ref[b] += 1
         return c
 
@@ -72,7 +69,7 @@ def _hist_kernel(keys_ref, hist_ref, *, rng: int, num_buckets: int):
 
 
 def _scatter_kernel(starts_ref, keys_ref, rows_ref, out_ref, cursor_ref, *,
-                    rng: int, num_buckets: int, pad_key: int):
+                    bucket_size: int, num_buckets: int, pad_key: int):
     @pl.when(pl.program_id(0) == 0)
     def _init():
         def seed(b, c):
@@ -85,7 +82,7 @@ def _scatter_kernel(starts_ref, keys_ref, rows_ref, out_ref, cursor_ref, *,
         out_ref[...] = jnp.where(lane == 0, pad_key, 0)
 
     def move(j, c):
-        b = _bucket(keys_ref[0, j], rng, num_buckets)
+        b = _bucket(keys_ref[0, j], bucket_size, num_buckets)
         dst = cursor_ref[b]
         cursor_ref[b] = dst + jnp.where(b < num_buckets, 1, 0)
         out_ref[pl.ds(dst, 1), :] = rows_ref[pl.ds(j, 1), :]
@@ -94,33 +91,56 @@ def _scatter_kernel(starts_ref, keys_ref, rows_ref, out_ref, cursor_ref, *,
     lax.fori_loop(0, keys_ref.shape[1], move, 0)
 
 
-def _partition_pass(keys, rows, *, rng: int, num_buckets: int, n_real: int,
-                    pad_align: int, tile_n: int, pad_key: int,
-                    interpret: bool):
-    """One stable pass: ``[Np]`` keys + ``[Np, C]`` rows -> padded layout.
+@functools.partial(jax.jit, static_argnames=(
+    "key_space", "bucket_size", "pad_align", "tile_n", "interpret"))
+def radix_partition(
+    keys: jax.Array,
+    values: jax.Array,
+    key_space: int,
+    *,
+    bucket_size: int,
+    pad_align: int,
+    tile_n: int,
+    interpret: bool,
+):
+    """Partition ``[N]`` keys + ``[N, D]`` values into padded buckets.
 
-    Returns ``(rows [out_slots, C], starts [num_buckets])``."""
-    n_tiles = keys.shape[0] // tile_n
-    keys = keys.reshape(n_tiles, 1, tile_n)  # SMEM tiles of any length
+    Returns ``(pkeys [Np], pvals [Np, D], starts [B])``: bucket ``b``
+    occupies ``pkeys[starts[b] : starts[b] + padded_count[b]]``, every
+    region is a ``pad_align`` multiple, pad slots carry the sentinel
+    ``key_space`` and the final ``pad_align`` slots are the trash region
+    for invalid pairs.  ``tile_n`` (any length) is the pair tile the
+    kernels walk per grid step."""
+    n, d = values.shape
+    num_buckets = -(-key_space // bucket_size)
+    tile_n = min(tile_n, max(n, 8))
+    pad = (-n) % tile_n
+    n_tiles = (n + pad) // tile_n
+    keys_p = jnp.pad(keys.astype(jnp.int32), (0, pad),
+                     constant_values=key_space)
+    bits = lax.bitcast_convert_type(values.astype(jnp.float32), jnp.int32)
+    rows = jnp.concatenate([keys_p[:, None],
+                            jnp.pad(bits, ((0, pad), (0, 0)))], axis=1)
+    key_tiles = keys_p.reshape(n_tiles, 1, tile_n)  # SMEM tiles
     smem_tile = pl.BlockSpec((None, 1, tile_n), lambda i: (i, 0, 0),
                              memory_space=pltpu.SMEM)
+    kw = dict(bucket_size=bucket_size, num_buckets=num_buckets)
     hist = pl.pallas_call(
-        functools.partial(_hist_kernel, rng=rng, num_buckets=num_buckets),
+        functools.partial(_hist_kernel, **kw),
         grid=(n_tiles,),
         in_specs=[smem_tile],
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((num_buckets + 1,), jnp.int32),
         compiler_params=compiler_params(),
         interpret=interpret,
-    )(keys)[:num_buckets]
+    )(key_tiles)[:num_buckets]
     padded = -(-hist // pad_align) * pad_align
     starts = (jnp.cumsum(padded) - padded).astype(jnp.int32)
-    out_slots = n_real + num_buckets * pad_align + pad_align  # + trash
+    out_slots = n + num_buckets * pad_align + pad_align  # + trash
     out_slots += (-out_slots) % pad_align
     c = rows.shape[1]
     out = pl.pallas_call(
-        functools.partial(_scatter_kernel, rng=rng, num_buckets=num_buckets,
-                          pad_key=pad_key),
+        functools.partial(_scatter_kernel, pad_key=key_space, **kw),
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -132,55 +152,8 @@ def _partition_pass(keys, rows, *, rng: int, num_buckets: int, n_real: int,
         scratch_shapes=[pltpu.SMEM((num_buckets + 1,), jnp.int32)],
         compiler_params=compiler_params(),
         interpret=interpret,
-    )(starts, keys, rows)
-    return out, starts
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "key_space", "bucket_size", "fanouts", "pad_align", "tile_n",
-    "interpret"))
-def radix_partition_multi(
-    keys: jax.Array,
-    values: jax.Array,
-    key_space: int,
-    *,
-    bucket_size: int,
-    fanouts: tuple[int, ...],
-    pad_align: int,
-    tile_n: int,
-    interpret: bool,
-):
-    """Partition ``[N]`` keys + ``[N, D]`` values into padded leaf buckets.
-
-    Returns ``(pkeys [Np], pvals [Np, D], starts [B])``: leaf bucket ``b``
-    occupies ``pkeys[starts[b] : starts[b] + padded_count[b]]``, every
-    region is a ``pad_align`` multiple, pad slots carry the sentinel
-    ``key_space`` and the final ``pad_align`` slots are the trash region
-    for invalid pairs.  ``fanouts=()`` or one entry is the single-level
-    partition; more entries run one stable pass per level."""
-    n, d = values.shape
-    num_leaves = -(-key_space // bucket_size)
-    ranges = [bucket_size]
-    for fan in reversed(fanouts[1:]):
-        ranges.insert(0, ranges[0] * fan)
-    cover = ranges[0] * (fanouts[0] if fanouts else num_leaves)
-    if cover < key_space:
-        raise ValueError(f"fanouts {fanouts} x bucket_size {bucket_size} "
-                         f"cover {cover} < key_space {key_space}")
-    tile_n = min(tile_n, max(n, 8))
-    pad = (-n) % tile_n
-    keys_p = jnp.pad(keys.astype(jnp.int32), (0, pad), constant_values=cover)
-    bits = lax.bitcast_convert_type(values.astype(jnp.float32), jnp.int32)
-    rows = jnp.concatenate([keys_p[:, None],
-                            jnp.pad(bits, ((0, pad), (0, 0)))], axis=1)
-    starts = None
-    for rng in ranges:
-        rows, starts = _partition_pass(
-            rows[:, 0], rows, rng=rng, num_buckets=-(-key_space // rng),
-            n_real=n, pad_align=pad_align, tile_n=tile_n, pad_key=cover,
-            interpret=interpret)
-        tile_n = pad_align  # later passes walk the padded layout
+    )(starts, key_tiles, rows)
     # every dropped slot (pads, trash) reads as THE sentinel downstream
-    pkeys = jnp.minimum(rows[:, 0], key_space)
-    pvals = lax.bitcast_convert_type(rows[:, 1:], jnp.float32)
-    return pkeys, pvals, starts[:num_leaves]
+    pkeys = jnp.minimum(out[:, 0], key_space)
+    pvals = lax.bitcast_convert_type(out[:, 1:], jnp.float32)
+    return pkeys, pvals, starts

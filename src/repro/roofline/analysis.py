@@ -261,9 +261,8 @@ def mapreduce_flow_bytes(
       the carried tables are re-touched once per chunk — same O(N + K)
       bytes class as the stream flow, but O(N·log N + K) compute instead
       of the one-hot fold's O(N·K).  ``sort_levels > 1`` charges the
-      multi-pass hierarchy's extra per-level key/permutation traffic
-      (one int32 stream re-read + re-write per extra level — the digit
-      sorts / inner partition passes past one bucket sweep).
+      pure-JAX multi-pass sort's extra per-pass key/permutation traffic
+      (one int32 stream re-read + re-write per extra digit sort).
     """
     if chunk_pairs is None:  # keep the model in sync with the engine
         from repro.core.engine import (DEFAULT_CHUNK_PAIRS,
@@ -298,7 +297,7 @@ def mapreduce_flow_bytes(
         # tables are re-touched (read + write) per chunk, minus the first
         # read (identity init).  Equal to the single-chunk combine-flow
         # bytes — the sort flow's win is the compute term
-        # (see core/cost_model.py).  Extra hierarchy levels each re-touch
+        # (see core/cost_model.py).  Extra digit-sort passes each re-touch
         # the int32 key/permutation stream once.
         return (2.0 * N * pair + (2.0 * n_chunks - 1.0) * table
                 + (max(sort_levels, 1) - 1) * 2.0 * N * 4.0)

@@ -224,7 +224,7 @@ def test_forced_sort_on_noncombinable_raises():
 
 
 # ---------------------------------------------------------------------------
-# Multi-pass hierarchical radix shuffle (ISSUE 4)
+# Multi-pass radix sort and the kernel partition past one bucket
 # ---------------------------------------------------------------------------
 
 
@@ -274,13 +274,12 @@ def test_sort_flow_multi_pass_regime_parity():
 
 
 def test_sort_flow_kernel_hierarchy_parity(monkeypatch):
-    """use_kernels with a key space past one bucket sweep: the hierarchical
-    multi-pass pipeline (levels > 1) stays bitwise exact.  Budgets shrunk
-    so the hierarchy engages at test-sized K."""
+    """use_kernels with a key space of many buckets: the one-pass kernel
+    partition stays bitwise exact across chunk boundaries.  The bucket
+    cap is shrunk so K=4096 takes 16 buckets."""
     from repro.kernels import ops
 
-    monkeypatch.setattr(ops, "LEAF_BUCKET_CAP", 256)
-    monkeypatch.setattr(ops, "MAX_RADIX_FANOUT", 4)
+    monkeypatch.setattr(ops, "MAX_BUCKET_SIZE", 256)
     K = 4096
     app = make_app(
         lambda item, emit: emit(item, jnp.ones_like(item, jnp.float32)),
@@ -288,13 +287,11 @@ def test_sort_flow_kernel_hierarchy_parity(monkeypatch):
         key_space=K, value_aval=jax.ShapeDtypeStruct((), jnp.float32),
         emit_capacity=8, max_values_per_key=1024,
     )
-    plan = ops.plan_radix_levels(K, d=2)
-    assert plan.levels == 2  # 16 leaves of 256 keys at fan-out 4
     rng = np.random.default_rng(9)
     toks = rng.integers(0, K, size=(128, 8)).astype(np.int32)
     mr = MapReduce(app, flow="sort", use_kernels=True,
                    stream_chunk_pairs=512)
-    assert mr.tiling.level_fanouts == plan.fanouts
+    assert mr.tiling.key_block == 256 and mr.tiling.n_key_blocks == 16
     res = mr.run(jnp.asarray(toks))
     want = np.bincount(toks.reshape(-1), minlength=K)
     np.testing.assert_array_equal(np.asarray(res.values), want)
@@ -302,35 +299,35 @@ def test_sort_flow_kernel_hierarchy_parity(monkeypatch):
 
 
 def test_sort_flow_level_budget_fallback_warns_once(monkeypatch):
-    """Satellite fix: a key space past the level budget fires ONE
-    LoweringFallbackWarning with plan diagnostics and degrades to the
-    pure-JAX multi-pass sorted fold — instead of silently clamping the
-    bucket count (results stay exact either way)."""
+    """The kernel sort flow hides nothing: holders the kernels cannot
+    carry (int32 counts) fire ONE LoweringFallbackWarning per plan with
+    diagnostics and fold exactly in pure JAX, and a bucket count whose
+    padded partition overflows VMEM raises instead of falling back."""
     from repro.kernels import ops
 
-    monkeypatch.setattr(ops, "MAX_RADIX_LEVELS", 1)
-    monkeypatch.setattr(ops, "LEAF_BUCKET_CAP", 256)
-    monkeypatch.setattr(ops, "MAX_RADIX_FANOUT", 4)
-    K = 4096  # needs 2 levels under the shrunk budget
+    K = 4096
+    rng = np.random.default_rng(10)
+    toks = rng.integers(0, K, size=(64, 8)).astype(np.int32)
+    want = np.bincount(toks.reshape(-1), minlength=K)
+    mr = MapReduce(_sum_app(K), flow="sort", use_kernels=True)
+    with pytest.warns(col.LoweringFallbackWarning, match="only f32 holder"):
+        res = mr.run(jnp.asarray(toks))
+    np.testing.assert_array_equal(np.asarray(res.values), want)
+    assert any("only f32 holder" in d for d in mr.plan.diagnostics)
+    with warnings.catch_warnings():  # re-trace: deduped per plan
+        warnings.simplefilter("error", col.LoweringFallbackWarning)
+        mr.run(jnp.asarray(rng.integers(0, K, size=(80, 8))
+                           .astype(np.int32)))
+
+    monkeypatch.setattr(ops, "MAX_BUCKET_SIZE", 8)  # 512 buckets
     app = make_app(
         lambda item, emit: emit(item, jnp.ones_like(item, jnp.float32)),
         lambda k, v, c: jnp.sum(v),
         key_space=K, value_aval=jax.ShapeDtypeStruct((), jnp.float32),
         emit_capacity=8, max_values_per_key=1024,
     )
-    rng = np.random.default_rng(10)
-    toks = rng.integers(0, K, size=(64, 8)).astype(np.int32)
-    mr = MapReduce(app, flow="sort", use_kernels=True)
-    assert any("LEVEL BUDGET" in n for n in mr.tiling.notes)
-    with pytest.warns(col.LoweringFallbackWarning, match="radix levels"):
-        res = mr.run(jnp.asarray(toks))
-    want = np.bincount(toks.reshape(-1), minlength=K)
-    np.testing.assert_array_equal(np.asarray(res.values), want)
-    assert any("radix levels" in d for d in mr.plan.diagnostics)
-    with warnings.catch_warnings():  # re-trace: deduped per plan
-        warnings.simplefilter("error", col.LoweringFallbackWarning)
-        mr.run(jnp.asarray(rng.integers(0, K, size=(80, 8))
-                           .astype(np.int32)))
+    with pytest.raises(ValueError, match="VMEM"):
+        MapReduce(app, flow="sort", use_kernels=True).run(jnp.asarray(toks))
 
 
 def test_sort_cost_model_prices_multi_pass():
@@ -345,15 +342,16 @@ def test_sort_cost_model_prices_multi_pass():
 
 
 def test_explain_shows_levels_at_large_k():
+    """At K=2^20 explain() shows the kernel buckets and the pure-JAX
+    lowering's two packed-sort passes."""
     mr = MapReduce(_sum_app(1 << 20, jnp.float32), flow="sort",
                    n_pairs_hint=4096)
     text = mr.explain()
-    assert "levels=2" in text and "buckets=" in text
-    assert mr.tiling.level_fanouts and mr.tiling.levels == 2
+    assert "buckets=64×16384keys" in text and "sort_passes=2" in text
     assert mr.tiling.sort_passes == 2
 
 
-# -- hypothesis: multi-pass ≡ single-pass ≡ reduce --------------------------
+# -- hypothesis: tiled partition ≡ one-tile partition ≡ reduce -------------
 
 try:  # optional dependency (mirrors tests/core/test_properties.py)
     from hypothesis import given, settings, strategies as st
@@ -366,50 +364,48 @@ if HAVE_HYPOTHESIS:
 
     @settings(max_examples=12, deadline=None)
     @given(
-        bucket_pow=st.integers(2, 4),       # leaf 4..16 keys
-        fan_pows=st.lists(st.integers(1, 2), min_size=2, max_size=3),
-        k_off=st.integers(0, 3),            # K not a bucket·ΠB multiple
+        bucket_pow=st.integers(2, 4),       # bucket 4..16 keys
+        n_buckets=st.integers(2, 16),
+        k_off=st.integers(0, 3),            # K not a bucket multiple
         n=st.integers(1, 120),
+        tile_n=st.integers(1, 64),
         seed=st.integers(0, 2 ** 16),
     )
     def test_multi_pass_equals_single_pass_equals_reduce(
-            bucket_pow, fan_pows, k_off, n, seed):
-        """Random level splits: the hierarchical kernel fold, the
-        single-level kernel fold and the reduce-flow ground truth agree,
-        including K % bucket^levels != 0 and sentinel/trash invariants."""
+            bucket_pow, n_buckets, k_off, n, tile_n, seed):
+        """Random bucket layouts and pair tiles: the partition walked in
+        ``tile_n``-pair tiles, the partition walked in one tile, and the
+        reduce-flow ground truth agree, including a ragged last bucket,
+        chunks shorter than ``pad_align`` and sentinel/trash
+        invariants."""
         from repro.kernels import ops, ref
 
         bs = 1 << bucket_pow
-        fanouts = tuple(1 << p for p in fan_pows)
-        cover = bs
-        for b in fanouts:
-            cover *= b
-        k = max(cover - k_off, bs + 1)  # force >1 bucket, ragged last leaf
+        k = max(bs * n_buckets - k_off, bs + 1)  # >1 bucket, ragged last
         rng = np.random.default_rng(seed)
         keys = rng.integers(0, k + 1, size=n).astype(np.int32)  # + sentinel
         vals = rng.standard_normal((n, 1)).astype(np.float32)
         pa = 8
-        multi_k, multi_v, _ = ops.radix_partition(
+        tiled_k, tiled_v, _ = ops.radix_partition(
             jnp.asarray(keys), jnp.asarray(vals), k, bucket_size=bs,
-            fanouts=fanouts, pad_align=pa, tile_n=pa)
-        single_k, single_v, _ = ops.radix_partition(
+            pad_align=pa, tile_n=tile_n)
+        whole_k, whole_v, _ = ops.radix_partition(
             jnp.asarray(keys), jnp.asarray(vals), k, bucket_size=bs,
-            pad_align=pa, tile_n=pa)
-        np.testing.assert_array_equal(np.asarray(multi_k),
-                                      np.asarray(single_k))
-        real = np.asarray(single_k) < k
-        np.testing.assert_allclose(np.asarray(multi_v)[real],
-                                   np.asarray(single_v)[real], rtol=1e-6)
+            pad_align=pa, tile_n=max(n, 8))
+        np.testing.assert_array_equal(np.asarray(tiled_k),
+                                      np.asarray(whole_k))
+        real = np.asarray(whole_k) < k
+        np.testing.assert_allclose(np.asarray(tiled_v)[real],
+                                   np.asarray(whole_v)[real], rtol=1e-6)
         # sentinel/trash invariants: dropped slots normalized, none lost
-        mk = np.asarray(multi_k)
-        np.testing.assert_array_equal(np.sort(mk[mk < k]),
+        tk = np.asarray(tiled_k)
+        np.testing.assert_array_equal(np.sort(tk[tk < k]),
                                       np.sort(keys[keys < k]))
-        assert (mk <= k).all()
+        assert (tk <= k).all()
         # the folded table == the reduce-flow per-key sums (ground truth)
         acc = jnp.zeros((k, 1), jnp.float32)
         got = ops.sort_segment_fold(jnp.asarray(keys), jnp.asarray(vals),
-                                    acc, "add", bucket_size=bs,
-                                    fanouts=fanouts, pad_align=pa)
+                                    acc, "add", bucket_size=bs, pad_align=pa)
         want = np.zeros((k, 1), np.float64)
         np.add.at(want, keys[keys < k],
                   vals[keys < k].astype(np.float64))

@@ -93,11 +93,10 @@ def test_distributed_sort_flow():
 
 
 def test_distributed_sort_flow_hierarchical_kernels():
-    """Multi-level sort flow on a 4-device mesh with the kernel pipeline:
-    the shard key ranges are the hierarchy's top-level digits (the
-    all-to-all wire format is unchanged), and each shard re-derives the
-    remaining level decomposition for its own K/S range — shrunk budgets
-    force two levels per shard."""
+    """Sort flow on a 4-device mesh with the kernel pipeline: the shard
+    key ranges are the top-level radix digits (the all-to-all wire format
+    is unchanged), and each shard sizes the buckets of its own K/S range —
+    a shrunk bucket cap gives every shard 16 buckets."""
     out = run_with_devices("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
@@ -105,11 +104,9 @@ def test_distributed_sort_flow_hierarchical_kernels():
         from repro.core import engine as eng
         from repro.kernels import ops
 
-        ops.LEAF_BUCKET_CAP = 64   # per-shard K/S = 1024 -> 16 leaves
-        ops.MAX_RADIX_FANOUT = 4   # -> 2 levels of fan-out 4
+        ops.MAX_BUCKET_SIZE = 64   # per-shard K/S = 1024 -> 16 buckets
         VOCAB = 4096
-        plan_local = ops.plan_radix_levels(VOCAB // 4, d=2)
-        assert plan_local.levels == 2, plan_local
+        assert ops.auto_bucket_size(VOCAB // 4, d=2) == 64
 
         class WC(MapReduceApp):
             key_space = VOCAB

@@ -257,27 +257,27 @@ def test_radix_partition_vmem_guard():
 
 
 # ---------------------------------------------------------------------------
-# Multi-pass hierarchical radix partition
+# Radix partition over many buckets, any pair tile
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("n,k,bs,fanouts,pa", [
-    (200, 256, 16, (4, 4), 16),
-    (300, 100, 8, (4, 4), 16),        # K % (bs·ΠB) != 0, cover > K
-    (500, 1000, 16, (4, 4, 4), 32),   # three levels
-    (64, 64, 4, (4, 4), 8),
-    (333, 2000, 64, (8, 4), 16),      # non-uniform fan-outs
+@pytest.mark.parametrize("n,k,bs,tile_n,pa", [
+    (200, 256, 16, 16, 16),
+    (300, 100, 8, 48, 16),          # K % bs != 0, tile not a pa multiple
+    (500, 1000, 16, 32, 32),        # 63 buckets, ragged last one
+    (64, 64, 4, 8, 8),
+    (333, 2000, 64, 1000, 16),      # one tile holds every pair
 ])
 def test_radix_partition_multi_matches_single_level_oracle(n, k, bs,
-                                                           fanouts, pa):
-    """The hierarchical multi-pass layout is bitwise identical to the
-    single-level partition at the leaf bucket (stability per level composes
-    to the stable leaf grouping) — the argsort oracle covers both."""
+                                                           tile_n, pa):
+    """The one-pass partition over many buckets is bitwise the argsort
+    oracle, whatever tile the kernels walk the pairs in: the scalar walk
+    is in pair order, so the partition is stable."""
     keys = RNG.integers(0, k + 1, size=n).astype(np.int32)  # incl. sentinel
     vals = _vals((n, 3), np.float32)
     got_k, got_v, got_s = ops.radix_partition(
         jnp.asarray(keys), jnp.asarray(vals), k, bucket_size=bs,
-        fanouts=fanouts, pad_align=pa, tile_n=pa)
+        pad_align=pa, tile_n=tile_n)
     want_k, want_v, want_s = ref.radix_partition(
         jnp.asarray(keys), jnp.asarray(vals), k, bucket_size=bs, pad_align=pa)
     np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
@@ -288,14 +288,15 @@ def test_radix_partition_multi_matches_single_level_oracle(n, k, bs,
 
 
 def test_radix_partition_multi_bucket_invariants():
-    """Leaf regions of the hierarchy: every real key inside its leaf range,
-    aligned region starts, nothing lost, trash slots sentinel-normalized."""
+    """Bucket regions of a 32-bucket partition: every real key inside its
+    bucket range, aligned region starts, nothing lost, trash slots
+    sentinel-normalized."""
     n, k, bs, pa = 400, 512, 16, 16
     keys = RNG.integers(0, k + 1, size=n).astype(np.int32)
     vals = _vals((n, 1), np.float32)
     pk, _, starts = ops.radix_partition(
         jnp.asarray(keys), jnp.asarray(vals), k, bucket_size=bs,
-        fanouts=(8, 4), pad_align=pa, tile_n=pa)
+        pad_align=pa, tile_n=pa)
     pk, starts = np.asarray(pk), np.asarray(starts)
     assert starts.shape[0] == k // bs
     assert (starts % pa == 0).all()
@@ -311,66 +312,58 @@ def test_radix_partition_multi_bucket_invariants():
 
 @pytest.mark.parametrize("op", ["add", "max", "min"])
 def test_sort_segment_fold_multi_level_matches_ref(op):
-    """The full hierarchical pipeline (multi-pass partition feeding
-    segment_reduce leaf blocks) == the argsort/segment oracle, merged into
-    a carried accumulator."""
+    """The full pipeline over many buckets (12 buckets of 256 keys, the
+    last one ragged, feeding segment_reduce key blocks) == the
+    argsort/segment oracle, merged into a carried accumulator."""
     n, d, k = 333, 2, 3000
     keys = RNG.integers(0, k + 1, size=n).astype(np.int32)
     vals = jnp.asarray(_vals((n, d), np.float32))
     acc = jnp.asarray(_vals((k, d), np.float32))
-    plan = ops.plan_radix_levels(k, d=d, max_fanout=4, leaf_cap=256)
-    assert plan.levels >= 2  # the hierarchy is actually engaged
     got = ops.sort_segment_fold(jnp.asarray(keys), vals, acc, op,
-                                bucket_size=plan.bucket_size,
-                                fanouts=plan.fanouts)
+                                bucket_size=256)
     want = ref.sort_segment_fold(jnp.asarray(keys), vals, acc, op)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
 
 
 def test_plan_radix_levels_small_keyspaces_stay_single_level():
-    """The decomposition preserves the PR 3 behaviour below one sweep:
-    single bucket for tiny K, one level while leaves fit the fan-out."""
-    assert ops.plan_radix_levels(512).fanouts == ()
-    p = ops.plan_radix_levels(32768, d=2)
-    assert p.levels == 1 and p.bucket_size == 2048
-    assert p.bucket_size == ops.auto_bucket_size(32768, d=2)
+    """Bucket sizing: a single bucket for tiny K (plain segment reduce),
+    about K/32 keys per bucket above the 8·pad_align floor."""
+    assert ops.auto_bucket_size(512) == 512
+    assert ops.auto_bucket_size(32768, d=2) == 2048
+    assert ops.auto_bucket_size(131072, d=2) == 4096
 
 
 def test_plan_radix_levels_multi_level_and_budget():
-    p = ops.plan_radix_levels(1 << 20, d=2)
-    assert p.feasible and p.levels == 2
-    assert all(b <= ops.MAX_RADIX_FANOUT for b in p.fanouts)
-    cover = p.bucket_size
-    for b in p.fanouts:
-        cover *= b
-    assert cover >= 1 << 20
-    assert p.bucket_size <= ops.LEAF_BUCKET_CAP
-    # past the level budget: infeasible is REPORTED, never silently clamped
-    bad = ops.plan_radix_levels(1 << 20, d=2, max_levels=1)
-    assert not bad.feasible and "max_levels=1" in bad.reason
-    assert "INFEASIBLE" in bad.describe()
+    """Large key spaces keep buckets at MAX_BUCKET_SIZE and grow their
+    count; a bucket count whose padded regions overflow the partition's
+    VMEM budget raises — it never partitions anywhere else."""
+    bs = ops.auto_bucket_size(1 << 20, d=2)
+    assert bs == ops.MAX_BUCKET_SIZE
+    assert (1 << 20) // bs == 64
+    keys = jnp.zeros(16384, jnp.int32)
+    vals = jnp.zeros((16384, 1), jnp.float32)
+    with pytest.raises(ValueError, match="VMEM"):
+        ops.radix_partition(keys, vals, 1 << 22)  # 256 buckets
 
 
 def test_radix_partition_multi_requires_aligned_tiles():
-    """Each pass walks its pairs in order, so a first-pass tile need not
-    match pad_align; fan-outs that do not cover the key space raise."""
+    """The raw kernel walks its pairs in order, so no tile length is
+    required to match pad_align: a tile shorter than pad_align, a ragged
+    one and one longer than the chunk give the same layout."""
     from repro.kernels import radix_partition as rp
 
     keys = RNG.integers(0, 257, size=64).astype(np.int32)
     vals = _vals((64, 1), np.float32)
-    got_k, _, got_s = rp.radix_partition_multi(
-        jnp.asarray(keys), jnp.asarray(vals), 256, bucket_size=16,
-        fanouts=(4, 4), pad_align=16, tile_n=32, interpret=True)
     want_k, _, want_s = ref.radix_partition(
         jnp.asarray(keys), jnp.asarray(vals), 256, bucket_size=16,
         pad_align=16)
-    np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
-    np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
-    with pytest.raises(ValueError, match="cover"):
-        rp.radix_partition_multi(
+    for tile_n in (8, 24, 128):
+        got_k, _, got_s = rp.radix_partition(
             jnp.asarray(keys), jnp.asarray(vals), 256, bucket_size=16,
-            fanouts=(4, 2), pad_align=16, tile_n=16, interpret=True)
+            pad_align=16, tile_n=tile_n, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
+        np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
 
 
 def test_fold_kernel_autoblocks_past_vmem_budget():

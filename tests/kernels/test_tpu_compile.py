@@ -76,18 +76,18 @@ def test_segment_reduce_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("key_space,levels", [(K, 1), (1 << 20, 2)])
-def test_radix_partition_compiles(one_chip, key_space, levels):
-    """The sort flow's radix partition of a 16384-pair chunk, one and two
-    levels, with the plan the engine derives."""
-    plan = ops.plan_radix_levels(key_space, d=D)
-    assert plan.levels == levels
+@pytest.mark.parametrize("key_space,buckets", [(K, 32), (1 << 20, 64)])
+def test_radix_partition_compiles(one_chip, key_space, buckets):
+    """The sort flow's radix partition of a 16384-pair chunk, with the
+    bucket size the engine derives: 32 buckets at the smoke's vocabulary,
+    64 at a million keys."""
+    bucket_size = ops.auto_bucket_size(key_space, d=D)
+    assert -(-key_space // bucket_size) == buckets
     n = 16384
 
     def part(keys, vals):
         return ops.radix_partition(keys, vals, key_space,
-                                   bucket_size=plan.bucket_size,
-                                   fanouts=plan.fanouts, interpret=False)
+                                   bucket_size=bucket_size, interpret=False)
 
     text = _compile_text(part, _spec(one_chip, (n,), jnp.int32),
                          _spec(one_chip, (n, D)))
