@@ -59,6 +59,7 @@ from repro.core import engine as eng
 from repro.core import combiner as C
 from repro.core import plan_cache as pc
 from repro.core import skew as sk
+from repro.core import trace
 from repro.core.skew import ShuffleOptions
 from repro.core.optimizer import Derivation, derive_combiner
 from repro.core.plan import ExecutionPlan, plan_execution
@@ -562,8 +563,9 @@ class MapReduce:
     def run(self, items, *, options: ExecutionOptions | None = None,
             **legacy) -> MapReduceResult:
         opts = _resolve_options(options, legacy, method="run")
-        return self.lower(items, options=opts, mode="local"
-                          ).optimize().compile()(items)
+        with trace.span(trace.RUN, rid=trace.next_job()):
+            return self.lower(items, options=opts, mode="local"
+                              ).optimize().compile()(items)
 
     def run_distributed(self, items, *, mesh=None,
                         options: ExecutionOptions | None = None,
@@ -578,8 +580,9 @@ class MapReduce:
         if opts.mesh is None:
             raise TypeError("run_distributed requires a mesh (pass mesh=... "
                             "or options=ExecutionOptions(mesh=...))")
-        return self.lower(items, options=opts, mode="distributed"
-                          ).optimize().compile()(items)
+        with trace.span(trace.RUN_DISTRIBUTED, rid=trace.next_job()):
+            return self.lower(items, options=opts, mode="distributed"
+                              ).optimize().compile()(items)
 
     def run_resilient(self, items, *, mesh=None,
                       options: ExecutionOptions | None = None,
@@ -913,13 +916,16 @@ class Compiled:
                     lambda a: jnp.concatenate(
                         [a, jnp.zeros((pad,) + a.shape[1:], a.dtype)]),
                     items)
-                keys, values, counts = self._entry.executable(
-                    items, jnp.int32(self.n_items))
+                with trace.span(trace.DISPATCH):
+                    keys, values, counts = self._entry.executable(
+                        items, jnp.int32(self.n_items))
             else:
-                keys, values, counts = self._entry.executable(items)
+                with trace.span(trace.DISPATCH):
+                    keys, values, counts = self._entry.executable(items)
             return MapReduceResult(keys, values, counts, plan=self.plan)
         if self.mode == "distributed":
-            out = self._entry.executable(items)
+            with trace.span(trace.DISPATCH):
+                out = self._entry.executable(items)
             keys, values, counts = self._entry.aux(
                 out, strict_shuffle=self.options.strict_shuffle)
             return MapReduceResult(keys, values, counts, plan=self.plan)

@@ -35,6 +35,7 @@ import numpy as np
 from jax import lax
 
 from repro.core import combiner as C
+from repro.core import trace
 
 SENTINEL = "sentinel"  # invalid-pair key == key_space
 
@@ -272,6 +273,7 @@ def combine_segment(spec: C.CombinerSpec, stream: PairStream) -> tuple[Any, jax.
     return _sequential_fold(spec, tables0, counts0, skeys, svals)
 
 
+@jax.named_scope(trace.FINALIZE)
 def finalize_tables(spec: C.CombinerSpec, tables, counts, key_space: int) -> Grouped:
     keys = jnp.arange(key_space, dtype=jnp.int32)
     vals = jax.vmap(spec.finalize)(keys, tables, counts)
@@ -984,8 +986,16 @@ class SortCombiner:
             return state
         if self.mode == "monoid" and self._use_kernel:
             return self._fold_kernel(state, stream)
-        sk, order = stable_sort_by_key(stream.keys, self.key_space,
-                                       impl=self.sort_impl)
+        with jax.named_scope(trace.PARTITION):
+            sk, order = stable_sort_by_key(stream.keys, self.key_space,
+                                           impl=self.sort_impl)
+        with jax.named_scope(trace.SEGMENT_REDUCE):
+            return self._fold_sorted(state, stream, sk, order)
+
+    def _fold_sorted(self, state, stream: PairStream, sk, order):
+        """Merge one aggregate per run of the key-sorted chunk into the
+        carried state."""
+        n = stream.keys.shape[0]
         if self.mode == "size":
             _, _, run_len, tgt = self._run_layout(sk)
             return state.at[tgt].add(run_len, mode="drop")

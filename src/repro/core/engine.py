@@ -62,6 +62,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import collector as col
 from repro.core import combiner as C
+from repro.core import trace
 from repro.distributed import wire as wirelib
 from repro.distributed.wire import shuffle_bucket_capacity  # noqa: F401
 
@@ -127,6 +128,7 @@ class Emitter:
         return ks, vs
 
 
+@jax.named_scope(trace.MAP)
 def map_phase(app, items) -> col.PairStream:
     """vmap the user map over input items -> flat PairStream."""
 
@@ -276,7 +278,8 @@ def _fold_items_chunked(app, combiner, items, chunk_items: int,
             stream = col.PairStream(
                 jnp.where(mask, stream.keys, app.key_space),
                 stream.values, app.key_space)
-        return combiner.fold_chunk(state, stream)
+        with jax.named_scope(trace.FOLD):
+            return combiner.fold_chunk(state, stream)
 
     padded = n_chunks * chunk_items
     pad = padded - n_items
@@ -293,8 +296,9 @@ def _fold_items_chunked(app, combiner, items, chunk_items: int,
         stream = map_phase(app, citems)
         keys = jnp.where(jnp.repeat(cmask, app.emit_capacity),
                          stream.keys, app.key_space)
-        state = combiner.fold_chunk(
-            state, col.PairStream(keys, stream.values, app.key_space))
+        with jax.named_scope(trace.FOLD):
+            state = combiner.fold_chunk(
+                state, col.PairStream(keys, stream.values, app.key_space))
         return state, None
 
     state, _ = lax.scan(body, state, (chunked, item_mask))
@@ -492,6 +496,7 @@ def run_local(app, plan, items, *, combine_impl="auto", use_kernels=False,
 _PCOLLECTIVE = {"add": lax.psum, "max": lax.pmax, "min": lax.pmin}
 
 
+@jax.named_scope(trace.MERGE)
 def merge_tables_collective(spec: C.CombinerSpec, tables, counts,
                             axis_name: str, *, scatter: bool = False):
     """Merge per-shard holder tables across ``axis_name``.
@@ -689,6 +694,7 @@ def _localize_recv(app, recv_keys, recv_vals, *, num_shards, shard_index,
     return lstream, lo
 
 
+@jax.named_scope(trace.SHUFFLE)
 def _shuffle_pairs(app, stream: col.PairStream, *, axis_name, num_shards,
                    shuffle_capacity, shuffle_plan=None, wire="raw"
                    ) -> tuple[col.PairStream, jax.Array, jax.Array,
@@ -902,7 +908,8 @@ def _sort_range_fold(app, spec, lstream: col.PairStream, lo, *,
     if hot_patch is not None:
         tables, counts = hot_patch(tables, counts)
     keys = jnp.arange(K_local, dtype=jnp.int32) + lo
-    vals = jax.vmap(spec.finalize)(keys, tables, counts)
+    with jax.named_scope(trace.FINALIZE):
+        vals = jax.vmap(spec.finalize)(keys, tables, counts)
     return keys, vals, counts
 
 
@@ -1023,6 +1030,8 @@ def _surface_overflow(plan, overflow, *, strict: bool,
                 "jax.jit (the overflow count is a tracer); call "
                 "run_distributed un-jitted or check plan.diagnostics")
         return
+    with trace.span(trace.SYNC):
+        overflow = np.asarray(overflow)
     report(overflow)
 
 
@@ -1149,14 +1158,15 @@ def build_distributed_fn(
     jitted = jax.jit(sm)
 
     def postprocess(out, *, strict_shuffle: bool = False):
-        if plan.flow in ("reduce", "sort"):
-            keys, values, counts, overflow = out
-            _surface_overflow(plan, overflow, strict=strict_shuffle,
-                              shuffle_capacity=shuffle_capacity)
+        if plan.flow not in ("reduce", "sort"):
+            return out
+        keys, values, counts, overflow = out
+        _surface_overflow(plan, overflow, strict=strict_shuffle,
+                          shuffle_capacity=shuffle_capacity)
+        with trace.span(trace.POST):
             if shuffle_plan is not None:
                 return _densify_ranges(keys, values, counts, shuffle_plan)
             return keys, values, counts
-        return out
 
     return jitted, postprocess
 
@@ -1193,6 +1203,7 @@ def _merge_tables_host(spec, tables_seq, counts_seq):
     return tables
 
 
+@jax.named_scope(trace.MERGE)
 def merge_partial_tables(app, spec, tables_seq, counts_seq):
     """Merge per-shard partial holder tables in shard order, host side.
 

@@ -35,7 +35,9 @@ Counters (``STATS``) are bumped at the places the cache is meant to make
 idle — ``optimizer.derive_combiner`` (the optimizer's trace), the
 ``autotune_stream``/``autotune_sort`` calls, the measured micro-probe, and
 the staged ``compile()`` — so tests can assert "warm traffic does none of
-this" instead of trusting the docs.
+this" instead of trusting the docs.  They are process-wide totals; the
+traces and compiles inside one call are on its span's record
+(``repro.core.trace``).
 """
 
 from __future__ import annotations

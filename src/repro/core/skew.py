@@ -43,6 +43,10 @@ Derivation policy (host-side numpy, sample-sized — micro-probe cheap):
 * the default per-destination capacity envelope derives from the sampled
   p-max destination load plus :data:`CAPACITY_SLACK` headroom instead of
   the uniform ``2N/S`` assumption (:meth:`ShufflePlan.capacity_for`).
+
+``SKEW_STATS`` counts samples, cache hits and resolves process-wide; the
+traces and compiles inside one call are on its span's record
+(``repro.core.trace``).
 """
 
 from __future__ import annotations

@@ -46,6 +46,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import trace
+
 #: scoped-VMEM limit every kernel of this package is compiled with (v5e
 #: has 128 MiB of VMEM per core; Mosaic's default scope is far smaller).
 #: Tile sizes are budgeted against half of it, for double buffering.
@@ -132,7 +134,7 @@ def block_fold(keys: jax.Array, values: jax.Array, acc: jax.Array, op: str,
     acc_t = jnp.pad(acc.astype(jnp.float32).T, ((0, 0), (0, pad_k)),
                     constant_values=IDENTITY[op])
     rows = tile_n // LANES
-    out = pl.pallas_call(
+    fold = pl.pallas_call(
         functools.partial(_block_fold_kernel, op=op),
         grid=(n_blocks, keys3.shape[0]),
         in_specs=[
@@ -145,7 +147,9 @@ def block_fold(keys: jax.Array, values: jax.Array, acc: jax.Array, op: str,
         compiler_params=compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(keys3, vals_t, acc_t)
+    )
+    with jax.named_scope(trace.FOLD):
+        out = fold(keys3, vals_t, acc_t)
     return out[:, :key_space].T
 
 

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.kernels import flash_decode as _fd
 from repro.kernels import fold as _fold
 from repro.kernels import radix_partition as _rp
@@ -211,11 +212,13 @@ def sort_segment_fold(keys, values, acc, op="add", *, bucket_size=None,
         return acc.astype(jnp.float32)
     if bucket_size is None:
         bucket_size = auto_bucket_size(key_space, d=d, pad_align=pad_align)
-    pkeys, pvals, _ = radix_partition(
-        keys, values, key_space, bucket_size=bucket_size,
-        pad_align=pad_align, interpret=interpret)
-    return _segment_fold(pkeys, pvals, acc, op, tile_n=pad_align,
-                         block_k=bucket_size, interpret=interpret)
+    with jax.named_scope(trace.PARTITION):
+        pkeys, pvals, _ = radix_partition(
+            keys, values, key_space, bucket_size=bucket_size,
+            pad_align=pad_align, interpret=interpret)
+    with jax.named_scope(trace.SEGMENT_REDUCE):
+        return _segment_fold(pkeys, pvals, acc, op, tile_n=pad_align,
+                             block_k=bucket_size, interpret=interpret)
 
 
 def _segment_fold(sorted_keys, sorted_values, acc, op, *, tile_n, block_k,

@@ -45,6 +45,7 @@ import numpy as np
 from repro.checkpoint import ckpt
 from repro.core import engine as eng
 from repro.core import plan_cache as pc
+from repro.core import trace
 from repro.core.api import ExecutionOptions, MapReduce, MapReduceResult
 from repro.streaming.windows import Window
 
@@ -195,6 +196,10 @@ class MapReduceService:
 
         Thread-safe single-writer: concurrent callers serialize on the
         service lock; snapshots never wait on it."""
+        with trace.span(trace.INGEST) as span:
+            return self._ingest(items, span)
+
+    def _ingest(self, items, span: trace.span) -> int:
         if self._failed is not None:
             raise ServiceFailedError(
                 f"service is marked failed "
@@ -217,15 +222,20 @@ class MapReduceService:
             st = self._state
             b = st.batch_id  # 0-based id of the incoming batch
             slots = list(st.slots)
+            span.rid = b + 1  # the id this call publishes
             if self.window is not None:
                 i = self.window.slot_of(b)
-                # first batch of a new slide period: re-initialize the
-                # slot, overwriting (expiring) the oldest period's tables
-                seed = (self._compiled.init_state()
-                        if b % self.window.slide == 0 else slots[i])
+                seed = slots[i]
+                if b % self.window.slide == 0:
+                    # first batch of a new slide period: re-initialize the
+                    # slot, overwriting (expiring) the oldest period's
+                    # tables
+                    with trace.span(trace.SEED):
+                        seed = self._compiled.init_state()
             else:
                 i, seed = 0, slots[0]
-            slots[i] = self._compiled.ingest_state(seed, items, n)
+            with trace.span(trace.DISPATCH):
+                slots[i] = self._compiled.ingest_state(seed, items, n)
             new = _ServiceState(tuple(slots), b + 1, st.n_items + n)
             self._state = new  # atomic publish: snapshots see old or new
             if (self._ckpt_dir is not None and self.ckpt_every > 0
@@ -256,21 +266,23 @@ class MapReduceService:
                 "service not staged yet: ingest a first micro-batch or "
                 "construct with item_spec=... to compile eagerly")
         st = self._state
-        states = self._live_slots(st)
-        if len(states) == 1:
-            g = self._compiled.finalize_state(states[0])
-            keys, values, counts = g.keys, g.values, g.counts
-        elif not states:  # windowed service before any ingest
-            g = self._compiled.finalize_state(self._compiled.init_state())
-            keys, values, counts = g.keys, g.values, g.counts
-        else:
-            pairs = [self._compiled.state_tables(s) for s in states]
-            keys, values, counts = eng.merge_partial_tables(
-                self.app, self.spec,
-                [t for t, _ in pairs], [c for _, c in pairs])
-        return MapReduceResult(keys, values, counts,
-                               plan=self._compiled.plan,
-                               batch_id=st.batch_id)
+        with trace.span(trace.SNAPSHOT, rid=st.batch_id):
+            states = self._live_slots(st)
+            if len(states) > 1:
+                with trace.span(trace.MERGE):
+                    pairs = [self._compiled.state_tables(s) for s in states]
+                    keys, values, counts = eng.merge_partial_tables(
+                        self.app, self.spec,
+                        [t for t, _ in pairs], [c for _, c in pairs])
+            else:
+                # one live slot, or a windowed service before any ingest
+                state = states[0] if states else self._compiled.init_state()
+                with trace.span(trace.FINALIZE):
+                    g = self._compiled.finalize_state(state)
+                keys, values, counts = g.keys, g.values, g.counts
+            return MapReduceResult(keys, values, counts,
+                                   plan=self._compiled.plan,
+                                   batch_id=st.batch_id)
 
     @property
     def batch_id(self) -> int:
