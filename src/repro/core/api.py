@@ -399,7 +399,12 @@ class MapReduce:
             stream_chunk_pairs = self.tiling.chunk_pairs
             key_block = (self.tiling.key_block if self.tiling.blocked
                          else None)
-            if self.tiling.mode == "scatter" and self.plan.spec.mxu_lowerable:
+            spec = self.plan.spec
+            chosen = col.scatter_fold_chosen(
+                spec, app.key_space, kernel_additive=(
+                    use_kernels and spec.kernel_additive_ok(app.value_aval)))
+            if (self.tiling.mode == "scatter" and spec.mxu_lowerable
+                    and not chosen):
                 self.plan.diagnostics += (
                     "stream fold degraded to exact scatter (dense budgets "
                     "exceeded) — see tiling notes",)

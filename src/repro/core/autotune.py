@@ -20,7 +20,8 @@ Model-driven selection (the default, ``source="model"``):
   fold — the measured regime in which XLA keeps the one-hot contraction
   on-chip (beyond it the ``[chunk, K]`` expansion round-trips HBM); the
   Pallas kernel path is exempt, its one-hot tile is VMEM-resident at any
-  chunk size.
+  chunk size, and so is a fold the TPU lowers to scatter-add above
+  ``collector.TPU_SCATTER_MIN_KEYS`` keys (``scatter_fold_chosen``).
 * ``key_block`` — sized per lowering from its memory model: the Pallas
   fold kernels keep a ``[Kb, Td]`` table block plus a ``[Tn, Kb]`` one-hot
   tile VMEM-resident (``stream_working_set_bytes`` vs ``VMEM_BUDGET`` with
@@ -232,6 +233,8 @@ def autotune_stream(
     # holders under use_kernels=True — the pure-JAX budgets apply.
     kernel_additive = use_kernels and spec.kernel_additive_ok(app.value_aval)
     kernel_monoid = use_kernels and spec.kernel_monoid_ok(app.value_aval)
+    scatter_additive = col.scatter_fold_chosen(
+        spec, K, kernel_additive=kernel_additive)
 
     manual_chunk = isinstance(chunk_pairs, int)
     if manual_chunk:
@@ -240,7 +243,8 @@ def autotune_stream(
         chunk = choose_chunk_pairs(
             K, holder_bytes=holder_bytes, pair_bytes=pair_bytes,
             emit_capacity=app.emit_capacity, n_pairs_hint=n_pairs_hint,
-            fused_cap=spec.mxu_lowerable and not kernel_additive)
+            fused_cap=(spec.mxu_lowerable and not kernel_additive
+                       and not scatter_additive))
 
     manual_block = key_block is None or isinstance(key_block, int)
     def pick_block(chunk_now: int) -> int:
@@ -248,6 +252,8 @@ def autotune_stream(
             return K
         if isinstance(key_block, int):
             return max(1, min(int(key_block), K))
+        if scatter_additive:
+            return K  # the scatter folds the whole table at once
         if kernel_monoid and not spec.mxu_lowerable:
             # chunk_monoid_fold auto-sizes its own key block (its VMEM
             # model carries the extra [Tn, Kb, D] masked-expansion term);
@@ -293,8 +299,16 @@ def autotune_stream(
                    or chunk <= col.ADDITIVE_FOLD_PAIRS_FUSED)
     dense_ok = (kernel_monoid
                 or chunk * blk <= col.DENSE_FOLD_ELEMS_BUDGET)
-    mode = col.stream_mode(spec, dense_ok=dense_ok, additive_ok=additive_ok)
-    if spec.mxu_lowerable and mode == "scatter":
+    mode = col.stream_mode(spec, dense_ok=dense_ok, additive_ok=additive_ok,
+                           scatter_additive=scatter_additive)
+    lowering = f"fold lowering: {mode} on {col.fold_platform()}"
+    if scatter_additive:
+        lowering += (f", key_space={K} > {col.TPU_SCATTER_MIN_KEYS} "
+                     f"(one-hot {col.TPU_ONEHOT_S_PER_PAIR_KEY:g} s per "
+                     f"pair·key, scatter-add {col.TPU_SCATTER_S_PER_PAIR:g} "
+                     f"s per pair)")
+    notes.append(lowering)
+    if spec.mxu_lowerable and mode == "scatter" and not scatter_additive:
         notes.append(
             f"FALLBACK: chunk_pairs={chunk} is outside the fused one-hot "
             f"contraction regime (N <= {col.ADDITIVE_FOLD_PAIRS_FUSED} "

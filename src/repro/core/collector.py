@@ -35,7 +35,7 @@ import numpy as np
 from jax import lax
 
 from repro.core import combiner as C
-from repro.core import trace
+from repro.core import plan_cache, trace
 
 SENTINEL = "sentinel"  # invalid-pair key == key_space
 
@@ -382,13 +382,50 @@ DENSE_FOLD_ELEMS_BUDGET = 1 << 24
 ADDITIVE_FOLD_PAIRS_FUSED = 2048
 
 
+#: chip rates of the two exact lowerings of a pure-JAX additive fold on a
+#: TPU v5e, per channel (values and counts each pay them), read from the
+#: benchmark's traces.  An int32 one-hot contraction has no MXU path: it
+#: runs as a compare-select-reduce on the vector unit, 4,194 ms a job for
+#: 1.68e7 pairs x 131,072 keys in ``wc_large.batch``.  The scatter-add
+#: costs 0.496 ms per 65,536-pair chunk in ``wc_large.ingest``.
+TPU_ONEHOT_S_PER_PAIR_KEY = 1.9e-12
+TPU_SCATTER_S_PER_PAIR = 7.6e-9
+
+#: the key space above which a TPU folds additive holders by scatter-add:
+#: the one-hot costs grow with K, the scatter's do not (4,000 keys).
+TPU_SCATTER_MIN_KEYS = round(TPU_SCATTER_S_PER_PAIR
+                             / TPU_ONEHOT_S_PER_PAIR_KEY)
+
+
+def fold_platform() -> str:
+    """Platform the folds are built for: the default devices' (as
+    ``roofline.peaks`` reads the running device's kind)."""
+    return jax.default_backend()
+
+
+def scatter_fold_chosen(spec: C.CombinerSpec, key_space: int, *,
+                        kernel_additive: bool = False) -> bool:
+    """Whether the pure-JAX additive fold lowers to an exact scatter-add.
+
+    On a TPU above :data:`TPU_SCATTER_MIN_KEYS` the one-hot contraction
+    costs more per pair than the scatter.  XLA:CPU keeps the one-hot
+    contraction, where the scatter is a serialized per-pair loop, and so
+    does the Pallas fold kernel."""
+    return (spec.mxu_lowerable and spec.scatter_lowerable
+            and not kernel_additive and fold_platform() == "tpu"
+            and key_space > TPU_SCATTER_MIN_KEYS)
+
+
 def stream_mode(spec: C.CombinerSpec, *, dense_ok: bool = True,
-                additive_ok: bool | None = None) -> str:
+                additive_ok: bool | None = None,
+                scatter_additive: bool = False) -> str:
     """Pick the per-chunk fold lowering for the streaming collector.
 
     ``dense_ok`` gates the masked-expansion folds (max/min/mul/bool);
     ``additive_ok`` gates the one-hot matmul fold (defaults to ``dense_ok``
-    for backward compatibility — the budgets differ, see above).
+    for backward compatibility — the budgets differ, see above);
+    ``scatter_additive`` folds additive specs by scatter-add
+    (:func:`scatter_fold_chosen`).
     """
     if additive_ok is None:
         additive_ok = dense_ok
@@ -396,6 +433,8 @@ def stream_mode(spec: C.CombinerSpec, *, dense_ok: bool = True,
         return "size"
     if spec.strategy == C.STRATEGY_FIRST:
         return "first"
+    if spec.mxu_lowerable and scatter_additive:
+        return "scatter"
     if spec.mxu_lowerable and additive_ok:
         return "additive"
     if spec.scatter_lowerable:
@@ -419,7 +458,7 @@ def choose_dense_key_block(key_space: int, chunk_pairs: int | None,
 
 
 class StreamCombiner:
-    """Chunked scatter-free fold of a pair stream into carried holder tables.
+    """Chunked fold of a pair stream into carried holder tables.
 
     The engine's streaming flow threads ``state`` through a ``lax.scan`` over
     map chunks; :meth:`fold_chunk` folds one chunk's emitted pairs into the
@@ -444,11 +483,14 @@ class StreamCombiner:
     * first    — vectorized first-occurrence gather, kept only where the
       carried count is still zero.
     * size     — counts only.
-    * scatter  — exact ``table.at[keys].<op>`` folds, selected only when the
-      scatter-free lowerings cannot stay on-chip (pure-JAX additive folds:
-      ``chunk`` beyond :data:`ADDITIVE_FOLD_PAIRS_FUSED` — the Pallas
-      kernel path has no such limit; masked folds: ``chunk × key_block``
-      beyond :data:`DENSE_FOLD_ELEMS_BUDGET` at the minimum block).  Emits
+    * scatter  — exact ``table.at[keys].<op>`` folds of every leaf and of
+      the counts.  The lowering of pure-JAX additive folds on a TPU above
+      :data:`TPU_SCATTER_MIN_KEYS` (:func:`scatter_fold_chosen`);
+      elsewhere selected only when the scatter-free lowerings cannot stay
+      on-chip (pure-JAX additive folds: ``chunk`` beyond
+      :data:`ADDITIVE_FOLD_PAIRS_FUSED` — the Pallas kernel path has no
+      such limit; masked folds: ``chunk × key_block`` beyond
+      :data:`DENSE_FOLD_ELEMS_BUDGET` at the minimum block).  Emits
       :class:`LoweringFallbackWarning` when an MXU-lowerable spec degrades
       this way.
     * sequential — per-pair gather/combine/write-back scan (coupled holders).
@@ -495,9 +537,13 @@ class StreamCombiner:
         # while the per-fold pair count is inside the fused regime.
         additive_ok = (kernel_additive or chunk_pairs is None or
                        chunk_pairs <= ADDITIVE_FOLD_PAIRS_FUSED)
+        scatter_additive = scatter_fold_chosen(
+            spec, key_space, kernel_additive=kernel_additive)
         self.mode = (mode if mode is not None else
                      stream_mode(spec, dense_ok=self._dense_ok,
-                                 additive_ok=additive_ok))
+                                 additive_ok=additive_ok,
+                                 scatter_additive=scatter_additive))
+        plan_cache.STATS.folds[self.mode] += 1
         kernels_used = ((kernel_additive and self.mode == "additive")
                         or (kernel_monoid and self.mode == "dense"))
         if (fold_fn is not None or monoid_fold_fn is not None) \
@@ -508,7 +554,8 @@ class StreamCombiner:
                 f"({spec.describe or spec.strategy} over {value_aval}, "
                 f"fold mode {self.mode}); the chunks fold in pure JAX.",
                 on_fallback)
-        if mode is None and spec.mxu_lowerable and self.mode == "scatter":
+        if (mode is None and spec.mxu_lowerable and self.mode == "scatter"
+                and not scatter_additive):
             _emit_fallback(
                 f"stream flow: dense fold budgets exceeded at key_space="
                 f"{key_space}, chunk_pairs={chunk_pairs}, key_block="
@@ -599,7 +646,7 @@ class StreamCombiner:
         return self._blocked(one)
 
     def _chunk_counts(self, stream: PairStream) -> jax.Array:
-        if not self._dense_ok:
+        if not self._dense_ok or self.mode == "scatter":
             return jnp.zeros((self.key_space,), jnp.int32).at[stream.keys].add(
                 stream.valid.astype(jnp.int32), mode="drop")
         if self.key_block is not None:
@@ -632,11 +679,32 @@ class StreamCombiner:
                                 stream.keys, stream.values)
 
     def _fold_scatter(self, tables, counts, stream: PairStream):
-        # exact large-K fallback: same per-chunk semantics as combine_scatter
-        # but folding into the *carried* tables instead of identity ones
+        # same per-chunk semantics as combine_scatter, but folding into the
+        # *carried* tables instead of identity ones
         mapped = _premap_stream(self.spec, stream.values)
+        leaves = jax.tree.leaves(tables)
+        if self.spec.mxu_lowerable and all(t.dtype == counts.dtype
+                                           for t in leaves):
+            # every channel and the counts in ONE [K, ΣD + 1] scatter-add:
+            # on a TPU v5e, 65,536 pairs into K = 131,072 cost 0.77 ms this
+            # way against 1.00 ms as a [K] scatter each.  The carried state
+            # stays (tables, counts), the layout every reader of it takes.
+            K, n = self.key_space, stream.keys.shape[0]
+            acc = jnp.concatenate(
+                [t.reshape(K, -1) for t in leaves] + [counts[:, None]], 1)
+            upd = jnp.concatenate(
+                [c.reshape(n, -1).astype(counts.dtype)
+                 for c in jax.tree.leaves(mapped)]
+                + [stream.valid.astype(counts.dtype)[:, None]], 1)
+            acc = acc.at[stream.keys].add(upd, mode="drop")
+            out, off = [], 0
+            for t in leaves:
+                size = int(np.prod(t.shape[1:]))
+                out.append(acc[:, off:off + size].reshape(t.shape))
+                off += size
+            return jax.tree.unflatten(self._holder_treedef, out), acc[:, -1]
         out = []
-        for mono, tab, chan in zip(self.spec.monoids, jax.tree.leaves(tables),
+        for mono, tab, chan in zip(self.spec.monoids, leaves,
                                    jax.tree.leaves(mapped)):
             upd = getattr(tab.at[stream.keys], mono.scatter_method)
             out.append(upd(chan.astype(tab.dtype), mode="drop"))

@@ -59,6 +59,11 @@ PLAN_CACHE_ENV = "JAX_PALLAS_PLAN_CACHE"
 # ---------------------------------------------------------------------------
 
 
+#: the streaming fold's lowerings (``collector.stream_mode``)
+FOLD_LOWERINGS = ("additive", "dense", "scatter", "first", "size",
+                  "sequential")
+
+
 @dataclasses.dataclass
 class CacheStats:
     """Process-wide event counters (see module docstring).
@@ -68,7 +73,9 @@ class CacheStats:
     measured micro-probe invocations, ``compiles`` the staged XLA
     compiles.  ``hits``/``misses`` are in-memory compiled-plan lookups;
     ``plan_hits``/``plan_misses`` the plan-stage (pre-shape) lookups;
-    ``file_hits`` the advisory file-layer hits."""
+    ``file_hits`` the advisory file-layer hits.  ``folds`` counts the
+    streaming folds built (``collector.StreamCombiner``) per lowering,
+    ``folds.<lowering>`` in a snapshot."""
 
     derives: int = 0
     autotunes: int = 0
@@ -79,9 +86,14 @@ class CacheStats:
     plan_hits: int = 0
     plan_misses: int = 0
     file_hits: int = 0
+    folds: dict = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(FOLD_LOWERINGS, 0))
 
     def snapshot(self) -> dict:
-        return dataclasses.asdict(self)
+        snap = dataclasses.asdict(self)
+        folds = snap.pop("folds")
+        snap.update({f"folds.{m}": n for m, n in folds.items()})
+        return snap
 
 
 STATS = CacheStats()
@@ -248,14 +260,16 @@ def plan_key(app, *, flow: str, trust_semantics: bool,
              combine_impl: str, chunk_pairs, key_block,
              autotune_probe: bool, streaming: bool = False) -> str:
     """Key of the plan stage (derivation + flow selection + tiling) —
-    everything :class:`MapReduce` resolves before it sees item shapes."""
+    everything :class:`MapReduce` resolves before it sees item shapes,
+    which includes the platform the fold lowering is chosen for."""
+    from repro.core import collector
     return _digest(
         "plan", reduce_fingerprint(app), _app_attr_sig(app),
         f"flow={flow}", f"trust={trust_semantics}",
         f"hint={n_pairs_hint}", f"kern={use_kernels}",
         f"impl={combine_impl}", f"chunk={chunk_pairs}",
         f"blk={key_block}", f"probe={autotune_probe}",
-        f"streaming={streaming}")
+        f"streaming={streaming}", f"platform={collector.fold_platform()}")
 
 
 def compiled_key(app, items_spec, *, plan_key: str, flow: str,

@@ -8,6 +8,8 @@ overruns.  The topology is described inside a fixture, so collection
 never loads the TPU compiler.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -92,3 +94,33 @@ def test_radix_partition_compiles(one_chip, key_space, buckets):
     text = _compile_text(part, _spec(one_chip, (n,), jnp.int32),
                          _spec(one_chip, (n, D)))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_wordcount_batch_fold_lowering_compiles(one_chip, monkeypatch,
+                                                platform):
+    """The WordCount batch job (16 words a window, K = 131,072) through
+    ``MapReduce.run``'s program: with the fold rule set to the TPU the fold
+    scatters, 65,536 pairs a chunk, and holds no compare-select-reduce over
+    ``[2048, 8192]`` one-hot blocks; with it set to the CPU it keeps them."""
+    from repro.core import MapReduce, make_app
+    from repro.core import collector as col
+
+    monkeypatch.setattr(col, "fold_platform", lambda: platform)
+    app = make_app(
+        lambda window, emit: emit(window, jnp.ones_like(window)),
+        lambda k, v, c: jnp.sum(v),
+        key_space=K, value_aval=jax.ShapeDtypeStruct((), jnp.int32),
+        emit_capacity=16, max_values_per_key=16384)
+    mr = MapReduce(app, cache=False)
+    items = _spec(one_chip, (1 << 14, 16), jnp.int32)
+    text = mr.lower(items).compile().as_text()
+    # the one-hot of 2,048 pairs against an 8,192-key block, in either layout
+    onehot_blocks = re.search(
+        r"pred\[(2048,8192|8192,2048)\]\S* compare\(", text) is not None
+    if platform == "tpu":
+        assert mr.tiling.mode == "scatter" and mr.tiling.chunk_pairs == 65536
+        assert "scatter(" in text and not onehot_blocks
+    else:
+        assert mr.tiling.mode == "additive"
+        assert onehot_blocks
