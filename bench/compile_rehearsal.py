@@ -32,19 +32,21 @@ def rehearse(cell, topo) -> list[str]:
     from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
     from jax.sharding import PartitionSpec as P
 
-    from bench import registry
+    from bench import harness, registry
     from repro.core import ExecutionOptions, MapReduce, ShuffleOptions
 
     app_mod = registry.load_module(cell.app_path)
     cfg, tr = cell.config, cell.traffic
-    shape, dtype = app_mod.items_shape(cfg)
     if cell.chips == 1:
         sharding = key_sharding = SingleDeviceSharding(topo.devices[0])
     else:
         mesh = Mesh(topo.devices[:cell.chips], (tr["axis"],))
         sharding = NamedSharding(mesh, P(tr["axis"]))
         key_sharding = NamedSharding(mesh, P())
-    spec = jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    spec = harness.map_item_shapes(
+        lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=sharding),
+        app_mod, cfg)
     key = jax.ShapeDtypeStruct((2,), jax.numpy.uint32, sharding=key_sharding)
     gen = jax.jit(lambda k: app_mod.generate(
         cfg, jax.random.wrap_key_data(k)), out_shardings=sharding)

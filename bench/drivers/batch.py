@@ -1,8 +1,9 @@
 """Batch jobs on one chip: ``MapReduce(app, flow=...).run(items)``.
 
-Set-up makes the input on the chip, plans the job and compiles it: the
-ahead-of-time executable that ``run`` then finds in the engine's
-in-memory cache.  The window runs whole jobs back to back.
+Set-up makes the input on the chip, plans the job, compiles it (the
+ahead-of-time executable that ``run`` then finds in the engine's in-memory
+cache) and runs it once.  The window runs whole jobs back to back, some
+dispatched ahead.
 """
 
 from __future__ import annotations
@@ -20,8 +21,8 @@ class Driver(BatchJobs):
         self.mr = MapReduce(run.app_mod.make_app(run.cfg),
                             flow=run.traffic["flow"])
         self.compiled = self.mr.lower(self.items).compile()
+        self.warm_up()
         harness.log(f"plan: {self.plan()}")
-        self.outs = []
 
     def call(self):
         return self.mr.run(self.items)

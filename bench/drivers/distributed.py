@@ -36,7 +36,7 @@ class Driver(BatchJobs):
             shuffle=ShuffleOptions(capacity=int(cap), strict=tr["strict"],
                                    wire=tr["wire"]))
         self.mr = MapReduce(app, flow=tr["flow"])
-        self.outs = [self.job()]
+        self.warm_up()
         harness.log(f"plan: {self.plan()}")
 
     def call(self):

@@ -121,14 +121,15 @@ class Driver:
         def batch_counts(b):
             i = b % n_pool
             if i not in per_batch:
-                per_batch[i] = self.run.expected(
-                    items[i * self.batch:(i + 1) * self.batch])
+                per_batch[i] = self.run.ref_mod.counts(
+                    items[i * self.batch:(i + 1) * self.batch], self.run.cfg)
             return per_batch[i]
 
         answers, expected = [], []
         for n, *out in self.snaps:
             cover = ref_windows.covered(n, int(tr["window_size"]),
                                         int(tr["window_slide"]))
-            expected.append(sum(batch_counts(b) for b in cover))
+            expected.append(harness.count_table(
+                sum(batch_counts(b) for b in cover)))
             answers.append((f"snapshot after {n} batches", *out))
         return answers, expected

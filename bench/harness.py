@@ -12,12 +12,31 @@ warms up the program, and the methods
 - ``hlo_texts() -> list[str]``: the executed programs' HLO, which the
   trace reduction classifies device ops against;
 - ``answers() -> (list, list)``: every answer the timed path produced,
-  ``(label, keys, values, counts)`` on the host, and the reference's
-  expected counts for each; called once the device state is freed.
+  ``(label, keys, values, counts)`` on the host (``values`` a pytree of
+  columns), and the reference's expected table for each; called once the
+  device state is freed.
+
+The reference (``bench/reference/<app>.py``, NumPy only, importing
+nothing of the program) defines one of
+
+- ``table(items, cfg) -> {"values": {name: ndarray[K, ...]}, "counts":
+  ndarray[K]}``: a flat dict of named value columns and the count of each
+  of the ``K`` keys;
+- ``counts(items, cfg) -> ndarray[K]``: a job whose every value is a sum
+  of ones, the table whose one column ``value`` equals the counts.
+
+``items`` is the host copy of the input: one array, or a pytree of
+columns.  The program's values are named the same way (``columns``): a
+single array is the column ``value``, a pytree's leaves take their path
+(dict keys, tuple positions) joined by ``.``.  A column is compared
+exactly unless the configuration's file states a tolerance for it:
+``"tolerance": {name: {"rtol": r, "atol": a, "why": "<reason>"}}``, which
+``tolerances`` checks when the run is set up.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import shutil
@@ -160,56 +179,208 @@ class Run:
         self.rehearse = rehearse
         self.app_mod = registry.load_module(cell.app_path)
         self.ref_mod = registry.load_module(cell.reference_path)
+        self.tolerance = tolerances(self.cfg, self.app_mod, self.ref_mod)
         self.spans = Spans()
 
     def make_items(self, sharding=None):
-        """The cell's input, made on the device from the seed in one
-        jitted call, laid out by ``sharding`` (default: the first chip)."""
+        """The cell's input (one array or a pytree of columns), made on
+        the device from the seed in one jitted call, every leaf laid out
+        by ``sharding`` (default: the first chip)."""
         import jax
         from jax.sharding import SingleDeviceSharding
 
         sharding = sharding or SingleDeviceSharding(self.devices[0])
         gen = jax.jit(lambda k: self.app_mod.generate(self.cfg, k),
                       out_shardings=sharding)
-        items = gen(prng_key(self.seed))
-        return items.block_until_ready()
+        return jax.block_until_ready(gen(prng_key(self.seed)))
 
-    def expected(self, items_host) -> np.ndarray:
-        return self.ref_mod.counts(items_host, self.cfg)
+    def expected(self, items_host) -> dict:
+        return reference_table(self.ref_mod, items_host, self.cfg)
 
 
-def run_jobs(job, seconds: float):
-    """Jobs back to back; the window closes when the first job that ends
-    after ``seconds`` has ended.  Returns ``(window_s, outputs)``."""
-    outs = []
+def count_table(counts: np.ndarray) -> dict:
+    """The table of a job whose every value is a sum of ones."""
+    return {"values": {"value": counts}, "counts": counts}
+
+
+def reference_table(ref_mod, items_host, cfg) -> dict:
+    if hasattr(ref_mod, "table"):
+        return ref_mod.table(items_host, cfg)
+    return count_table(ref_mod.counts(items_host, cfg))
+
+
+def _shape_pair(x) -> bool:
+    return (isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
+            and all(isinstance(d, int) for d in x[0]))
+
+
+def map_item_shapes(fn, app_mod, cfg):
+    """``fn(shape, dtype)`` for each leaf of ``items_shape(cfg)``: one
+    ``(shape, dtype)`` pair for a single array, or a dict or tuple of them
+    for columns."""
+    import jax
+
+    return jax.tree_util.tree_map(lambda sd: fn(*sd),
+                                  app_mod.items_shape(cfg),
+                                  is_leaf=_shape_pair)
+
+
+def tolerances(cfg, app_mod, ref_mod) -> dict:
+    """The configuration's stated tolerances, ``{column: (rtol, atol)}``.
+
+    Raises unless each names a float column that the reference returns and
+    gives its reason: integers are always exact.  The reference's columns
+    are read from its table of an input of no rows."""
+    stated = cfg.get("tolerance", {})
+    if not stated:
+        return {}
+    empty = map_item_shapes(lambda shape, dtype: np.zeros(
+        (0,) + tuple(shape[1:]), dtype), app_mod, cfg)
+    with np.errstate(all="ignore"):
+        cols = columns(reference_table(ref_mod, empty, cfg)["values"])
+    out = {}
+    for name, t in stated.items():
+        if not isinstance(t, dict) or not str(t.get("why", "")).strip():
+            raise ValueError(f"tolerance on {name!r} gives no 'why'")
+        if set(t) - {"rtol", "atol", "why"}:
+            raise ValueError(f"tolerance on {name!r}: unknown keys "
+                             f"{sorted(set(t) - {'rtol', 'atol', 'why'})}")
+        if name not in cols:
+            raise ValueError(f"tolerance on {name!r}, a column the "
+                             f"reference does not return ({sorted(cols)})")
+        if not np.issubdtype(cols[name].dtype, np.floating):
+            raise ValueError(f"tolerance on {name!r}, a {cols[name].dtype} "
+                             f"column: integers are compared exactly")
+        rtol, atol = float(t.get("rtol", 0)), float(t.get("atol", 0))
+        if not (rtol >= 0 and atol >= 0):
+            raise ValueError(f"tolerance on {name!r}: rtol and atol must "
+                             f"be numbers >= 0")
+        out[name] = (rtol, atol)
+    return out
+
+
+def run_jobs(start, finish, seconds: float, ahead: int = 0):
+    """Jobs back to back, ``ahead`` of them dispatched beyond the one the
+    host waits for, so that the chip stays fed while the host stands still.
+
+    ``start()`` dispatches a job and returns its handle; ``finish(handle)``
+    waits for it and returns its answer on the host.  No job starts once
+    ``seconds`` have passed; the window closes when every job started has
+    finished, so all of that work counts over all of that time (with
+    ``ahead = 0``: when the first job that ends after ``seconds`` has
+    ended).  Returns ``(window_s, outputs)``."""
+    outs, pending = [], collections.deque()
     t0 = time.perf_counter()
-    while True:
-        outs.append(job())
-        window = time.perf_counter() - t0
-        if window >= seconds:
-            return window, outs
+    while time.perf_counter() - t0 < seconds:
+        pending.append(start())
+        while len(pending) > ahead:
+            outs.append(finish(pending.popleft()))
+    while pending:
+        outs.append(finish(pending.popleft()))
+    return time.perf_counter() - t0, outs
 
 
 def fetch(res):
     """A result on the host: the job is not done before the user has it."""
-    return tuple(np.asarray(a) for a in (res.keys, res.values, res.counts))
+    import jax
+
+    return (np.asarray(res.keys),
+            jax.tree_util.tree_map(np.asarray, res.values),
+            np.asarray(res.counts))
 
 
-def wrong_keys(answer, expected: np.ndarray) -> int:
-    """Keys whose key id, value or count differs from the reference, plus
-    any count past the key space."""
+def _name(entry) -> str:
+    for attr in ("key", "idx", "name"):
+        if hasattr(entry, attr):
+            return str(getattr(entry, attr))
+    return str(entry)
+
+
+def columns(values) -> dict:
+    """An answer's value columns by name: a single array is ``value``, a
+    pytree's leaves are named by their path, joined by ``.``."""
+    import jax
+
+    leaves = jax.tree_util.tree_flatten_with_path(values)[0]
+    return {".".join(_name(e) for e in path) or "value": np.asarray(leaf)
+            for path, leaf in leaves}
+
+
+def _differs(got, want: np.ndarray, tol=None) -> np.ndarray:
+    """Per key of ``want``'s ``K``: whether any element of its row differs
+    (beyond ``tol = (rtol, atol)``, as ``np.isclose`` measures it against
+    the reference; NaN matches NaN).  A row the program lacks differs; so
+    does every row where the column's shape per key is not the
+    reference's."""
+    got = np.asarray(got)
+    K = want.shape[0]
+    bad = np.ones(K, bool)
+    if got.shape[1:] != want.shape[1:]:
+        return bad
+    n = min(K, got.shape[0])
+    g, w = got[:n].reshape(n, -1), want[:n].reshape(n, -1)
+    if (tol is None and np.issubdtype(g.dtype, np.integer)
+            and np.issubdtype(w.dtype, np.integer)):
+        d = g.astype(np.int64) != w.astype(np.int64)
+    else:
+        rtol, atol = tol or (0.0, 0.0)
+        d = ~np.isclose(g.astype(np.float64), w.astype(np.float64),
+                        rtol=rtol, atol=atol, equal_nan=True)
+    bad[:n] = d.any(axis=1)
+    return bad
+
+
+def wrong_keys(answer, expected: dict, tolerance=None) -> int:
+    """Keys whose key id or count differs from the reference table, or
+    any element of any value column beyond that column's tolerance (exact
+    where none is stated), plus any count past the key space.  A column
+    missing from the answer, or one the reference lacks, makes every key
+    wrong."""
     keys, values, counts = answer
-    K = expected.shape[0]
-    values = np.asarray(values).reshape(values.shape[0], -1)[:, 0]
-    bad = ((np.asarray(keys)[:K] != np.arange(K))
-           | (values[:K].astype(np.int64) != expected)
-           | (np.asarray(counts)[:K].astype(np.int64) != expected))
+    tolerance = tolerance or {}
+    want = columns(expected["values"])
+    got = columns(values)
+    K = expected["counts"].shape[0]
+    bad = (_differs(keys, np.arange(K))
+           | _differs(counts, expected["counts"]))
+    if set(got) != set(want):
+        bad[:] = True
+    else:
+        for name, col in want.items():
+            bad |= _differs(got[name], col, tolerance.get(name))
     return int(bad.sum()) + int(np.count_nonzero(np.asarray(counts)[K:]))
 
 
-def check(answers, expected) -> dict:
-    """``wrong_keys`` summed over every answer; exact, so its limit is 0."""
-    wrong = [wrong_keys(a[1:], e) for a, e in zip(answers, expected)]
+def largest_relative_errors(answers, expected) -> dict:
+    """Per float column of the reference, the largest ``|got - want| /
+    |want|`` over every answer (where ``want`` is finite and not 0)."""
+    worst: dict = {}
+    for a, e in zip(answers, expected):
+        got = columns(a[2])
+        for name, w in columns(e["values"]).items():
+            if not np.issubdtype(w.dtype, np.floating):
+                continue
+            g = got.get(name)
+            err = 0.0
+            if g is not None and g.shape[1:] == w.shape[1:]:
+                n = min(len(g), len(w))
+                g64, w64 = g[:n].astype(np.float64), w[:n].astype(np.float64)
+                ok = np.isfinite(w64) & (w64 != 0)
+                with np.errstate(all="ignore"):
+                    rel = np.abs(g64[ok] - w64[ok]) / np.abs(w64[ok])
+                err = float(np.nanmax(rel, initial=0.0))
+            worst[name] = max(worst.get(name, 0.0), err)
+    return worst
+
+
+def check(answers, expected, tolerance=None) -> dict:
+    """``wrong_keys`` summed over every answer; exact, so its limit is 0.
+    Logs the largest relative error of each float column."""
+    wrong = [wrong_keys(a[1:], e, tolerance) for a, e in zip(answers, expected)]
+    for name, err in largest_relative_errors(answers, expected).items():
+        rtol, atol = (tolerance or {}).get(name, (0.0, 0.0))
+        log(f"column {name}: largest relative error {err!r} "
+            f"(rtol {rtol!r}, atol {atol!r})")
     return {"answers": len(answers),
             "failed": sum(1 for w in wrong if w),
             "wrong_keys": sum(wrong)}
@@ -313,7 +484,7 @@ def execute(cell: registry.Cell, *, seed: int, seconds: float, trace: bool,
     t = time.perf_counter()
     answers, expected = driver.answers()
     del driver
-    checked = check(answers, expected)
+    checked = check(answers, expected, run.tolerance)
     log(f"reference compared {checked['answers']} answers in "
         f"{time.perf_counter() - t:.1f}s")
     checks = {"wrong_keys": {"value": checked["wrong_keys"], "limit": 0}}

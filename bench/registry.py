@@ -3,12 +3,22 @@
 A cell names a configuration and a traffic mix.  The configuration's file
 names its ``app``; the files are then found by name alone:
 
-- ``bench/apps/<app>.py``: the job's map/reduce and the input generator;
-- ``bench/reference/<app>.py``: the plain reference;
+- ``bench/apps/<app>.py``: the job's map/reduce and the input generator
+  (``make_app``, ``generate``, ``items_shape``, ``pairs``, ``key_space``);
+  the input is one array or a pytree of columns;
+- ``bench/reference/<app>.py``: the plain reference, NumPy only: either
+  ``table(items, cfg)``, a dict ``{"values": {name: ndarray[K, ...]},
+  "counts": ndarray[K]}``, or ``counts(items, cfg)`` for a job whose
+  values are its counts (``bench/harness.py`` says how columns are named
+  and compared);
 - ``bench/traffic/<traffic>.json``: the traffic's parameters and its
   ``driver``;
 - ``bench/drivers/<driver>.py``: the entry point the window drives;
 - ``bench/metrics/<metric>.py``: one reader per per-layer metric.
+
+The configuration's file may state a tolerance for a float column of the
+reference's table, ``"tolerance": {name: {"rtol": r, "atol": a, "why":
+"<reason>"}}``; every other column is compared exactly.
 
 So a new cell, configuration, traffic mix or metric is a new file and an
 entry in ``BENCHMARK.json``, never an edit of these files.

@@ -14,18 +14,21 @@ sys.path.insert(0, str(ROOT))
 
 from bench import registry  # noqa: E402
 
-PRELUDE = f"""
+def prelude(root: Path) -> str:
+    """Load ``<root>/bench/run.py`` as ``run``, with the program's ``src``
+    of this repository on the path."""
+    return f"""
 import importlib.util, sys
-sys.path[:0] = [{str(ROOT)!r}, {str(ROOT / 'src')!r}]
+sys.path[:0] = [{str(root)!r}, {str(ROOT / 'src')!r}]
 spec = importlib.util.spec_from_file_location(
-    "bench_run_main", {str(ROOT / 'bench' / 'run.py')!r})
+    "bench_run_main", {str(root / 'bench' / 'run.py')!r})
 run = importlib.util.module_from_spec(spec)
 spec.loader.exec_module(run)
 """
 
 
 def bench(args, *, devices: int = 1, patch: str = "", script: str = "",
-          cwd: Path = ROOT, timeout: float = 600):
+          cwd: Path = ROOT, root: Path = ROOT, timeout: float = 600):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     if devices > 1:
@@ -34,7 +37,8 @@ def bench(args, *, devices: int = 1, patch: str = "", script: str = "",
         cmd = [sys.executable, script, *args]
     else:
         cmd = [sys.executable, "-c",
-               PRELUDE + patch + f"\nsys.exit(run.main({list(args)!r}))"]
+               prelude(root) + patch
+               + f"\nsys.exit(run.main({list(args)!r}))"]
     return subprocess.run(cmd, capture_output=True, text=True,
                           timeout=timeout, env=env, cwd=cwd)
 
