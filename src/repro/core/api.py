@@ -44,6 +44,7 @@ Every entry point — ``run*``, ``Compiled.__call__`` and
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import warnings as _warnings
 from functools import partial
@@ -270,6 +271,21 @@ class MapReduceResult:
         return {int(k): vals[k] for k in np.nonzero(counts > 0)[0]}
 
 
+def wide_job(app) -> bool:
+    """Whether ``app`` is a *wide* job: one of its declared value columns
+    is a 64-bit integer (``value_aval``).
+
+    JAX holds arrays at 32 bits unless ``jax_enable_x64`` is on, and a TPU
+    emulates 64-bit integers, so no global flag is asked of the user: the
+    entry points plan, compile and run a wide job in a 64-bit scope
+    (:meth:`MapReduce.width`).  Every array the engine makes for itself keeps
+    an explicit 32-bit dtype there (key ids, counts, masks, iotas); only
+    the declared columns, the premap's products and their holders are
+    64-bit.  A wide job takes 32-bit inputs and widens them in its map."""
+    return any(col.is_wide(leaf.dtype)
+               for leaf in jax.tree.leaves(app.value_aval))
+
+
 class MapReduce:
     """``MapReduce(app).run(items)`` — the framework entry point.
 
@@ -347,116 +363,137 @@ class MapReduce:
         self.use_kernels = use_kernels
         self.cache = cache
         self.streaming = streaming
-        self._plan_key = pc.plan_key(
-            app, flow=flow, trust_semantics=trust_semantics,
-            n_pairs_hint=n_pairs_hint, use_kernels=use_kernels,
-            combine_impl=combine_impl, chunk_pairs=stream_chunk_pairs,
-            key_block=stream_key_block, autotune_probe=autotune_probe,
-            streaming=streaming)
+        self.wide = wide_job(app)
+        if self.wide:
+            pc.STATS.wide_jobs += 1
+        with self.width():
+            self._plan_key = pc.plan_key(
+                app, flow=flow, trust_semantics=trust_semantics,
+                n_pairs_hint=n_pairs_hint, use_kernels=use_kernels,
+                combine_impl=combine_impl, chunk_pairs=stream_chunk_pairs,
+                key_block=stream_key_block, autotune_probe=autotune_probe,
+                streaming=streaming, wide=self.wide)
 
-        entry = pc.plan_get(self._plan_key) if cache else None
-        if entry is not None:
-            # full in-memory hit: reuse the derivation (live combiner
-            # closures), flow choice and tiling — zero optimizer traces,
-            # zero autotune calls.  Fresh plan INSTANCE per MapReduce so
-            # run-time diagnostics never pollute the cached template.
-            self.plan = dataclasses.replace(
-                entry.plan, recovery=(), stage="planned",
-                cache_key=self._plan_key, cache_event="hit")
-            self.tiling = entry.tiling
-            self.stream_chunk_pairs = entry.stream_chunk_pairs
-            self._key_block = entry.key_block
-            self._bucket_size = entry.bucket_size
-            return
+            entry = pc.plan_get(self._plan_key) if cache else None
+            if entry is not None:
+                # full in-memory hit: reuse the derivation (live combiner
+                # closures), flow choice and tiling — zero optimizer traces,
+                # zero autotune calls.  Fresh plan INSTANCE per MapReduce so
+                # run-time diagnostics never pollute the cached template.
+                self.plan = dataclasses.replace(
+                    entry.plan, recovery=(), stage="planned",
+                    cache_key=self._plan_key, cache_event="hit")
+                self.tiling = entry.tiling
+                self.stream_chunk_pairs = entry.stream_chunk_pairs
+                self._key_block = entry.key_block
+                self._bucket_size = entry.bucket_size
+                self._record_holder_bytes()
+                return
 
-        cache_event = "miss" if cache else ""
-        fentry = pc.file_get(self._plan_key) if cache else None
-        if (fentry is not None and not isinstance(stream_chunk_pairs, int)
-                and fentry["flow"] in ("stream", "sort")):
-            # cross-process advisory hit: pin the persisted tiling decision
-            # so the (potentially measured) autotune probes are skipped;
-            # derivation and compilation still run — closures and
-            # executables don't serialize.
-            stream_chunk_pairs = int(fentry["chunk_pairs"])
-            if fentry.get("key_block") is not None \
-                    and not isinstance(stream_key_block, int):
-                stream_key_block = int(fentry["key_block"])
-            cache_event = "file-hit"
+            cache_event = "miss" if cache else ""
+            fentry = pc.file_get(self._plan_key) if cache else None
+            if (fentry is not None and not isinstance(stream_chunk_pairs, int)
+                    and fentry["flow"] in ("stream", "sort")):
+                # cross-process advisory hit: pin the persisted tiling decision
+                # so the (potentially measured) autotune probes are skipped;
+                # derivation and compilation still run — closures and
+                # executables don't serialize.
+                stream_chunk_pairs = int(fentry["chunk_pairs"])
+                if fentry.get("key_block") is not None \
+                        and not isinstance(stream_key_block, int):
+                    stream_key_block = int(fentry["key_block"])
+                cache_event = "file-hit"
 
-        self.plan = plan_execution(app, flow=flow,
-                                   trust_semantics=trust_semantics,
-                                   n_pairs_hint=n_pairs_hint,
-                                   streaming=streaming)
-        self.tiling = None
-        key_block = None
-        bucket_size = None
-        if self.plan.flow == "stream":
-            self.tiling = at.autotune_stream(
-                app, self.plan.spec, use_kernels=use_kernels,
-                chunk_pairs=stream_chunk_pairs, key_block=stream_key_block,
-                n_pairs_hint=n_pairs_hint, probe=autotune_probe)
-            self.plan.tiling = self.tiling
-            stream_chunk_pairs = self.tiling.chunk_pairs
-            key_block = (self.tiling.key_block if self.tiling.blocked
-                         else None)
-            spec = self.plan.spec
-            chosen = col.scatter_fold_chosen(
-                spec, app.key_space, kernel_additive=(
-                    use_kernels and spec.kernel_additive_ok(app.value_aval)))
-            if (self.tiling.mode == "scatter" and spec.mxu_lowerable
-                    and not chosen):
-                self.plan.diagnostics += (
-                    "stream fold degraded to exact scatter (dense budgets "
-                    "exceeded) — see tiling notes",)
-        elif self.plan.flow == "sort":
-            self.tiling = at.autotune_sort(
-                app, self.plan.spec, use_kernels=use_kernels,
-                chunk_pairs=stream_chunk_pairs, n_pairs_hint=n_pairs_hint)
-            self.plan.tiling = self.tiling
-            stream_chunk_pairs = self.tiling.chunk_pairs
-            bucket_size = (self.tiling.key_block if self.tiling.blocked
-                           else None)
-        elif not isinstance(stream_chunk_pairs, int):
-            stream_chunk_pairs = eng.DEFAULT_CHUNK_PAIRS
-        if (self.plan.flow == "combine" and self.plan.spec is not None
-                and self.plan.spec.mxu_lowerable
-                and app.key_space > col.ONEHOT_MAX_KEYS):
-            # below the legacy key-space cutoff the one-hot path holds at
-            # any pair count — nothing to flag there
-            if use_kernels:
-                self.plan.diagnostics += (
-                    f"combine flow: key_space={app.key_space} > "
-                    f"{col.ONEHOT_MAX_KEYS} exceeds the onehot_combine "
-                    f"kernel's VMEM-resident table cutoff; the collector "
-                    f"uses the exact scatter fallback "
-                    f"(LoweringFallbackWarning at trace time) — the "
-                    f"streaming flow's key-blocked fold kernel has no such "
-                    f"limit",)
-            else:
-                self.plan.diagnostics += (
-                    f"combine flow: at key_space={app.key_space} > "
-                    f"{col.ONEHOT_MAX_KEYS} the one-hot lowering holds up "
-                    f"to {col.ADDITIVE_FOLD_PAIRS_FUSED} pairs (the fused-"
-                    f"contraction regime); beyond that the collector "
-                    f"degrades to the exact scatter fallback "
-                    f"(LoweringFallbackWarning at trace time) — the "
-                    f"chunked stream flow has no such limit",)
-        self.stream_chunk_pairs = stream_chunk_pairs
-        self._key_block = key_block
-        self._bucket_size = bucket_size
-        self.plan.stage = "planned"
-        self.plan.cache_key = self._plan_key
-        self.plan.cache_event = cache_event
-        if cache:
-            # snapshot NOW: the template must not see diagnostics a later
-            # run of this instance appends
-            pc.plan_put(self._plan_key, pc.PlanEntry(
-                plan=dataclasses.replace(self.plan),
-                tiling=self.tiling,
-                stream_chunk_pairs=stream_chunk_pairs,
-                key_block=key_block, bucket_size=bucket_size))
-            pc.file_put(self._plan_key,
-                        pc.file_entry_from(self.plan, self.tiling))
+            self.plan = plan_execution(app, flow=flow,
+                                       trust_semantics=trust_semantics,
+                                       n_pairs_hint=n_pairs_hint,
+                                       streaming=streaming)
+            self.tiling = None
+            key_block = None
+            bucket_size = None
+            if self.plan.flow == "stream":
+                self.tiling = at.autotune_stream(
+                    app, self.plan.spec, use_kernels=use_kernels,
+                    chunk_pairs=stream_chunk_pairs, key_block=stream_key_block,
+                    n_pairs_hint=n_pairs_hint, probe=autotune_probe)
+                self.plan.tiling = self.tiling
+                stream_chunk_pairs = self.tiling.chunk_pairs
+                key_block = (self.tiling.key_block if self.tiling.blocked
+                             else None)
+                spec = self.plan.spec
+                chosen = col.scatter_fold_chosen(
+                    spec, app.key_space, kernel_additive=(
+                        use_kernels and spec.kernel_additive_ok(app.value_aval)))
+                if (self.tiling.mode == "scatter" and spec.mxu_lowerable
+                        and not chosen):
+                    self.plan.diagnostics += (
+                        "stream fold degraded to exact scatter (dense budgets "
+                        "exceeded) — see tiling notes",)
+            elif self.plan.flow == "sort":
+                self.tiling = at.autotune_sort(
+                    app, self.plan.spec, use_kernels=use_kernels,
+                    chunk_pairs=stream_chunk_pairs, n_pairs_hint=n_pairs_hint)
+                self.plan.tiling = self.tiling
+                stream_chunk_pairs = self.tiling.chunk_pairs
+                bucket_size = (self.tiling.key_block if self.tiling.blocked
+                               else None)
+            elif not isinstance(stream_chunk_pairs, int):
+                stream_chunk_pairs = eng.DEFAULT_CHUNK_PAIRS
+            if (self.plan.flow == "combine" and self.plan.spec is not None
+                    and self.plan.spec.mxu_lowerable
+                    and app.key_space > col.ONEHOT_MAX_KEYS):
+                # below the legacy key-space cutoff the one-hot path holds at
+                # any pair count — nothing to flag there
+                if use_kernels:
+                    self.plan.diagnostics += (
+                        f"combine flow: key_space={app.key_space} > "
+                        f"{col.ONEHOT_MAX_KEYS} exceeds the onehot_combine "
+                        f"kernel's VMEM-resident table cutoff; the collector "
+                        f"uses the exact scatter fallback "
+                        f"(LoweringFallbackWarning at trace time) — the "
+                        f"streaming flow's key-blocked fold kernel has no such "
+                        f"limit",)
+                else:
+                    self.plan.diagnostics += (
+                        f"combine flow: at key_space={app.key_space} > "
+                        f"{col.ONEHOT_MAX_KEYS} the one-hot lowering holds up "
+                        f"to {col.ADDITIVE_FOLD_PAIRS_FUSED} pairs (the fused-"
+                        f"contraction regime); beyond that the collector "
+                        f"degrades to the exact scatter fallback "
+                        f"(LoweringFallbackWarning at trace time) — the "
+                        f"chunked stream flow has no such limit",)
+            self.stream_chunk_pairs = stream_chunk_pairs
+            self._key_block = key_block
+            self._bucket_size = bucket_size
+            self.plan.stage = "planned"
+            self.plan.cache_key = self._plan_key
+            self.plan.cache_event = cache_event
+            if cache:
+                # snapshot NOW: the template must not see diagnostics a later
+                # run of this instance appends
+                pc.plan_put(self._plan_key, pc.PlanEntry(
+                    plan=dataclasses.replace(self.plan),
+                    tiling=self.tiling,
+                    stream_chunk_pairs=stream_chunk_pairs,
+                    key_block=key_block, bucket_size=bucket_size))
+                pc.file_put(self._plan_key,
+                            pc.file_entry_from(self.plan, self.tiling))
+            self._record_holder_bytes()
+
+    def _record_holder_bytes(self) -> None:
+        """``plan_cache.STATS.holder_bytes[plan key]``: the bytes of holder
+        state this plan folds per emitted pair, every holder column's
+        itemsize x width plus the int32 count (nothing for the reduce
+        flow, which folds no holders)."""
+        if self.plan.spec is not None:
+            _, nbytes = self.plan.spec.holder_width(self.app.value_aval)
+            pc.STATS.holder_bytes[self._plan_key] = nbytes + 4
+
+    def width(self):
+        """The scope this job is traced and run in: 64-bit for a wide job
+        (:func:`wide_job`), unchanged for any other."""
+        return (jax.enable_x64(True) if self.wide
+                else contextlib.nullcontext())
 
     # -- lowering knob resolution ------------------------------------------
 
@@ -491,7 +528,8 @@ class MapReduce:
         opts = options if options is not None else ExecutionOptions()
         rmode = _infer_mode(opts, mode)
         if rmode in ("distributed", "resilient"):
-            opts = self._resolve_shuffle(opts, items, rmode)
+            with self.width():  # the skew planner runs the map
+                opts = self._resolve_shuffle(opts, items, rmode)
         return Lowered(self, pc.items_spec_of(items), opts, mode=rmode)
 
     def _resolve_shuffle(self, opts: ExecutionOptions, items,
@@ -720,7 +758,8 @@ class Optimized:
         else:
             self.n_bucket = pc.bucket_items(self.n_items,
                                             options.items_bucket)
-        self.cache_key = self._cache_key()
+        with mr.width():  # the key traces the map
+            self.cache_key = self._cache_key()
 
     def _cache_key(self) -> str | None:
         if self.mode == "resilient":
@@ -762,7 +801,8 @@ class Optimized:
             ent = pc.compiled_get(self.cache_key)
             if ent is not None:
                 return Compiled(self, ent, cache_event="hit")
-        ent = self._build()
+        with self.mr.width():
+            ent = self._build()
         if use_cache:
             pc.compiled_put(self.cache_key, ent)
         return Compiled(self, ent,
@@ -901,12 +941,17 @@ class Compiled:
         self.cache_key = opt.cache_key
         self.cache_event = cache_event
         self._entry = entry
+        self.width = opt.mr.width
         # a fresh copy of the plan the executable was traced with: run-time
         # diagnostics (shuffle overflow, lowering fallbacks) land here
         # without polluting other Compiled objects sharing the cache entry
         self.plan = dataclasses.replace(entry.plan, stage="compiled")
 
     def __call__(self, items) -> MapReduceResult:
+        with self.width():
+            return self._call(items)
+
+    def _call(self, items) -> MapReduceResult:
         if self.mode == "streaming":
             raise TypeError(
                 "a streaming-mode Compiled is an incremental ingest "
@@ -942,22 +987,26 @@ class Compiled:
 
     def init_state(self):
         """Fresh carried combiner state (streaming mode)."""
-        return self._entry.aux.init_state()
+        with self.width():
+            return self._entry.aux.init_state()
 
     def ingest_state(self, state, items, n_valid):
         """Fold one padded micro-batch into ``state`` (streaming mode).
 
         Pure AOT dispatch: ``items`` must already be padded to the lowered
         ``batch_capacity`` shape; ``n_valid`` masks the tail."""
-        return self._entry.executable(state, items, jnp.int32(n_valid))
+        with self.width():
+            return self._entry.executable(state, items, jnp.int32(n_valid))
 
     def state_tables(self, state):
         """Un-finalized ``(tables, counts)`` view of a carried state."""
-        return self._entry.aux.tables_counts(state)
+        with self.width():
+            return self._entry.aux.tables_counts(state)
 
     def finalize_state(self, state):
         """Finalized ``Grouped(keys, values, counts)`` of a carried state."""
-        return self._entry.aux.finalize(state)
+        with self.width():
+            return self._entry.aux.finalize(state)
 
     # -- XLA introspection pass-through (local AOT executables) -------------
 

@@ -273,7 +273,7 @@ def _fold_items_chunked(app, combiner, items, chunk_items: int,
     if n_chunks <= 1:
         stream = map_phase(app, items)
         if n_valid is not None:
-            mask = jnp.repeat(jnp.arange(n_items) < n_valid,
+            mask = jnp.repeat(jnp.arange(n_items, dtype=jnp.int32) < n_valid,
                               app.emit_capacity)
             stream = col.PairStream(
                 jnp.where(mask, stream.keys, app.key_space),
@@ -288,7 +288,7 @@ def _fold_items_chunked(app, combiner, items, chunk_items: int,
     chunked = jax.tree.map(
         lambda a: a.reshape((n_chunks, chunk_items) + a.shape[1:]), items_p)
     valid_items = n_items if n_valid is None else n_valid
-    item_mask = (jnp.arange(padded) < valid_items).reshape(
+    item_mask = (jnp.arange(padded, dtype=jnp.int32) < valid_items).reshape(
         n_chunks, chunk_items)
 
     def body(state, xs):
@@ -473,7 +473,8 @@ def run_local(app, plan, items, *, combine_impl="auto", use_kernels=False,
     stream = map_phase(app, items)
     if n_valid is not None:
         n_items = jax.tree.leaves(items)[0].shape[0]
-        mask = jnp.repeat(jnp.arange(n_items) < n_valid, app.emit_capacity)
+        mask = jnp.repeat(jnp.arange(n_items, dtype=jnp.int32) < n_valid,
+                          app.emit_capacity)
         stream = col.PairStream(jnp.where(mask, stream.keys, app.key_space),
                                 stream.values, app.key_space)
     if plan.flow == "combine":
@@ -945,6 +946,15 @@ def _distributed_tiling(app, plan, items, num_shards, *, use_kernels,
     return chunk_pairs, key_block
 
 
+def _replicated(a):
+    """``a`` whole on every device of its mesh (unchanged when it is not
+    laid out over one)."""
+    sh = getattr(a, "sharding", None)
+    if isinstance(sh, jax.sharding.NamedSharding):
+        return jax.device_put(a, jax.sharding.NamedSharding(sh.mesh, P()))
+    return a
+
+
 def _densify_ranges(keys, values, counts, shuffle_plan):
     """Scatter concatenated boundary-range outputs into the dense
     ``keys == arange(K)`` layout.
@@ -967,6 +977,10 @@ def _densify_ranges(keys, values, counts, shuffle_plan):
     K = shuffle_plan.key_space
     b = shuffle_plan.boundaries
     W = shuffle_plan.width
+    # the rows are laid out over the mesh; the scatter below indexes them
+    # by key, so it reads them whole (a mesh with explicit axes refuses
+    # an index that mixes a sharded and an unsharded operand)
+    keys, values, counts = jax.tree.map(_replicated, (keys, values, counts))
     spans = np.asarray([b[s + 1] - b[s]
                         for s in range(shuffle_plan.num_shards)])
     auth = jnp.asarray(

@@ -75,7 +75,11 @@ class CacheStats:
     ``plan_hits``/``plan_misses`` the plan-stage (pre-shape) lookups;
     ``file_hits`` the advisory file-layer hits.  ``folds`` counts the
     streaming folds built (``collector.StreamCombiner``) per lowering,
-    ``folds.<lowering>`` in a snapshot."""
+    ``folds.<lowering>`` in a snapshot.  ``wide_jobs`` counts the
+    :class:`~repro.core.api.MapReduce` plans built for wide (64-bit value)
+    jobs.  ``holder_bytes`` is per plan, not an event: ``{plan key: bytes
+    of holder state folded per emitted pair}`` (every holder column's
+    itemsize x width, plus the int32 count), left out of snapshots."""
 
     derives: int = 0
     autotunes: int = 0
@@ -88,9 +92,12 @@ class CacheStats:
     file_hits: int = 0
     folds: dict = dataclasses.field(
         default_factory=lambda: dict.fromkeys(FOLD_LOWERINGS, 0))
+    wide_jobs: int = 0
+    holder_bytes: dict = dataclasses.field(default_factory=dict)
 
     def snapshot(self) -> dict:
         snap = dataclasses.asdict(self)
+        del snap["holder_bytes"]
         folds = snap.pop("folds")
         snap.update({f"folds.{m}": n for m, n in folds.items()})
         return snap
@@ -258,10 +265,12 @@ def bucket_items(n: int, policy: str = "exact") -> int:
 def plan_key(app, *, flow: str, trust_semantics: bool,
              n_pairs_hint: int | None, use_kernels: bool,
              combine_impl: str, chunk_pairs, key_block,
-             autotune_probe: bool, streaming: bool = False) -> str:
+             autotune_probe: bool, streaming: bool = False,
+             wide: bool = False) -> str:
     """Key of the plan stage (derivation + flow selection + tiling) —
     everything :class:`MapReduce` resolves before it sees item shapes,
-    which includes the platform the fold lowering is chosen for."""
+    which includes the platform the fold lowering is chosen for and the
+    job's width (``api.wide_job``)."""
     from repro.core import collector
     return _digest(
         "plan", reduce_fingerprint(app), _app_attr_sig(app),
@@ -269,7 +278,8 @@ def plan_key(app, *, flow: str, trust_semantics: bool,
         f"hint={n_pairs_hint}", f"kern={use_kernels}",
         f"impl={combine_impl}", f"chunk={chunk_pairs}",
         f"blk={key_block}", f"probe={autotune_probe}",
-        f"streaming={streaming}", f"platform={collector.fold_platform()}")
+        f"streaming={streaming}", f"platform={collector.fold_platform()}",
+        f"wide={wide}")
 
 
 def compiled_key(app, items_spec, *, plan_key: str, flow: str,

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.extend import core as jax_core
 
 from repro.core import combiner as C
 
@@ -94,11 +95,15 @@ def _is_lit(v) -> bool:
 
 
 def _inline(jaxpr, consts):
-    """Flatten (jaxpr, consts) -> (eqns, const_env, invars, outvars)."""
+    """Flatten (jaxpr, consts) -> (eqns, const_env, invars, outvars).
+
+    A called jaxpr's equations get fresh output vars at each call site:
+    JAX caches the jaxpr of a jitted helper (``jnp.where`` is one), so two
+    calls of it would otherwise share, and overwrite, the same vars."""
     const_env: dict[Any, Any] = {}
     flat_eqns: list = []
 
-    def go(jx, jconsts, sub: dict):
+    def go(jx, jconsts, sub: dict, inner: bool):
         for cv, cval in zip(jx.constvars, jconsts):
             const_env[cv] = cval
 
@@ -109,21 +114,28 @@ def _inline(jaxpr, consts):
             name = eqn.primitive.name
             if name in CALL_PRIMS:
                 cj = _sub_jaxpr(eqn)
-                inner, inner_consts = cj.jaxpr, cj.consts
+                inner_jx, inner_consts = cj.jaxpr, cj.consts
                 inner_sub: dict = {}
                 args = [resolve(v) for v in eqn.invars]
-                n = min(len(inner.invars), len(args))
-                for iv, av in zip(inner.invars[:n], args[:n]):
+                n = min(len(inner_jx.invars), len(args))
+                for iv, av in zip(inner_jx.invars[:n], args[:n]):
                     inner_sub[iv] = av
-                go(inner, inner_consts, inner_sub)
-                for ov, inner_ov in zip(eqn.outvars, inner.outvars):
+                go(inner_jx, inner_consts, inner_sub, True)
+                for ov, inner_ov in zip(eqn.outvars, inner_jx.outvars):
                     sub[ov] = (inner_ov if _is_lit(inner_ov)
                                else inner_sub.get(inner_ov, inner_ov))
             else:
-                flat_eqns.append(eqn.replace(invars=[resolve(v) for v in eqn.invars]))
+                outvars = eqn.outvars
+                if inner:
+                    outvars = [jax_core.Var(v.aval) if type(v) is jax_core.Var
+                               else v for v in outvars]  # not a DropVar
+                    sub.update(zip(eqn.outvars, outvars))
+                flat_eqns.append(eqn.replace(
+                    invars=[resolve(v) for v in eqn.invars],
+                    outvars=outvars))
 
     top_sub: dict = {}
-    go(jaxpr, consts, top_sub)
+    go(jaxpr, consts, top_sub, False)
     outvars = [v if _is_lit(v) else top_sub.get(v, v) for v in jaxpr.outvars]
     return flat_eqns, const_env, list(jaxpr.invars), outvars
 

@@ -268,7 +268,12 @@ class ShufflePlan:
         S = self.num_shards
         legacy = eng.shuffle_bucket_capacity(n_pairs, S)
         if self.max_dest_frac is None:
-            return legacy
+            # boundaries and hot splits given by hand, nothing sampled: a
+            # hot key may carry every pair, and the round-robin hands each
+            # of its destinations at most ceil(n / ways) of them on top of
+            # the range's own share
+            hot = sum(-(-n_pairs // w) for w in self.hot_ways)
+            return min(n_pairs, legacy + hot) if hot else legacy
         frac = min(1.0, float(self.max_dest_frac))
         cap = int(np.ceil(n_pairs * frac * CAPACITY_SLACK))
         return max(min(n_pairs, max(cap, 8)), legacy)
@@ -353,7 +358,9 @@ def sample_key_histogram(app, items, *,
     n = int(leaves[0].shape[0])
     idx = _sample_indices(n, sample_fraction,
                           int(getattr(app, "emit_capacity", 16)))
-    sub = jax.tree.map(lambda a: jnp.asarray(a)[idx], items)
+    # the subsample is cut on the host, as the memo key cuts it: items
+    # sharded over a mesh cannot be gathered by index on the device
+    sub = jax.tree.map(lambda a: np.asarray(a)[idx], items)
     stream = eng.map_phase(app, sub)
     keys = np.asarray(stream.keys)
     valid = np.asarray(stream.valid)

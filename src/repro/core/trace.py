@@ -31,7 +31,8 @@ Host spans, by entry point (children indented):
     - ``mr.finalize``: finalize of one slot's state
 
 Device scopes (``jax.named_scope``; they set ``op_name`` metadata and
-never change the computation): ``mr.map``, ``mr.fold``, ``mr.partition``,
+never change the computation): ``mr.map``, ``mr.premap`` (the derived
+premap, map-side), ``mr.fold``, ``mr.partition``,
 ``mr.segment_reduce``, ``mr.shuffle``, ``mr.merge``, ``mr.finalize``.
 """
 
@@ -56,6 +57,7 @@ SEED = "mr.seed"
 SNAPSHOT = "mr.snapshot"
 # device scopes (MERGE and FINALIZE are host spans in the service too)
 MAP = "mr.map"
+PREMAP = "mr.premap"
 FOLD = "mr.fold"
 PARTITION = "mr.partition"
 SEGMENT_REDUCE = "mr.segment_reduce"

@@ -24,6 +24,10 @@ reference once and works off that frozen view.  That is the
 double-buffered table swap: snapshots are consistent without pausing
 ingestion and without copying tables.
 
+Width: a wide job (64-bit values, ``api.wide_job``) ingests, merges,
+snapshots and restores in its 64-bit scope, as its batch runs do, so its
+tables are never truncated to 32 bits.
+
 Durability: every ``ckpt_every`` batches the slot states are snapshotted
 atomically via ``checkpoint/ckpt.py`` (tmp + ``os.replace``), keyed by
 the monotonically increasing batch id.  ``restore()`` reloads the newest
@@ -196,7 +200,7 @@ class MapReduceService:
 
         Thread-safe single-writer: concurrent callers serialize on the
         service lock; snapshots never wait on it."""
-        with trace.span(trace.INGEST) as span:
+        with trace.span(trace.INGEST) as span, self.mr.width():
             return self._ingest(items, span)
 
     def _ingest(self, items, span: trace.span) -> int:
@@ -266,7 +270,7 @@ class MapReduceService:
                 "service not staged yet: ingest a first micro-batch or "
                 "construct with item_spec=... to compile eagerly")
         st = self._state
-        with trace.span(trace.SNAPSHOT, rid=st.batch_id):
+        with trace.span(trace.SNAPSHOT, rid=st.batch_id), self.mr.width():
             states = self._live_slots(st)
             if len(states) > 1:
                 with trace.span(trace.MERGE):
@@ -352,9 +356,10 @@ class MapReduceService:
             slots=tuple(self._compiled.init_state()
                         for _ in range(self.n_slots)),
             batch_id=0, n_items=0))
-        tree, step = self._retried(
-            f"service restore from {d}",
-            lambda: ckpt.restore(d, example, step=step))
+        with self.mr.width():
+            tree, step = self._retried(
+                f"service restore from {d}",
+                lambda: ckpt.restore(d, example, step=step))
         with self._lock:
             self._state = _ServiceState(
                 slots=tuple(tree["slots"]),

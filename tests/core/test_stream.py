@@ -115,18 +115,23 @@ def test_masked_emission_stream():
     assert int(res.values[0]) == 80
 
 
-def test_first_idiom_stream():
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int64],
+                         ids=["f32", "wide_i64"])
+def test_first_idiom_stream(dtype):
     """First-element idiom: holder keeps the first-arriving value across
-    chunk boundaries."""
+    chunk boundaries (also for a wide job, whose carried counts must stay
+    int32 through the chunk scan)."""
     app = make_app(
         lambda item, emit: emit(item[0], item[1]),
         lambda k, v, c: v[0],
         key_space=4,
-        value_aval=jax.ShapeDtypeStruct((), jnp.float32),
+        value_aval=jax.ShapeDtypeStruct((), dtype),
         emit_capacity=1, max_values_per_key=256,
     )
     keys = np.array([2, 0, 2, 1, 0, 1, 3, 2] * 16, np.int32)
     vals = np.arange(len(keys), dtype=np.float32)
+    if dtype == jnp.int64:
+        vals = vals.astype(np.int32)
     mr = MapReduce(app, flow="stream", stream_chunk_pairs=16)
     assert mr.plan.derivation.strategy == C.STRATEGY_FIRST
     res = mr.run((jnp.asarray(keys), jnp.asarray(vals)))

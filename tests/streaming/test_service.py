@@ -101,6 +101,11 @@ SPECS = {
               jax.ShapeDtypeStruct((), I32), np.int32, ()),
     "vecsum_f32": (lambda k, v, c: jnp.sum(v, axis=0),
                    jax.ShapeDtypeStruct((4,), F32), np.float32, (4,)),
+    # wide jobs: int64 values (the map widens the int32 items)
+    "count_i64": (lambda k, v, c: c,
+                  jax.ShapeDtypeStruct((), jnp.int64), np.int32, ()),
+    "sum_i64": (lambda k, v, c: jnp.sum(v * (1 << 40)),
+                jax.ShapeDtypeStruct((), jnp.int64), np.int32, ()),
 }
 
 
